@@ -73,7 +73,7 @@ def measured_layer_flops(arch: str, B: int, S: int,
         if mesh is not None:
             shd.set_active_mesh(mesh)
             try:
-                with shd.use_mesh(mesh):
+                with jax.set_mesh(mesh):
                     def fwd_moe(params, batch, model=model, cfg1=cfg1, n=n):
                         x = model._embed_inputs(params, batch)
                         seg = cfg1.segments[0]

@@ -292,15 +292,16 @@ def bench_multilevel(P=8, eps=0.05, seed=0, sizes=None, flat_limit=None):
 
 
 def bench_device_resident(P=4, eps=0.05, seed=0, sizes=None,
-                          interpret_row=True):
+                          pallas_row=True):
     """Device-resident FM pass vs per-front dispatch vs numpy (PR 6).
 
     Times one ``fm_refine`` call per variant on integer-weight row-net
     instances: the numpy frontier (PR 3 host path), the per-front jax
     dispatch (PR 3 jax path, forced by raising the device floor above n),
     the whole-pass device-resident program (one host sync per committed
-    move), and -- at the smallest size only, interpret mode is slow -- the
-    Pallas find-pricing path.  All variants are decision-identical, so a
+    move), and -- at the smallest size only, interpret mode is slow off
+    the TPU -- the Pallas find-pricing path (``seconds_device_pallas``,
+    with ``pallas_interpret`` saying how it ran).  All variants are decision-identical, so a
     cost mismatch is a bug; host-sync counters come from an instrumented
     ``run_fm`` on the same instance and land in ``BENCH_partition.json``
     as ``device_resident`` via ``run.py``.
@@ -314,10 +315,6 @@ def bench_device_resident(P=4, eps=0.05, seed=0, sizes=None,
     one-sync contract); the commit-batching follow-up and the compiled
     TPU path are ROADMAP open item 3.
     """
-    try:
-        import jax  # noqa: F401
-    except ImportError:
-        return {"scale": [], "available": False}
     from repro.kernels import front_pass, gain
 
     sizes = sizes or ((8192, 16384, 32768) if FULL else (4096, 8192))
@@ -328,14 +325,13 @@ def bench_device_resident(P=4, eps=0.05, seed=0, sizes=None,
     floor_saved = front_pass.DEVICE_MIN_NODES
     front_pass.DEVICE_MIN_NODES = min(min(sizes) // 2, floor_saved)
     try:
-        rows = _device_resident_rows(sizes, P, eps, seed, interpret_row)
+        rows = _device_resident_rows(sizes, P, eps, seed, pallas_row)
     finally:
         front_pass.DEVICE_MIN_NODES = floor_saved
-    return {"scale": rows, "available": True,
-            "kernel_cache": gain.kernel_cache_stats()}
+    return {"scale": rows, "kernel_cache": gain.kernel_cache_stats()}
 
 
-def _device_resident_rows(sizes, P, eps, seed, interpret_row):
+def _device_resident_rows(sizes, P, eps, seed, pallas_row):
     from repro.core.partition import PartitionState
     from repro.core.partition.cost import capacity
     from repro.core.partition.heuristic import fm_refine, greedy_initial
@@ -415,14 +411,17 @@ def _device_resident_rows(sizes, P, eps, seed, interpret_row):
             "price_seconds_perfront": t_perfront,
             "price_speedup": t_perfront / max(t_fused, 1e-9),
         }
-        if interpret_row and n == sizes[0]:
+        if pallas_row and n == sizes[0]:
+            # the Pallas find-pricing path: compiled on a TPU (where it is
+            # what seconds_device already ran), interpreted elsewhere
             ops.force("pallas")
             try:
-                t_pi, c_pi = timed("jax", warm=True)
+                t_pl, c_pl = timed("jax", warm=True)
             finally:
                 ops.force(None)
-            assert c_pi == c_np, (n, c_pi, c_np)
-            row["seconds_device_pallas_interpret"] = t_pi
+            assert c_pl == c_np, (n, c_pl, c_np)
+            row["seconds_device_pallas"] = t_pl
+            row["pallas_interpret"] = dev.interpret
         rows.append(row)
     return rows
 
@@ -513,7 +512,7 @@ def device_smoke(P=4, eps=0.1, seed=0):
     front_pass.DEVICE_MIN_NODES = 1
     try:
         out = bench_device_resident(P=P, eps=eps, seed=seed, sizes=(1024,),
-                                    interpret_row=True)
+                                    pallas_row=True)
     finally:
         front_pass.DEVICE_MIN_NODES = saved
     for row in out["scale"]:    # cost equality is asserted inside; re-check

@@ -80,8 +80,9 @@ def main() -> None:
           f"speedup_numpy={frep['speedup_numpy']:.2f}x;"
           f"rep_cost={frep['rep_cost']:.0f}")
     for row in part["device"].get("scale", []):
-        pi = (f";pallas_interpret={row['seconds_device_pallas_interpret']:.2f}s"
-              if "seconds_device_pallas_interpret" in row else "")
+        pi = (f";pallas={row['seconds_device_pallas']:.2f}s"
+              f";interpret={row['pallas_interpret']}"
+              if "seconds_device_pallas" in row else "")
         _emit(f"partition_device_n{row['n']}", row["seconds_device"],
               f"speedup_vs_numpy={row['speedup_vs_numpy']:.2f}x;"
               f"speedup_vs_perfront={row['speedup_vs_perfront']:.2f}x;"
@@ -248,6 +249,8 @@ def serve_smoke() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "--device-smoke" in sys.argv:
         device_smoke()
     elif "--parallel-smoke" in sys.argv:

@@ -36,7 +36,8 @@ Device-resident refinement path (PR 6):
 runs one FM refinement pass twice -- numpy frontier vs the whole-pass
 device-resident program (`kernels/front_pass.py`: persistent jnp state,
 fused pricing, one host sync per committed move) -- and prints both
-wall-clocks, the sync/commit counters and the bit-identity check.
+wall-clocks, the sync/commit counters and the bit-identity check.  It
+exits non-zero when the device pass cannot attach.
 
 Online serving path (PR 10):
 
@@ -60,6 +61,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
 from repro.configs import get_config, reduce_config
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.optim import adamw
 from repro.runtime.trainer import Trainer, TrainerConfig
@@ -159,10 +161,10 @@ def device_demo(n: int, backend: str = "jax", P: int = 4,
     st_dev = PartitionState(hg, P, masks=m0.copy())
     dev = device_pass(st_dev, capacity(hg, P, eps) + 1e-9, backend=backend)
     if dev is None:
-        print("device path unavailable (no jax / non-integer weights / "
-              f"n < DEVICE_MIN_NODES) -- frontier='{backend}' would fall "
-              "back to the per-front path")
-        return
+        raise SystemExit(
+            f"device pass did not attach (frontier='{backend}', n={hg.n}; "
+            "needs frontier='jax', integer weights and n >= "
+            "DEVICE_MIN_NODES)")
     t0 = time.perf_counter()
     try:
         dev.run_fm(np.random.default_rng(0), 6)
@@ -255,6 +257,7 @@ def main() -> None:
                     help="disable the superstep-split refinement front in "
                          "--multilevel-schedule (PR 9 default: on)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.multilevel:
         multilevel_demo(args.n or 8192, workers=args.workers)

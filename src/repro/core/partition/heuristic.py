@@ -454,6 +454,7 @@ def partition_with_replication(
     frontier: str | None = None,
     multilevel: bool = False,
     workers: int | None = None,
+    stats: list | None = None,
 ):
     """End-to-end entry: returns (non_repl_result, repl_result).
 
@@ -469,6 +470,7 @@ def partition_with_replication(
     (``core.partition.parallel``); cost stays never-worse -- the parallel
     reconciliation accepts improving moves only -- but the refinement
     trajectory may diverge from serial (disclosed in the benches).
+    ``stats`` (multilevel only) receives one row per refinement stop.
     """
     from .exact import exact_partition
 
@@ -481,7 +483,7 @@ def partition_with_replication(
         from .multilevel import partition_with_replication_multilevel
         return partition_with_replication_multilevel(
             hg, P, eps, mode=mode, seed=seed, frontier=frontier,
-            workers=workers)
+            stats=stats, workers=workers)
     base = partition_heuristic(hg, P, eps, seed=seed, frontier=frontier)
     max_replicas = 2 if mode == "dup" else None
     # alternate replication local search with FM passes on the primary

@@ -248,11 +248,13 @@ def _compose_maps(cmaps, edge_maps, lo: int, hi: int):
 
 
 def _make_ctx(workers: int | None):
-    """A ``ParallelContext`` for ``workers > 1`` (None when unavailable)."""
+    """A ``ParallelContext`` for ``workers > 1`` (None, with a
+    ``SerialFallbackWarning``, when shared memory is unavailable)."""
     if not workers or workers <= 1:
         return None
-    from .parallel import ParallelContext, shm_available
+    from .parallel import ParallelContext, shm_available, warn_serial
     if not shm_available():
+        warn_serial("POSIX shared memory unavailable")
         return None
     return ParallelContext(workers)
 

@@ -14,8 +14,9 @@ times) or going through a file.  This module provides the third option:
   segments are unlinked on ``close()`` -- also after worker crashes, the
   registry never relies on worker-side cleanup.
 
-* ``ParallelContext`` -- worker-pool lifecycle (``fork`` preferred,
-  ``spawn`` fallback -- both tested), per-``Hypergraph`` export cache (the
+* ``ParallelContext`` -- worker-pool lifecycle (``fork`` while this
+  process holds no JAX backend, ``spawn`` once it does -- both tested),
+  per-``Hypergraph`` export cache (the
   six CSR arrays + omega + mu are shared once per level), and
   ``adopt_state``: re-back a live ``PartitionState``'s ``uncov`` /
   ``edge_lambda`` / ``masks`` with shared segments so the engine's
@@ -41,8 +42,12 @@ times) or going through a file.  This module provides the third option:
   therefore never worse than the projected cost; divergence from the
   serial trajectory is disclosed in the ``parallel_scale`` bench rows.
 
-Workers never touch the JAX backend (``frontier="numpy"`` end to end), so
-the pool is safe under ``fork`` even when the parent has device state.
+Workers never touch the JAX backend (``frontier="numpy"`` end to end).
+A parent that already holds one (``frontier="jax"`` ran, or anything else
+touched a device) never forks its pool: a forked child would inherit the
+accelerator runtime, and a chip belongs to one process.  Such a pool is
+spawned instead.  A pool that breaks mid-run is not hidden either: every
+call site that goes serial emits a ``SerialFallbackWarning``.
 Worker-side attaches suppress resource-tracker registration (bpo-38119:
 Python <= 3.12 registers attach-only segments too, and the process tree
 shares one tracker, so a worker's registration would let the tracker
@@ -53,6 +58,8 @@ from __future__ import annotations
 import dataclasses
 import multiprocessing as mp
 import secrets
+import sys
+import warnings
 from concurrent.futures import ProcessPoolExecutor
 
 import numpy as np
@@ -64,6 +71,23 @@ PARALLEL_MIN_NODES = 4096   # below this, sharding overhead beats the work
 _SEG_PREFIX = "repro"
 
 _CSR_KEYS = ("xpins", "pins", "xinc", "inc_edges", "xadj", "adj_nodes")
+
+
+class SerialFallbackWarning(RuntimeWarning):
+    """A parallel call site ran its serial path because the pool failed or
+    could not start."""
+
+
+def warn_serial(reason) -> None:
+    warnings.warn(f"worker pool unavailable, running serially: {reason!r}",
+                  SerialFallbackWarning, stacklevel=3)
+
+
+def jax_backend_live() -> bool:
+    """True once this process has initialised a JAX backend (the pool must
+    not fork it then).  Never imports jax itself."""
+    xb = sys.modules.get("jax._src.xla_bridge")
+    return xb is not None and xb.backends_are_initialized()
 
 
 def shm_available() -> bool:
@@ -290,9 +314,12 @@ def _crash_task(arg):
 class ParallelContext:
     """Pool + registry lifecycle for one partitioning run.
 
-    The pool starts lazily on first use; ``failed`` flips sticky-true on
-    the first worker-layer error, after which every call site falls back
-    to its serial path (never abort the partition over a pool problem).
+    The pool starts lazily on first use, and ``start_method=None`` picks
+    its start method then: ``fork`` where available while this process
+    holds no JAX backend, ``spawn`` otherwise.  An explicit ``"fork"`` in a
+    process that holds one raises.  ``failed`` flips sticky-true on the
+    first worker-layer error, after which every call site warns and runs
+    its serial path (never abort the partition over a pool problem).
     """
 
     def __init__(self, workers: int, start_method: str | None = None,
@@ -300,9 +327,6 @@ class ParallelContext:
         self.workers = max(int(workers), 1)
         self.min_nodes = (PARALLEL_MIN_NODES if min_nodes is None
                           else int(min_nodes))
-        if start_method is None:
-            start_method = ("fork" if "fork" in mp.get_all_start_methods()
-                            else "spawn")
         self.start_method = start_method
         self.reg = ShmRegistry()
         self.failed = False
@@ -316,6 +340,14 @@ class ParallelContext:
     # -- pool ------------------------------------------------------------
     def _get_pool(self) -> ProcessPoolExecutor:
         if self._pool is None:
+            live = jax_backend_live()
+            if self.start_method is None:
+                self.start_method = (
+                    "fork" if not live and "fork" in mp.get_all_start_methods()
+                    else "spawn")
+            elif self.start_method == "fork" and live:
+                raise RuntimeError("this process holds a JAX backend; a "
+                                   "forked worker would share its device")
             self._pool = ProcessPoolExecutor(
                 max_workers=self.workers,
                 mp_context=mp.get_context(self.start_method))
@@ -323,7 +355,7 @@ class ParallelContext:
 
     def run(self, fn, tasks: list) -> list:
         """Map ``fn`` over ``tasks`` on the pool (raises on worker death;
-        callers catch, set ``failed`` and go serial)."""
+        callers catch, set ``failed``, warn and go serial)."""
         return list(self._get_pool().map(fn, tasks))
 
     # -- shared exports --------------------------------------------------
@@ -429,8 +461,9 @@ def parallel_match_pref(hg: Hypergraph, ctx: ParallelContext,
                  if bounds[w + 1] > bounds[w]]
         parts = ctx.run(_pref_task, tasks)
         return np.concatenate(parts)
-    except Exception:
+    except Exception as e:
         ctx.failed = True
+        warn_serial(e)
         return _match_pref(hg, max_edge_size)
 
 
@@ -508,8 +541,9 @@ def parallel_refine(hg: Hypergraph, st: PartitionState, P: int, eps: float,
                                   max_replicas, lo, hi))
             results = ctx.run(_refine_task, tasks)
             stats["workers"] = len(tasks)
-        except Exception:
+        except Exception as e:
             ctx.failed = True
+            warn_serial(e)
             results = None
     if results is None:
         # pool unavailable/broken: the ordinary serial pass on ``st``

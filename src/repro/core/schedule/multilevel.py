@@ -182,11 +182,12 @@ def same_level_matching(dag: Dag, level: np.ndarray, max_weight: float,
     blocks = None
     if (ctx is not None and not ctx.failed and ctx.workers > 1
             and n >= ctx.min_nodes):
-        from ..partition.parallel import parallel_pair_parts
+        from ..partition.parallel import parallel_pair_parts, warn_serial
         try:
             blocks = parallel_pair_parts(dag, xch, level, ctx, max_fanout)
-        except Exception:
+        except Exception as e:
             ctx.failed = True
+            warn_serial(e)
             blocks = None
     if blocks is None:
         blocks = [_pair_parts(xch, dst, dag.xpar, dag.par_arr, mu, level,
@@ -377,9 +378,10 @@ def multilevel_schedule(inst: BspInstance,
     flat path also runs as a hedge and the cheaper schedule wins (see
     module docstring -- the hedge is off by default since PR 9).
     ``workers > 1`` shards coarsening's matching-score pass over a
-    shared-memory process pool (bit-identical result; silently serial
-    where shm is unavailable).  ``stats`` (optional list) receives one
-    row per refinement stop with projected/refined costs, which is how
+    shared-memory process pool (bit-identical result; serial, with a
+    ``SerialFallbackWarning``, where shm is unavailable).  ``stats``
+    (optional list) receives one row per refinement stop with
+    projected/refined costs, which is how
     the refinement-never-increases property is tested, plus a
     ``flat_guard`` row when the hedge ran.
     """
@@ -392,9 +394,13 @@ def multilevel_schedule(inst: BspInstance,
     ctx = None
     if workers is not None and workers > 1:
         from ..partition.parallel import (PARALLEL_MIN_NODES,
-                                          ParallelContext, shm_available)
-        if dag.n >= PARALLEL_MIN_NODES and shm_available():
-            ctx = ParallelContext(workers)
+                                          ParallelContext, shm_available,
+                                          warn_serial)
+        if dag.n >= PARALLEL_MIN_NODES:
+            if shm_available():
+                ctx = ParallelContext(workers)
+            else:
+                warn_serial("POSIX shared memory unavailable")
     try:
         levels, cmaps = build_levels(dag, inst.P, opts, rng, ctx=ctx)
     finally:

@@ -445,8 +445,8 @@ def best_replicated_schedule(inst, baseline: Schedule | None = None,
     falls through to this flat path exactly.  ``ml_opts`` forwards a
     ``MultilevelScheduleOptions``; ``stats`` collects per-level cost rows;
     ``workers`` (> 1) shards the coarsening scoring passes over a
-    process-parallel context (bit-identical results; serial where shared
-    memory is unavailable).
+    process-parallel context (bit-identical results; serial, with a
+    ``SerialFallbackWarning``, where shared memory is unavailable).
     """
     from .list_sched import baseline_schedule, bspg_schedule, hill_climb
 

@@ -61,6 +61,8 @@ from __future__ import annotations
 
 import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .gain import _NO_COVER, front_dlam
@@ -80,14 +82,13 @@ DEVICE_MIN_STEPS = 8
 _R_BLK_MIN = 2048
 _INT32_BUDGET = 2 ** 30  # headroom below int32 max for any partial sum
 
-
-def _try_jax():
-    try:
-        import jax
-        import jax.numpy as jnp
-        return jax, jnp
-    except ImportError:  # pragma: no cover - exercised on jax-less CI
-        return None, None
+# Process-wide totals of the device passes, so a caller that only sees the
+# public entry points can tell which levels ran on the device and how.
+# Partition passes fold their counters in on ``detach``, keyed by
+# (n, use_pallas, interpret): {"attaches", "syncs", "commits", "pass_scans"}.
+PARTITION_TOTALS: dict[tuple[int, bool, bool], dict[str, int]] = {}
+# Every ``DeviceScheduleWindows`` built, and every host sync it made.
+SCHEDULE_TOTALS = {"attaches": 0, "syncs": 0}
 
 
 def _integer_valued(a: np.ndarray) -> bool:
@@ -103,25 +104,20 @@ def _pow2(x: int) -> int:
 # Partition side
 # ==========================================================================
 
-def attach(state, cap: float, *, min_nodes: int | None = None,
-           interpret: bool | None = None):
+def attach(state, cap: float):
     """Build a ``DevicePartitionPass`` mirroring ``state``, or None.
 
-    Returns None -- caller falls back to the per-front path -- when jax is
-    unavailable, the instance is too small to pay for device dispatch, mu
-    is not integer-valued (the all-integer device program would not be
+    Returns None -- caller falls back to the per-front path -- when the
+    instance is too small to pay for device dispatch (``DEVICE_MIN_NODES``),
+    mu is not integer-valued (the all-integer device program would not be
     bit-identical), or an int32 partial sum could overflow.  On success the
     engine's ``device`` hook is set so every ``apply``/``undo`` keeps the
     device mirror in lockstep.
     """
-    jax, _ = _try_jax()
-    if jax is None:
-        return None
     if state.backend != "numpy" or state.device is not None:
         return None
     hg = state.hg
-    floor = DEVICE_MIN_NODES if min_nodes is None else min_nodes
-    if hg.n < floor:
+    if hg.n < DEVICE_MIN_NODES:
         return None
     if not _integer_valued(state.mu) or np.any(state.mu < 0):
         return None
@@ -140,11 +136,145 @@ def attach(state, cap: float, *, min_nodes: int | None = None,
         wsum = np.zeros(hg.n)
     if wsum.max(initial=0.0) * max(state.P - 1, 1) >= _INT32_BUDGET:
         return None
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    dev = DevicePartitionPass(state, cap, interpret=interpret)
+    dev = DevicePartitionPass(state, cap)
     state.device = dev
     return dev
+
+
+def find_program(mode: str, *, n: int, E: int, P: int, R_blk: int, pc,
+                 use_pallas: bool, interpret: bool):
+    """The jitted find program of a ``"fm"`` or ``"rep"`` pass.
+
+    ``n``/``E`` are the real node and edge counts (row n / E of the device
+    tables is the dummy), ``R_blk`` the pow2 rows of one front block and
+    ``pc`` the (2^P,) popcount-ordered column popcounts with the
+    ``_NO_COVER`` sentinel at column 0.  Everything else is an argument,
+    so the program can be lowered from shapes alone.
+    """
+    nsub = 1 << P
+    B_blk = R_blk // P
+    BIG = np.int32(np.iinfo(np.int32).max)
+    qbits = jnp.asarray((np.int64(1) << np.arange(P)).astype(np.int32))
+    allq = jnp.arange(P, dtype=jnp.int32)
+    Mp = -(-nsub // 128) * 128
+    is_rep = mode == "rep"
+
+    def dlam_of(rows, lam_old):
+        if use_pallas:
+            pc_p = pc
+            if Mp != nsub:
+                rows = jnp.pad(rows, ((0, 0), (0, Mp - nsub)),
+                               constant_values=1)
+                pc_p = jnp.pad(pc, (0, Mp - nsub), constant_values=_NO_COVER)
+            return front_dlam(rows, pc_p, lam_old, interpret=interpret)
+        lam_new = jnp.min(
+            jnp.where(rows == 0, pc[None, :], _NO_COVER),
+            axis=1).astype(jnp.int32)
+        return jnp.maximum(lam_new - 1, 0) - jnp.maximum(lam_old - 1, 0)
+
+    def find(uncov, lam, masks, mu, contrib, fits, prim, popcnt,
+             blk_edge, blk_pair, blk_node, blk_pos, active,
+             nb, b0, start_pos, resume_p, maxrep,
+             av, aold, anew, ae_win):
+        # fused apply: fold the last queued host mutation into this
+        # program (av = n with aold == anew encodes "nothing pending" --
+        # diff is all zeros, ae_win all-dummy, masks[n] is the dummy
+        # row), then run the scan on the updated buffers
+        adiff = contrib[anew] - contrib[aold]
+        avalid = ae_win < E
+        uncov = uncov.at[ae_win].add(
+            jnp.where(avalid[:, None], adiff[None, :], 0))
+        arows = uncov[ae_win]
+        alam = jnp.min(
+            jnp.where(arows == 0, pc[None, :], _NO_COVER),
+            axis=1).astype(jnp.int32)
+        lam = lam.at[ae_win].set(jnp.where(avalid, alam, lam[ae_win]))
+        masks = masks.at[av].set(anew)
+
+        def eval_block(b):
+            edges = blk_edge[b]
+            pairs = blk_pair[b]
+            nodes = blk_node[b]
+            poss = blk_pos[b]
+            m_old = masks[nodes]
+            qof = pairs % P
+            slot = pairs // P
+            m_row = m_old[slot]
+            rows0 = uncov[edges]
+            lam_old = lam[edges]
+            mu_row = mu[edges]
+            in_win = (poss >= start_pos) & (poss < n)
+
+            def deltas_for(cand_row):
+                rows = (rows0 + contrib[cand_row] - contrib[m_row])
+                terms = dlam_of(rows, lam_old) * mu_row
+                return jax.ops.segment_sum(
+                    terms, pairs,
+                    num_segments=B_blk * P).reshape(B_blk, P)
+
+            if not is_rep:
+                # FM: candidate masks 1 << q, primary excluded
+                d_move = deltas_for(qbits[qof])
+                feas = fits[nodes] & (allq[None, :]
+                                      != prim[m_old][:, None])
+                masked = jnp.where(feas, d_move, BIG)
+                bestq = jnp.argmin(masked, axis=1).astype(jnp.int32)
+                bestd = jnp.take_along_axis(
+                    masked, bestq[:, None], axis=1)[:, 0]
+                elig = (bestd <= -1) & in_win
+                sel = jnp.argmax(elig)
+                found = elig[sel]
+                return (jnp.where(found, poss[sel], n),
+                        jnp.int32(0),
+                        jnp.where(found, bestq[sel], 0))
+
+            # replication: add step then drop step, host visit order
+            k = popcnt[m_old]
+            unset = ((m_old[:, None] >> allq[None, :]) & 1) == 0
+            d_add = deltas_for(m_row | qbits[qof])
+            feas_add = fits[nodes] & unset & (k < maxrep)[:, None]
+            masked = jnp.where(feas_add, d_add, BIG)
+            bestq = jnp.argmin(masked, axis=1).astype(jnp.int32)
+            bestd = jnp.take_along_axis(
+                masked, bestq[:, None], axis=1)[:, 0]
+            resuming = resume_p >= 0
+            add_sup = resuming & (poss == start_pos)
+            has_add = (bestd <= -1) & in_win & ~add_sup
+            d_drop = deltas_for(m_row & ~qbits[qof])
+            minp = jnp.where(add_sup, resume_p, 0)
+            elig_drop = (~unset & (k > 1)[:, None] & (d_drop <= 0)
+                         & (allq[None, :] >= minp[:, None])
+                         & in_win[:, None])
+            dropp = jnp.argmax(elig_drop, axis=1).astype(jnp.int32)
+            has_drop = jnp.take_along_axis(
+                elig_drop, dropp[:, None], axis=1)[:, 0]
+            event = has_add | has_drop
+            sel = jnp.argmax(event)
+            found = event[sel]
+            kind = jnp.where(has_add[sel], 0, 1).astype(jnp.int32)
+            q = jnp.where(has_add[sel], bestq[sel], dropp[sel])
+            return (jnp.where(found, poss[sel], n), kind,
+                    jnp.where(found, q, 0))
+
+        def cond(c):
+            b, pos, _, _ = c
+            return (b < nb) & (pos >= n)
+
+        def body(c):
+            b = c[0]
+            pos, kind, q = jax.lax.cond(
+                active[b], eval_block,
+                lambda _b: (jnp.int32(n), jnp.int32(0), jnp.int32(0)), b)
+            return b + 1, pos, kind, q
+
+        _, pos, kind, q = jax.lax.while_loop(
+            cond, body,
+            (b0, jnp.int32(n), jnp.int32(0), jnp.int32(0)))
+        # donated buffers ride back out; the stacked triple keeps the
+        # host read down to a single transfer
+        return uncov, lam, masks, jnp.stack([pos, kind, q])
+
+    return functools.partial(jax.jit, donate_argnums=(0, 1, 2))(find)
 
 
 class DevicePartitionPass:
@@ -156,12 +286,13 @@ class DevicePartitionPass:
     a dummy node row n (infeasible everywhere) absorb all padding.
     """
 
-    def __init__(self, state, cap: float, *, interpret: bool) -> None:
-        jax, jnp = _try_jax()
-        self._jax, self._jnp = jax, jnp
+    def __init__(self, state, cap: float) -> None:
         self.state = state
         self.cap = float(cap)
-        self.interpret = bool(interpret)
+        # on a TPU the Pallas kernel always runs compiled; interpret mode
+        # exists only for the CPU backend, where ``ops.force("pallas")``
+        # selects it in tests
+        self.interpret = jax.default_backend() != "tpu"
         from .ops import _use_pallas
         self.use_pallas = _use_pallas()
         hg = state.hg
@@ -203,8 +334,11 @@ class DevicePartitionPass:
         self._last_loads = None
         self._dirty = np.zeros(self.n, dtype=bool)
         self._apply_fn = self._make_apply()
-        self._find_fm = self._make_find("fm")
-        self._find_rep = self._make_find("rep")
+        kw = dict(n=self.n, E=self.E, P=self.P, R_blk=self.R_blk,
+                  pc=self._pc, use_pallas=self.use_pallas,
+                  interpret=self.interpret)
+        self._find_fm = find_program("fm", **kw)
+        self._find_rep = find_program("rep", **kw)
         # instrumentation (sync = blocking device->host read)
         self.syncs = 0
         self.commits = 0
@@ -214,7 +348,6 @@ class DevicePartitionPass:
     # ------------------------------------------------------------ buffers
     def _refresh_from_host(self) -> None:
         """Full host -> device upload of uncov / lambdas / masks."""
-        jnp = self._jnp
         st = self.state
         self._pending.clear()   # host state already includes queued moves
         uncov_p = np.zeros((self.E + 1, self.nsub), dtype=np.int32)
@@ -232,6 +365,13 @@ class DevicePartitionPass:
 
     def detach(self) -> None:
         self.state.device = None
+        tot = PARTITION_TOTALS.setdefault(
+            (self.n, self.use_pallas, self.interpret),
+            {"attaches": 0, "syncs": 0, "commits": 0, "pass_scans": 0})
+        tot["attaches"] += 1
+        tot["syncs"] += self.syncs
+        tot["commits"] += self.commits
+        tot["pass_scans"] += self.pass_scans
 
     # -------------------------------------------------------- engine hook
     def apply(self, v: int, old: int, new: int) -> None:
@@ -255,7 +395,6 @@ class DevicePartitionPass:
         return w
 
     def _dispatch_apply(self, v: int, old: int, new: int) -> None:
-        jnp = self._jnp
         self._uncov, self._lam, self._masks = self._apply_fn(
             self._uncov, self._lam, self._masks,
             jnp.int32(v), jnp.int32(old), jnp.int32(new),
@@ -269,7 +408,6 @@ class DevicePartitionPass:
             self._dispatch_apply(v, old, new)
 
     def _make_apply(self):
-        jax, jnp = self._jax, self._jnp
         E = self.E
 
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
@@ -288,142 +426,9 @@ class DevicePartitionPass:
 
         return apply_
 
-    # ------------------------------------------------------- find programs
-    def _make_find(self, mode: str):
-        jax, jnp = self._jax, self._jnp
-        P, nsub = self.P, self.nsub
-        R_blk, B_blk = self.R_blk, self.B_blk
-        n = self.n
-        BIG = np.int32(np.iinfo(np.int32).max)
-        qbits = jnp.asarray((np.int64(1) << np.arange(P)).astype(np.int32))
-        allq = jnp.arange(P, dtype=jnp.int32)
-        use_pallas, interpret = self.use_pallas, self.interpret
-        Mp = -(-nsub // 128) * 128
-        is_rep = mode == "rep"
-
-        def dlam_of(rows, lam_old):
-            if use_pallas:
-                if Mp != nsub:
-                    rows = jnp.pad(rows, ((0, 0), (0, Mp - nsub)),
-                                   constant_values=1)
-                    pc = jnp.pad(self._pc, (0, Mp - nsub),
-                                 constant_values=_NO_COVER)
-                else:
-                    pc = self._pc
-                return front_dlam(rows, pc, lam_old, interpret=interpret)
-            lam_new = jnp.min(
-                jnp.where(rows == 0, self._pc[None, :], _NO_COVER),
-                axis=1).astype(jnp.int32)
-            return jnp.maximum(lam_new - 1, 0) - jnp.maximum(lam_old - 1, 0)
-
-        def find(uncov, lam, masks, mu, contrib, fits, prim, popcnt,
-                 blk_edge, blk_pair, blk_node, blk_pos, active,
-                 nb, b0, start_pos, resume_p, maxrep,
-                 av, aold, anew, ae_win):
-            # fused apply: fold the last queued host mutation into this
-            # program (av = n with aold == anew encodes "nothing pending" --
-            # diff is all zeros, ae_win all-dummy, masks[n] is the dummy
-            # row), then run the scan on the updated buffers
-            adiff = contrib[anew] - contrib[aold]
-            avalid = ae_win < self.E
-            uncov = uncov.at[ae_win].add(
-                jnp.where(avalid[:, None], adiff[None, :], 0))
-            arows = uncov[ae_win]
-            alam = jnp.min(
-                jnp.where(arows == 0, self._pc[None, :], _NO_COVER),
-                axis=1).astype(jnp.int32)
-            lam = lam.at[ae_win].set(jnp.where(avalid, alam, lam[ae_win]))
-            masks = masks.at[av].set(anew)
-
-            def eval_block(b):
-                edges = blk_edge[b]
-                pairs = blk_pair[b]
-                nodes = blk_node[b]
-                poss = blk_pos[b]
-                m_old = masks[nodes]
-                qof = pairs % P
-                slot = pairs // P
-                m_row = m_old[slot]
-                rows0 = uncov[edges]
-                lam_old = lam[edges]
-                mu_row = mu[edges]
-                in_win = (poss >= start_pos) & (poss < n)
-
-                def deltas_for(cand_row):
-                    rows = (rows0 + contrib[cand_row] - contrib[m_row])
-                    terms = dlam_of(rows, lam_old) * mu_row
-                    return jax.ops.segment_sum(
-                        terms, pairs,
-                        num_segments=B_blk * P).reshape(B_blk, P)
-
-                if not is_rep:
-                    # FM: candidate masks 1 << q, primary excluded
-                    d_move = deltas_for(qbits[qof])
-                    feas = fits[nodes] & (allq[None, :]
-                                          != prim[m_old][:, None])
-                    masked = jnp.where(feas, d_move, BIG)
-                    bestq = jnp.argmin(masked, axis=1).astype(jnp.int32)
-                    bestd = jnp.take_along_axis(
-                        masked, bestq[:, None], axis=1)[:, 0]
-                    elig = (bestd <= -1) & in_win
-                    sel = jnp.argmax(elig)
-                    found = elig[sel]
-                    return (jnp.where(found, poss[sel], n),
-                            jnp.int32(0),
-                            jnp.where(found, bestq[sel], 0))
-
-                # replication: add step then drop step, host visit order
-                k = popcnt[m_old]
-                unset = ((m_old[:, None] >> allq[None, :]) & 1) == 0
-                d_add = deltas_for(m_row | qbits[qof])
-                feas_add = fits[nodes] & unset & (k < maxrep)[:, None]
-                masked = jnp.where(feas_add, d_add, BIG)
-                bestq = jnp.argmin(masked, axis=1).astype(jnp.int32)
-                bestd = jnp.take_along_axis(
-                    masked, bestq[:, None], axis=1)[:, 0]
-                resuming = resume_p >= 0
-                add_sup = resuming & (poss == start_pos)
-                has_add = (bestd <= -1) & in_win & ~add_sup
-                d_drop = deltas_for(m_row & ~qbits[qof])
-                minp = jnp.where(add_sup, resume_p, 0)
-                elig_drop = (~unset & (k > 1)[:, None] & (d_drop <= 0)
-                             & (allq[None, :] >= minp[:, None])
-                             & in_win[:, None])
-                dropp = jnp.argmax(elig_drop, axis=1).astype(jnp.int32)
-                has_drop = jnp.take_along_axis(
-                    elig_drop, dropp[:, None], axis=1)[:, 0]
-                event = has_add | has_drop
-                sel = jnp.argmax(event)
-                found = event[sel]
-                kind = jnp.where(has_add[sel], 0, 1).astype(jnp.int32)
-                q = jnp.where(has_add[sel], bestq[sel], dropp[sel])
-                return (jnp.where(found, poss[sel], n), kind,
-                        jnp.where(found, q, 0))
-
-            def cond(c):
-                b, pos, _, _ = c
-                return (b < nb) & (pos >= n)
-
-            def body(c):
-                b = c[0]
-                pos, kind, q = jax.lax.cond(
-                    active[b], eval_block,
-                    lambda _b: (jnp.int32(n), jnp.int32(0), jnp.int32(0)), b)
-                return b + 1, pos, kind, q
-
-            _, pos, kind, q = jax.lax.while_loop(
-                cond, body,
-                (b0, jnp.int32(n), jnp.int32(0), jnp.int32(0)))
-            # donated buffers ride back out; the stacked triple keeps the
-            # host read down to a single transfer
-            return uncov, lam, masks, jnp.stack([pos, kind, q])
-
-        return functools.partial(jax.jit, donate_argnums=(0, 1, 2))(find)
-
     # ------------------------------------------------------- block builder
     def _build_blocks(self, perm: np.ndarray) -> None:
         """Pack the pass's flat (pair, edge) expansion into device blocks."""
-        jnp = self._jnp
         P, R_blk, B_blk = self.P, self.R_blk, self.B_blk
         n = len(perm)
         deg = self.deg[perm]
@@ -504,14 +509,14 @@ class DevicePartitionPass:
         for p in np.flatnonzero(changed):
             self._fits[:self.n, p] = st.omega + loads[p] <= self.cap
         self._last_loads = loads.copy()
-        return self._jnp.asarray(self._fits)
+        return jnp.asarray(self._fits)
 
     def _active_blocks(self, bnd_start: np.ndarray):
         av = (bnd_start | self._dirty)[self._perm]
         counts = np.add.reduceat(av.astype(np.int64), self._bounds[:-1])
         active = np.zeros(len(self._blk_edge), dtype=bool)
         active[:self._nb] = counts[:self._nb] > 0
-        return self._jnp.asarray(active)
+        return jnp.asarray(active)
 
     def _mark_dirty(self, v: int) -> None:
         hg = self.state.hg
@@ -520,7 +525,6 @@ class DevicePartitionPass:
 
     def _call_find(self, fn, b0: int, start_pos: int, resume_p: int,
                    maxrep: int, bnd_start: np.ndarray):
-        jnp = self._jnp
         # fold the newest queued mutation into this find (one dispatch per
         # committed move); older queue entries -- only possible after host-
         # side phases between passes -- still go out as standalone applies
@@ -624,9 +628,6 @@ class DevicePartitionPass:
 def schedule_device_supported(sched) -> bool:
     """Integer contract check: the fused int32 programs are bit-identical
     to the float64 numpy fronts only for integral weights/parameters."""
-    jax, _ = _try_jax()
-    if jax is None:
-        return False
     inst = sched.inst
     return (_integer_valued(inst.dag.mu) and _integer_valued(inst.dag.omega)
             and float(inst.L) == int(inst.L) and float(inst.g) == int(inst.g))
@@ -644,8 +645,6 @@ class DeviceScheduleWindows:
     """
 
     def __init__(self, sched) -> None:
-        jax, jnp = _try_jax()
-        self._jax, self._jnp = jax, jnp
         self.sched = sched
         self.P = sched.inst.P
         self.L = int(sched.inst.L)
@@ -654,12 +653,16 @@ class DeviceScheduleWindows:
         self._win_fns: dict = {}
         self.syncs = 0
         self.refreshes = 0
+        SCHEDULE_TOTALS["attaches"] += 1
+
+    def _synced(self) -> None:
+        self.syncs += 1
+        SCHEDULE_TOTALS["syncs"] += 1
 
     def mark_dirty(self) -> None:
         self._dirty = True
 
     def _refresh(self) -> None:
-        jnp = self._jnp
         s = self.sched
         self.S = s.S
         self.Sp = _pow2(self.S)
@@ -690,7 +693,6 @@ class DeviceScheduleWindows:
         fn = self._win_fns.get(key)
         if fn is not None:
             return fn
-        jax, jnp = self._jax, self._jnp
         L, g = self.L, self.g
 
         def step_cost(w1, h):
@@ -727,7 +729,6 @@ class DeviceScheduleWindows:
         """Fused-window twin of ``schedule_front.price_comm_moves``."""
         if self._dirty:
             self._refresh()
-        jnp = self._jnp
         sched = self.sched
         src, s = sched.comms[(v, dst)]
         mu = sched.inst.dag.mu[v]
@@ -738,7 +739,7 @@ class DeviceScheduleWindows:
         out = fn(self._sent, self._recv, self._stop, self._rtop, self._wtop,
                  self._scost, jnp.int32(lo), jnp.int32(src), jnp.int32(dst),
                  jnp.int32(int(mu)))
-        self.syncs += 1
+        self._synced()
         deltas = d0 + np.asarray(out[:W], dtype=np.float64)
         deltas[ts == s] = 0.0
         return deltas
@@ -747,7 +748,6 @@ class DeviceScheduleWindows:
         """Fused-window twin of ``schedule_front.price_comp_moves``."""
         if self._dirty:
             self._refresh()
-        jnp = self._jnp
         sched = self.sched
         s = sched.assign[v][p]
         om = sched.inst.dag.omega[v]
@@ -759,7 +759,7 @@ class DeviceScheduleWindows:
         out = fn(self._work, self._recv, self._stop, self._rtop, self._wtop,
                  self._scost, jnp.int32(lo), jnp.int32(p), jnp.int32(0),
                  jnp.int32(int(om)))
-        self.syncs += 1
+        self._synced()
         deltas = d_s + np.asarray(out[:W], dtype=np.float64)
         deltas[ts == s] = 0.0
         return deltas
@@ -769,7 +769,6 @@ class DeviceScheduleWindows:
         fn = self._win_fns.get(key)
         if fn is not None:
             return fn
-        jax, jnp = self._jax, self._jnp
         L, g = self.L, self.g
 
         def fold(work, sent, recv, scost, ts, dw, ds, dr):
@@ -798,7 +797,6 @@ class DeviceScheduleWindows:
             return price_node_moves(sched, v)
         if self._dirty:
             self._refresh()
-        jnp = self._jnp
         steps = sorted(cells)
         T = len(steps)
         Tp = _pow2(T)
@@ -814,7 +812,7 @@ class DeviceScheduleWindows:
                                 self._scost, jnp.asarray(ts),
                                 jnp.asarray(dw), jnp.asarray(ds),
                                 jnp.asarray(dr))
-        self.syncs += 1
+        self._synced()
         deltas = np.asarray(out, dtype=np.float64)
         deltas[p] = 0.0
         return deltas

@@ -41,6 +41,23 @@ def _jnp_lambda():
     return lam
 
 
+# Bytes of one (block_r, Mp) int32 input block.  Pallas double-buffers it
+# against the TPU's 16 MiB default scoped VMEM: a 512-row block at P = 12
+# (Mp = 4096) would need 16 MiB for that alone, while 2 MiB keeps every
+# P <= 12 inside the limit with room for the output and lambda blocks.
+_BLOCK_BYTES = 2 << 20
+
+
+def block_rows(Mp: int) -> int:
+    """Row-block size for a padded column width ``Mp`` (a multiple of 128).
+
+    A power of two between 8 and 512, so it divides every pow2 row count the
+    callers pad to (``R_blk >= 2048`` on the device pass).  Block size
+    changes only the kernel's tiling, never a value it returns.
+    """
+    return max(8, min(512, _BLOCK_BYTES // (Mp * 4)))
+
+
 # pow2 padding collapses front shapes onto a logarithmic family, but a long
 # multilevel run still visits many (Rp, Mp, block_r) triples across levels
 # and P values; an unbounded cache would pin every jitted executable for the
@@ -109,20 +126,19 @@ def _pallas_dlam_call(Rp: int, Mp: int, block_r: int, interpret: bool):
     ))
 
 
-def front_dlam(rows_perm, pc, lam_old, *, block_r: int = 512,
-               interpret: bool = False):
+def front_dlam(rows_perm, pc, lam_old, *, interpret: bool = False):
     """Per-row integer cost deltas for a candidate front (Pallas path).
 
     ``rows_perm`` is a (R, M) jnp int32 array of candidate uncov rows in
     popcount-column order (column 0 = subset 0), ``pc`` the (M,) popcounts
     with a ``_NO_COVER`` sentinel at column 0, ``lam_old`` the (R,) current
     edge lambdas.  Returns the (R,) int32 ``relu(lam_new-1)-relu(lam_old-1)``
-    terms.  Shapes must be pre-padded by the caller (rows to a multiple of
-    ``block_r``, columns to a multiple of 128): the device-resident pass
+    terms.  Shapes must be pre-padded by the caller (rows to a power of two
+    of at least 512, columns to a multiple of 128): the device-resident pass
     owns the padding, so this traces inside its jitted program.
     """
     R, M = rows_perm.shape
-    call = _pallas_dlam_call(R, M, block_r, interpret)
+    call = _pallas_dlam_call(R, M, block_rows(M), interpret)
     return call(rows_perm, pc.reshape(1, M),
                 lam_old.reshape(R, 1))[:, 0]
 
@@ -167,9 +183,10 @@ def _padded_rows(rows_perm: np.ndarray, Rp: int) -> np.ndarray:
 
 
 def _pallas_lambda(rows_perm: np.ndarray, pc: np.ndarray,
-                   block_r: int = 512, interpret: bool = False):
+                   interpret: bool = False):
     R, M = rows_perm.shape
     Mp = -(-M // 128) * 128
+    block_r = block_rows(Mp)
     # pow2 row padding (>= one block): ragged front sizes collapse onto a
     # logarithmic family of shapes, so the cached jitted pallas_call does
     # not recompile per front

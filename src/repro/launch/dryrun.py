@@ -60,7 +60,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     shd.set_active_mesh(mesh)
     shape = SHAPES[shape_name]
     try:
-        with shd.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             if shape.kind == "train":
                 ts = step_lib.build_train_step(cfg, mesh, plan=plan)
                 ab = input_specs(cfg, shape_name)

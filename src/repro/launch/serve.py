@@ -112,7 +112,7 @@ def main() -> None:
             plan = res.plan
             model = Model(cfg, plan=plan)
 
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.time()
         logits, caches = jax.jit(
             lambda p, b: model.prefill(p, b, max_len))(params, batch)

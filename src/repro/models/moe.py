@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.sharding import active_mesh, batch_axes, shard_map
+from ..parallel.sharding import active_mesh, batch_axes
 from .config import ModelConfig
 
 
@@ -278,7 +278,7 @@ def moe_tp(p: dict, x: jax.Array, cfg: ModelConfig, plan: PlacementPlan):
             aux = jax.lax.pmean(aux, dp)
         return y.reshape(B_loc, S, D), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(dp or None, None, None), P("model"), P("model"),
                   P("model"), P()),
@@ -345,7 +345,7 @@ def moe_a2a(p: dict, x: jax.Array, cfg: ModelConfig, plan: PlacementPlan):
         aux = jax.lax.pmean(aux, all_axes)
         return y.reshape(B_loc, S_loc, D), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(dp or None, "model", None), P("model"), P("model"),
                   P("model"), P()),

@@ -11,7 +11,6 @@ devices.
 """
 from __future__ import annotations
 
-import functools
 import re
 from typing import Any
 
@@ -20,31 +19,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 _ACTIVE_MESH: Mesh | None = None
-
-
-def use_mesh(mesh: Mesh):
-    """Version-compatible ``with use_mesh(mesh): ...`` context.
-
-    ``jax.set_mesh`` only exists on recent jax; older releases spell it
-    ``jax.sharding.use_mesh``; before that, ``Mesh`` itself is the context
-    manager that installs the global physical mesh.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
-
-
-def shard_map(f=None, **kw):
-    """Version-compatible ``jax.shard_map`` (older: jax.experimental)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, **kw) if f is not None else jax.shard_map(**kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    if "check_vma" in kw:  # renamed to check_rep in older jax
-        kw = dict(kw)
-        kw["check_rep"] = kw.pop("check_vma")
-    return _sm(f, **kw) if f is not None else functools.partial(_sm, **kw)
 
 
 def set_active_mesh(mesh: Mesh | None) -> None:

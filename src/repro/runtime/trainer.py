@@ -64,7 +64,7 @@ class Trainer:
         from ..models.model import Model
         model = Model(self.cfg,
                       n_ep_shards=self.mesh.shape.get("model", 1))
-        with shd.use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             params = jax.jit(
                 model.init,
                 out_shardings=self.ts.state_shardings["params"])(
@@ -95,7 +95,7 @@ class Trainer:
                 batch_np = self.data.next_batch()
                 self.failure_hook(step)  # test injection point
                 t0 = time.monotonic()
-                with shd.use_mesh(self.mesh):
+                with jax.set_mesh(self.mesh):
                     batch = jax.device_put(batch_np)
                     state, metrics = self.ts.step_fn(state, batch)
                     loss = float(metrics["loss"])

@@ -128,18 +128,3 @@ try:  # pragma: no cover - exercised implicitly by collection
 except ImportError:
     _install_hypothesis_fallback()
 
-
-# Environments without the jax toolchain (e.g. the CI runner) still test the
-# pure-python core; the accelerator-facing modules need jax at import time.
-try:  # pragma: no cover
-    import jax  # noqa: F401
-except ImportError:
-    collect_ignore = [
-        "test_elastic.py",
-        "test_front_pass.py",
-        "test_kernels.py",
-        "test_models_smoke.py",
-        "test_perf_knobs.py",
-        "test_sharding.py",
-        "test_substrate.py",
-    ]

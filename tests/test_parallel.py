@@ -19,7 +19,11 @@ The parallel layer's contract has three legs, each pinned here:
 Everything that needs a pool is skipped when POSIX shared memory is
 unavailable (e.g. /dev/shm-less sandboxes).
 """
+import os
+import pathlib
 import pickle
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -42,6 +46,7 @@ needs_shm = pytest.mark.skipif(not shm_available(),
                                reason="POSIX shared memory unavailable")
 
 START_METHODS = ["fork", "spawn"]
+TESTS = pathlib.Path(__file__).resolve().parent
 
 
 def small_hg(n=1200, seed=1):
@@ -233,46 +238,63 @@ def test_parallel_refine_cost_not_worse(kind, W):
         assert stats["workers"] == W and not stats["serial_fallback"]
 
 
-@needs_shm
-@pytest.mark.parametrize("method", START_METHODS)
-def test_both_start_methods(method):
-    import multiprocessing as mp
-    if method not in mp.get_all_start_methods():
-        pytest.skip(f"{method} start method unavailable")
+def _start_method_run(method):
+    """Replication refine + pooled matching through one ``method`` pool;
+    asserts the pool ran, matching stayed bit-identical and the state is
+    sound, and returns the reconciled masks."""
     hg = small_hg()
     res = partition_heuristic(hg, 4, 0.1, seed=0)
     st = PartitionState(hg, 4, masks=res.masks.copy())
     c0 = st.cost
     with ParallelContext(2, start_method=method, min_nodes=64) as ctx:
         parallel_refine(hg, st, 4, 0.1, ctx, "rep", 2, seed=3)
-        assert not ctx.failed
         # matching through the same pool: still bit-identical
         cm_p, _ = heavy_pin_matching(hg, 50.0, np.random.default_rng(7),
                                      ctx=ctx)
+        assert not ctx.failed
     cm_s, _ = heavy_pin_matching(hg, 50.0, np.random.default_rng(7))
     assert np.array_equal(cm_p, cm_s)
     assert st.cost <= c0 + 1e-9
     st.check()
+    return st.masks
+
+
+def _fork_run_in_fresh_process(out: pathlib.Path) -> np.ndarray:
+    """``_start_method_run("fork")`` in a new interpreter that never touches
+    jax: a process holding a JAX backend refuses to fork its pool, and the
+    test process may already hold one."""
+    code = (f"import sys; sys.path.insert(0, {str(TESTS)!r}); "
+            "import numpy as np, test_parallel as t; "
+            f"np.save({str(out)!r}, t._start_method_run('fork'))")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [str(TESTS.parent / "src"), os.environ.get("PYTHONPATH", "")]))
+    subprocess.run([sys.executable, "-c", code], check=True, env=env,
+                   timeout=300)
+    return np.load(out)
 
 
 @needs_shm
-def test_fork_and_spawn_agree():
+@pytest.mark.parametrize("method", START_METHODS)
+def test_both_start_methods(method, tmp_path):
+    import multiprocessing as mp
+    if method not in mp.get_all_start_methods():
+        pytest.skip(f"{method} start method unavailable")
+    if method == "fork":
+        _fork_run_in_fresh_process(tmp_path / "masks.npy")
+    else:
+        _start_method_run(method)
+
+
+@needs_shm
+def test_fork_and_spawn_agree(tmp_path):
     """Same worker count, same seeds -> the two start methods commit the
     same reconciled masks (worker results do not depend on how the
     process got its memory image)."""
     import multiprocessing as mp
     if "fork" not in mp.get_all_start_methods():
         pytest.skip("fork unavailable")
-    hg = small_hg()
-    res = partition_heuristic(hg, 4, 0.1, seed=0)
-    outs = []
-    for method in ("fork", "spawn"):
-        st = PartitionState(hg, 4, masks=res.masks.copy())
-        with ParallelContext(2, start_method=method, min_nodes=64) as ctx:
-            parallel_refine(hg, st, 4, 0.1, ctx, "rep", 2, seed=3)
-            assert not ctx.failed
-        outs.append(st.masks.copy())
-    assert np.array_equal(outs[0], outs[1])
+    forked = _fork_run_in_fresh_process(tmp_path / "masks.npy")
+    assert np.array_equal(forked, _start_method_run("spawn"))
 
 
 # ----------------------------------------------------- lifecycle / safety
@@ -298,8 +320,8 @@ def test_crash_cleanup_no_leaked_segments():
 
 @needs_shm
 def test_pool_failure_falls_back_serial():
-    """After a broken pool, parallel_refine still refines (serially) and
-    the context reports failed."""
+    """After a broken pool, parallel_refine still refines (serially), warns
+    that it did, and the context reports failed."""
     hg = small_hg()
     res = partition_heuristic(hg, 4, 0.1, seed=0)
     st = PartitionState(hg, 4, masks=res.masks.copy())
@@ -307,8 +329,9 @@ def test_pool_failure_falls_back_serial():
     with ParallelContext(2, min_nodes=64) as ctx:
         with pytest.raises(Exception):
             ctx.run(par._crash_task, [(None,)])
-        stats = parallel_refine(hg, st, 4, 0.1, ctx, "rep", 2, seed=3)
-    assert stats["serial_fallback"] or ctx.failed
+        with pytest.warns(par.SerialFallbackWarning):
+            stats = parallel_refine(hg, st, 4, 0.1, ctx, "rep", 2, seed=3)
+    assert stats["serial_fallback"] and ctx.failed
     assert st.cost <= c0 + 1e-9
     st.check()
 

@@ -31,7 +31,7 @@ shd.set_active_mesh(mesh)
 # 1) sharded train step compiles AND runs for a dense + a MoE arch
 for arch in ("smollm-135m", "olmoe-1b-7b"):
     cfg = reduce_config(get_config(arch)).with_(strategy="tp")
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         ts = step_lib.build_train_step(cfg, mesh,
                                        adamw.AdamWConfig(lr=5e-3, warmup_steps=1, total_steps=8))
         from repro.models.model import Model as M
@@ -67,7 +67,7 @@ shd.set_active_mesh(mesh)
 masks = np.array([0b1111 if e < 2 else (1 << (e % 4))
                   for e in range(cfg.n_experts)])
 plan = plan_from_masks(masks, cfg.n_experts, 4, capacity_factor=8.0)
-with shd.use_mesh(mesh):
+with jax.set_mesh(mesh):
     model_r = Model(cfg, plan=plan)
     loss_rep, _ = jax.jit(model_r.loss)(params, batch)
 out["placement"] = [float(loss_ref), float(loss_rep)]

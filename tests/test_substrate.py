@@ -16,7 +16,16 @@ from repro.datagen import synthetic_trace
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import Model
 from repro.optim import adamw
+from repro.parallel import sharding as shd
 from repro.runtime.trainer import Trainer, TrainerConfig
+
+
+@pytest.fixture(autouse=True)
+def _reset_active_mesh():
+    """A ``Trainer`` installs its mesh process-wide; drop it after each test
+    so later tests in the same worker see the no-mesh (dense) model path."""
+    yield
+    shd.set_active_mesh(None)
 
 
 def test_data_determinism_and_resume():
