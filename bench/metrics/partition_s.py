@@ -1,0 +1,8 @@
+"""Seconds per replicated partition: the whole window's wall time over the
+solves completed in it (each solve ends on its result on the host)."""
+
+
+def read(ctx):
+    if ctx.kind != "partition" or not ctx.solves:
+        return None
+    return ctx.window_s / ctx.solves
