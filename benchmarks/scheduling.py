@@ -397,7 +397,7 @@ def device_scale(P=8, g=4, L=20):
     pricers and with the device window pricers (``backend="jax"``); both
     are decision-identical, so costs must match and the only deliverable
     difference is wall-clock.  A per-instance instrumented
-    ``DeviceScheduleWindows`` records host syncs and full refreshes for
+    ``DeviceScheduleWindows`` records host syncs and uploaded bytes for
     the ``device_resident`` rows in ``BENCH_schedule.json``.
     """
     from repro.core.frontier import device_windows
@@ -425,7 +425,7 @@ def device_scale(P=8, g=4, L=20):
         assert win is not None, f"{name}: device windows did not attach"
         for v in range(0, probe.inst.dag.n, 7):
             win.price_node_moves(v)
-        syncs, refreshes = win.syncs, win.refreshes
+        syncs, h2d_bytes = win.syncs, win.h2d_bytes
         rows.append({
             "name": name, "n": dag.n, "P": P, "g": g, "L": L,
             "seconds_numpy": t1 - t0,
@@ -433,7 +433,7 @@ def device_scale(P=8, g=4, L=20):
             "seconds_device_cold": t2 - t1,
             "speedup_vs_numpy": (t1 - t0) / max(t3 - t2, 1e-9),
             "cost": float(hc_np.current_cost()),
-            "probe_syncs": syncs, "probe_refreshes": refreshes,
+            "probe_syncs": syncs, "probe_h2d_bytes": h2d_bytes,
         })
     return rows
 

@@ -46,6 +46,7 @@ import dataclasses
 
 import numpy as np
 
+from ...spans import span
 from ..hypergraph import Hypergraph
 from .engine import _MAX_P, PartitionState
 from .heuristic import (HeuristicResult, fm_refine, partition_heuristic,
@@ -306,22 +307,27 @@ def multilevel_partition(hg: Hypergraph, P: int, eps: float,
         # at-or-below the coarsest size the V-cycle *is* the flat
         # heuristic -- call it with its own defaults so the two paths are
         # literally identical there
-        return partition_heuristic(hg, P, eps, seed=seed, frontier=frontier)
+        with span("partition.initial", n=hg.n):
+            return partition_heuristic(hg, P, eps, seed=seed,
+                                       frontier=frontier)
     rng = np.random.default_rng(seed)
     ctx = _make_ctx(workers)
     try:
-        levels, cmaps, edge_maps = build_levels(hg, P, eps, opts, rng,
-                                                ctx=ctx)
+        with span("partition.coarsen"):
+            levels, cmaps, edge_maps = build_levels(hg, P, eps, opts, rng,
+                                                    ctx=ctx)
         if not cmaps:
             # matching stagnated immediately (e.g. every edge above
             # max_edge_size, or a weight cap below any pair): no coarse
             # level exists, so the V-cycle degenerates to the flat heuristic
-            return partition_heuristic(hg, P, eps, seed=seed,
-                                       frontier=frontier)
-        res = partition_heuristic(levels[-1], P, eps,
-                                  restarts=opts.restarts,
-                                  seed=seed, frontier=frontier)
-        st = PartitionState(levels[-1], P, masks=res.masks)
+            with span("partition.initial", n=hg.n):
+                return partition_heuristic(hg, P, eps, seed=seed,
+                                           frontier=frontier)
+        with span("partition.initial", n=levels[-1].n):
+            res = partition_heuristic(levels[-1], P, eps,
+                                      restarts=opts.restarts,
+                                      seed=seed, frontier=frontier)
+            st = PartitionState(levels[-1], P, masks=res.masks)
         if stats is not None:
             stats.append({"level": len(levels) - 1, "n": levels[-1].n,
                           "edges": len(levels[-1].edges),
@@ -331,13 +337,14 @@ def multilevel_partition(hg: Hypergraph, P: int, eps: float,
         for li in sorted(_refinement_schedule(len(levels),
                                               opts.refine_every),
                          reverse=True):
-            cmap, emap = _compose_maps(cmaps, edge_maps, li, prev)
-            st = _project_state(levels[li], P, st, cmap, emap)
-            prev = li
-            projected = float(st.cost)
-            _fm_stop(levels[li], st, P, eps, rng,
-                     opts.final_fm_passes if li == 0 else opts.fm_passes,
-                     frontier, ctx, seed + 101 * li)
+            with span("partition.level", level=li, n=levels[li].n):
+                cmap, emap = _compose_maps(cmaps, edge_maps, li, prev)
+                st = _project_state(levels[li], P, st, cmap, emap)
+                prev = li
+                projected = float(st.cost)
+                _fm_stop(levels[li], st, P, eps, rng,
+                         opts.final_fm_passes if li == 0 else opts.fm_passes,
+                         frontier, ctx, seed + 101 * li)
             if stats is not None:
                 stats.append({"level": li, "n": levels[li].n,
                               "edges": len(levels[li].edges),
@@ -385,55 +392,61 @@ def partition_with_replication_multilevel(
     """
     opts = opts or MultilevelOptions()
     if P > _MAX_P or hg.n <= opts.coarsest_n:
-        return partition_with_replication(hg, P, eps, mode=mode,
-                                          exact_node_limit=0, seed=seed,
-                                          frontier=frontier)
+        with span("partition.initial", n=hg.n):
+            return partition_with_replication(hg, P, eps, mode=mode,
+                                              exact_node_limit=0, seed=seed,
+                                              frontier=frontier)
     max_replicas = 2 if mode == "dup" else None
     rng = np.random.default_rng(seed)
     ctx = _make_ctx(workers)
     try:
-        levels, cmaps, edge_maps = build_levels(hg, P, eps, opts, rng,
-                                                ctx=ctx)
+        with span("partition.coarsen"):
+            levels, cmaps, edge_maps = build_levels(hg, P, eps, opts, rng,
+                                                    ctx=ctx)
         if not cmaps:  # immediate stagnation: no coarse level (cf. above)
-            return partition_with_replication(hg, P, eps, mode=mode,
-                                              exact_node_limit=0, seed=seed,
-                                              frontier=frontier)
-        base_res = partition_heuristic(levels[-1], P, eps,
-                                       restarts=opts.restarts, seed=seed,
-                                       frontier=frontier)
-        base_st = PartitionState(levels[-1], P, masks=base_res.masks)
-        rep_res = replicate_local_search(levels[-1], base_res.masks.copy(),
-                                         P, eps, max_replicas=max_replicas,
-                                         seed=seed, frontier=frontier)
-        rep_st = PartitionState(levels[-1], P, masks=rep_res.masks)
+            with span("partition.initial", n=hg.n):
+                return partition_with_replication(
+                    hg, P, eps, mode=mode, exact_node_limit=0, seed=seed,
+                    frontier=frontier)
+        with span("partition.initial", n=levels[-1].n):
+            base_res = partition_heuristic(levels[-1], P, eps,
+                                           restarts=opts.restarts, seed=seed,
+                                           frontier=frontier)
+            base_st = PartitionState(levels[-1], P, masks=base_res.masks)
+            rep_res = replicate_local_search(
+                levels[-1], base_res.masks.copy(), P, eps,
+                max_replicas=max_replicas, seed=seed, frontier=frontier)
+            rep_st = PartitionState(levels[-1], P, masks=rep_res.masks)
         prev = len(levels) - 1
         for li in sorted(_refinement_schedule(len(levels),
                                               opts.refine_every),
                          reverse=True):
             fine = levels[li]
             finest = li == 0
-            cmap, emap = _compose_maps(cmaps, edge_maps, li, prev)
-            base_st = _project_state(fine, P, base_st, cmap, emap)
-            _fm_stop(fine, base_st, P, eps, rng,
-                     opts.final_fm_passes if finest else opts.fm_passes,
-                     frontier, ctx, seed + 101 * li)
-            rep_st = _project_state(fine, P, rep_st, cmap, emap)
-            prev = li
-            projected = float(rep_st.cost)
-            passes = opts.final_rep_passes if finest else opts.rep_passes
-            rep = _rep_stop(fine, rep_st, P, eps, passes, max_replicas,
-                            frontier, ctx, seed)
-            if finest and rep.cost > base_st.cost - 1e-12:
-                # alternation seed at the finest level: replicate from the
-                # refined base masks -- only needed when the projected
-                # stream did not already beat the base (guarantees
-                # rep <= base)
-                alt_st = PartitionState(fine, P,
-                                        masks=base_st.masks.copy())
-                alt = _rep_stop(fine, alt_st, P, eps, passes, max_replicas,
-                                frontier, ctx, seed + li + 1)
-                if alt.cost < rep.cost - 1e-12:
-                    rep = alt
+            with span("partition.level", level=li, n=fine.n):
+                cmap, emap = _compose_maps(cmaps, edge_maps, li, prev)
+                base_st = _project_state(fine, P, base_st, cmap, emap)
+                _fm_stop(fine, base_st, P, eps, rng,
+                         opts.final_fm_passes if finest else opts.fm_passes,
+                         frontier, ctx, seed + 101 * li)
+                rep_st = _project_state(fine, P, rep_st, cmap, emap)
+                prev = li
+                projected = float(rep_st.cost)
+                passes = opts.final_rep_passes if finest else opts.rep_passes
+                rep = _rep_stop(fine, rep_st, P, eps, passes, max_replicas,
+                                frontier, ctx, seed)
+                if finest and rep.cost > base_st.cost - 1e-12:
+                    # alternation seed at the finest level: replicate from
+                    # the refined base masks -- only needed when the
+                    # projected stream did not already beat the base
+                    # (guarantees rep <= base)
+                    alt_st = PartitionState(fine, P,
+                                            masks=base_st.masks.copy())
+                    alt = _rep_stop(fine, alt_st, P, eps, passes,
+                                    max_replicas, frontier, ctx,
+                                    seed + li + 1)
+                    if alt.cost < rep.cost - 1e-12:
+                        rep = alt
             if stats is not None:
                 stats.append({"level": li, "n": fine.n,
                               "edges": len(fine.edges),
@@ -447,16 +460,17 @@ def partition_with_replication_multilevel(
         # primary copies, replicate again, keep while it improves (cf.
         # heuristic.py)
         for r in range(opts.alternations):
-            masks = best.masks.copy()
-            primary = np.array([1 << (int(m).bit_length() - 1)
-                                for m in masks])
-            alt_rng = np.random.default_rng(seed + r + 1)
-            fm_st = PartitionState(hg, P, masks=primary.copy())
-            _fm_stop(hg, fm_st, P, eps, alt_rng, opts.final_fm_passes,
-                     frontier, ctx, seed + r + 1)
-            rls_st = PartitionState(hg, P, masks=fm_st.masks.copy())
-            cand = _rep_stop(hg, rls_st, P, eps, opts.final_rep_passes,
-                             max_replicas, frontier, ctx, seed + r + 1)
+            with span("partition.alternate", round=r):
+                masks = best.masks.copy()
+                primary = np.array([1 << (int(m).bit_length() - 1)
+                                    for m in masks])
+                alt_rng = np.random.default_rng(seed + r + 1)
+                fm_st = PartitionState(hg, P, masks=primary.copy())
+                _fm_stop(hg, fm_st, P, eps, alt_rng, opts.final_fm_passes,
+                         frontier, ctx, seed + r + 1)
+                rls_st = PartitionState(hg, P, masks=fm_st.masks.copy())
+                cand = _rep_stop(hg, rls_st, P, eps, opts.final_rep_passes,
+                                 max_replicas, frontier, ctx, seed + r + 1)
             if cand.cost < best.cost - 1e-12:
                 best = cand
             else:

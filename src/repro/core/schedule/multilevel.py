@@ -68,6 +68,7 @@ import dataclasses
 
 import numpy as np
 
+from ...spans import span
 from ..hypergraph import Dag
 from .bsp import BspInstance, Schedule
 from .list_sched import (comp_rebalance_pass, dag_levels, node_move_pass,
@@ -388,8 +389,10 @@ def multilevel_schedule(inst: BspInstance,
     opts = opts or MultilevelScheduleOptions()
     dag = inst.dag
     if dag.n <= opts.coarsest_n:
-        return best_replicated_schedule(inst, baseline=baseline,
-                                        opts=adv_opts, seed=seed)
+        # a one-level stack: the flat solve is the initial solve
+        with span("schedule.initial", n=dag.n):
+            return best_replicated_schedule(inst, baseline=baseline,
+                                            opts=adv_opts, seed=seed)
     rng = np.random.default_rng(seed)
     ctx = None
     if workers is not None and workers > 1:
@@ -401,14 +404,16 @@ def multilevel_schedule(inst: BspInstance,
                 ctx = ParallelContext(workers)
             else:
                 warn_serial("POSIX shared memory unavailable")
-    try:
-        levels, cmaps = build_levels(dag, inst.P, opts, rng, ctx=ctx)
-    finally:
-        if ctx is not None:
-            ctx.close()
+    with span("schedule.coarsen"):
+        try:
+            levels, cmaps = build_levels(dag, inst.P, opts, rng, ctx=ctx)
+        finally:
+            if ctx is not None:
+                ctx.close()
     if not cmaps:  # immediate stagnation: no coarse level exists
-        return best_replicated_schedule(inst, baseline=baseline,
-                                        opts=adv_opts, seed=seed)
+        with span("schedule.initial", n=dag.n):
+            return best_replicated_schedule(inst, baseline=baseline,
+                                            opts=adv_opts, seed=seed)
     coarse_inst = BspInstance(levels[-1], inst.P, inst.g, inst.L)
     # coarse solve: advanced heuristic from the PARALLEL seed only.  The
     # flat best-of would often pick the sequential schedule here -- coarse
@@ -418,8 +423,9 @@ def multilevel_schedule(inst: BspInstance,
     # leave (every move needs a later superstep to deliver into).
     from .list_sched import bspg_schedule, hill_climb
 
-    par = hill_climb(bspg_schedule(coarse_inst, seed=seed), seed=seed)
-    sched = advanced_heuristic(par, adv_opts)
+    with span("schedule.initial", n=levels[-1].n):
+        par = hill_climb(bspg_schedule(coarse_inst, seed=seed), seed=seed)
+        sched = advanced_heuristic(par, adv_opts)
     if stats is not None:
         stats.append({"level": len(levels) - 1, "n": levels[-1].n,
                       "S": sched.S,
@@ -428,13 +434,14 @@ def multilevel_schedule(inst: BspInstance,
     prev = len(levels) - 1
     for li in sorted(_refinement_schedule(len(levels), opts.refine_every),
                      reverse=True):
-        cmap = _compose_cmaps(cmaps, li, prev)
-        li_inst = inst if li == 0 else BspInstance(levels[li], inst.P,
-                                                   inst.g, inst.L)
-        sched = Schedule.from_projection(li_inst, sched, cmap)
-        prev = li
-        projected = float(sched.current_cost())
-        _refine_level(sched, li == 0, opts, seed + li, adv_opts=adv_opts)
+        with span("schedule.level", level=li, n=levels[li].n):
+            cmap = _compose_cmaps(cmaps, li, prev)
+            li_inst = inst if li == 0 else BspInstance(levels[li], inst.P,
+                                                       inst.g, inst.L)
+            sched = Schedule.from_projection(li_inst, sched, cmap)
+            prev = li
+            projected = float(sched.current_cost())
+            _refine_level(sched, li == 0, opts, seed + li, adv_opts=adv_opts)
         if stats is not None:
             stats.append({"level": li, "n": levels[li].n, "S": sched.S,
                           "cost_projected": projected,

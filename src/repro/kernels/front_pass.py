@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..spans import span
 from .gain import _NO_COVER, front_dlam
 
 # Below this node count the per-front numpy path wins (device dispatch and
@@ -85,10 +86,12 @@ _INT32_BUDGET = 2 ** 30  # headroom below int32 max for any partial sum
 # Process-wide totals of the device passes, so a caller that only sees the
 # public entry points can tell which levels ran on the device and how.
 # Partition passes fold their counters in on ``detach``, keyed by
-# (n, use_pallas, interpret): {"attaches", "syncs", "commits", "pass_scans"}.
+# (n, use_pallas, interpret): {"attaches", "syncs", "commits", "pass_scans",
+# "h2d_bytes"}.
 PARTITION_TOTALS: dict[tuple[int, bool, bool], dict[str, int]] = {}
-# Every ``DeviceScheduleWindows`` built, and every host sync it made.
-SCHEDULE_TOTALS = {"attaches": 0, "syncs": 0}
+# Every ``DeviceScheduleWindows`` built, every host sync it made and every
+# byte it uploaded.
+SCHEDULE_TOTALS = {"attaches": 0, "syncs": 0, "h2d_bytes": 0}
 
 
 def _integer_valued(a: np.ndarray) -> bool:
@@ -136,7 +139,8 @@ def attach(state, cap: float):
         wsum = np.zeros(hg.n)
     if wsum.max(initial=0.0) * max(state.P - 1, 1) >= _INT32_BUDGET:
         return None
-    dev = DevicePartitionPass(state, cap)
+    with span("device.attach", n=hg.n):
+        dev = DevicePartitionPass(state, cap)
     state.device = dev
     return dev
 
@@ -289,6 +293,7 @@ class DevicePartitionPass:
     def __init__(self, state, cap: float) -> None:
         self.state = state
         self.cap = float(cap)
+        self.h2d_bytes = 0   # host -> device bytes uploaded (``_put``)
         # on a TPU the Pallas kernel always runs compiled; interpret mode
         # exists only for the CPU backend, where ``ops.force("pallas")``
         # selects it in tests
@@ -312,19 +317,18 @@ class DevicePartitionPass:
             ([0], np.asarray(state._order, dtype=np.int64)))
         pc_p = np.concatenate(
             ([_NO_COVER], np.asarray(state._order_pc, dtype=np.int64)))
-        self._pc = jnp.asarray(pc_p.astype(np.int32))
-        self._contrib = jnp.asarray(
+        self._pc = self._put(pc_p.astype(np.int32))
+        self._contrib = self._put(
             np.ascontiguousarray(state._contrib[:, self.colmap],
                                  dtype=np.int32))
-        popc = np.asarray(state.popcnt, dtype=np.int32)
-        self._popcnt = jnp.asarray(popc)
+        self._popcnt = self._put(np.asarray(state.popcnt, dtype=np.int32))
         prim = np.maximum(
             np.array([int(m).bit_length() - 1 for m in range(self.nsub)],
                      dtype=np.int32), 0)
-        self._prim = jnp.asarray(prim)
+        self._prim = self._put(prim)
         mu_i = np.zeros(self.E + 1, dtype=np.int32)
         mu_i[:self.E] = np.rint(state.mu).astype(np.int32)
-        self._mu = jnp.asarray(mu_i)
+        self._mu = self._put(mu_i)
         self._owner = np.repeat(np.arange(self.n), self.deg)  # bnd scatter
         # mutation queue: host applies are *deferred* and fused into the
         # next find program, so a committed move costs one dispatch, not two
@@ -346,32 +350,44 @@ class DevicePartitionPass:
         self.apply_dispatches = 0  # standalone apply programs dispatched
 
     # ------------------------------------------------------------ buffers
+    def _put(self, a: np.ndarray) -> jax.Array:
+        """Upload one host array, counted in ``h2d_bytes``."""
+        self.h2d_bytes += a.nbytes
+        return jnp.asarray(a)
+
+    def _scalars(self, *xs: int) -> list:
+        """Upload int32 scalars, counted in ``h2d_bytes``."""
+        self.h2d_bytes += 4 * len(xs)
+        return [jnp.int32(x) for x in xs]
+
     def _refresh_from_host(self) -> None:
         """Full host -> device upload of uncov / lambdas / masks."""
         st = self.state
         self._pending.clear()   # host state already includes queued moves
         uncov_p = np.zeros((self.E + 1, self.nsub), dtype=np.int32)
         uncov_p[:self.E] = st.uncov[:, self.colmap]
-        self._uncov = jnp.asarray(uncov_p)
+        self._uncov = self._put(uncov_p)
         # device lambda: masked-min value; differs from the engine's only
         # on rows with no assigned pins (engine 0, masked-min 1) -- the
         # relu(cost) terms agree, so deltas are unaffected
         lam = np.ones(self.E + 1, dtype=np.int32)
         lam[:self.E] = np.where(st.uncov[:, 0] == 0, 1, st.edge_lambda)
-        self._lam = jnp.asarray(lam)
+        self._lam = self._put(lam)
         masks = np.ones(self.n + 1, dtype=np.int32)
         masks[:self.n] = st.masks
-        self._masks = jnp.asarray(masks)
+        self._masks = self._put(masks)
 
     def detach(self) -> None:
         self.state.device = None
         tot = PARTITION_TOTALS.setdefault(
             (self.n, self.use_pallas, self.interpret),
-            {"attaches": 0, "syncs": 0, "commits": 0, "pass_scans": 0})
+            {"attaches": 0, "syncs": 0, "commits": 0, "pass_scans": 0,
+             "h2d_bytes": 0})
         tot["attaches"] += 1
         tot["syncs"] += self.syncs
         tot["commits"] += self.commits
         tot["pass_scans"] += self.pass_scans
+        tot["h2d_bytes"] += self.h2d_bytes
 
     # -------------------------------------------------------- engine hook
     def apply(self, v: int, old: int, new: int) -> None:
@@ -397,8 +413,8 @@ class DevicePartitionPass:
     def _dispatch_apply(self, v: int, old: int, new: int) -> None:
         self._uncov, self._lam, self._masks = self._apply_fn(
             self._uncov, self._lam, self._masks,
-            jnp.int32(v), jnp.int32(old), jnp.int32(new),
-            jnp.asarray(self._edge_window(v)), self._contrib, self._pc)
+            *self._scalars(v, old, new), self._put(self._edge_window(v)),
+            self._contrib, self._pc)
         self.apply_dispatches += 1
 
     def flush(self) -> None:
@@ -476,10 +492,10 @@ class DevicePartitionPass:
             blk_pos[b, :i1 - i0] = np.arange(i0, i1)
         self._bounds = bounds
         self._nb = NB
-        self._blk_edge = jnp.asarray(blk_edge)
-        self._blk_pair = jnp.asarray(blk_pair)
-        self._blk_node = jnp.asarray(blk_node)
-        self._blk_pos = jnp.asarray(blk_pos)
+        self._blk_edge = self._put(blk_edge)
+        self._blk_pair = self._put(blk_pair)
+        self._blk_node = self._put(blk_node)
+        self._blk_pos = self._put(blk_pos)
 
     # --------------------------------------------------------- host helpers
     def _boundary_start(self, rep: bool) -> np.ndarray:
@@ -509,14 +525,14 @@ class DevicePartitionPass:
         for p in np.flatnonzero(changed):
             self._fits[:self.n, p] = st.omega + loads[p] <= self.cap
         self._last_loads = loads.copy()
-        return jnp.asarray(self._fits)
+        return self._put(self._fits)
 
     def _active_blocks(self, bnd_start: np.ndarray):
         av = (bnd_start | self._dirty)[self._perm]
         counts = np.add.reduceat(av.astype(np.int64), self._bounds[:-1])
         active = np.zeros(len(self._blk_edge), dtype=bool)
         active[:self._nb] = counts[:self._nb] > 0
-        return jnp.asarray(active)
+        return self._put(active)
 
     def _mark_dirty(self, v: int) -> None:
         hg = self.state.hg
@@ -525,27 +541,30 @@ class DevicePartitionPass:
 
     def _call_find(self, fn, b0: int, start_pos: int, resume_p: int,
                    maxrep: int, bnd_start: np.ndarray):
-        # fold the newest queued mutation into this find (one dispatch per
-        # committed move); older queue entries -- only possible after host-
-        # side phases between passes -- still go out as standalone applies
-        if self._pending:
-            *older, (av, aold, anew) = self._pending
-            self._pending = []
-            for ov, oold, onew in older:
-                self._dispatch_apply(ov, oold, onew)
-        else:
-            av, aold, anew = self.n, 1, 1   # no-op: dummy row, zero diff
-        self._uncov, self._lam, self._masks, out = fn(
-            self._uncov, self._lam, self._masks, self._mu,
-            self._contrib, self._fits_now(), self._prim, self._popcnt,
-            self._blk_edge, self._blk_pair, self._blk_node,
-            self._blk_pos, self._active_blocks(bnd_start),
-            jnp.int32(self._nb), jnp.int32(b0), jnp.int32(start_pos),
-            jnp.int32(resume_p), jnp.int32(maxrep),
-            jnp.int32(av), jnp.int32(aold), jnp.int32(anew),
-            jnp.asarray(self._edge_window(av)))
-        pos, kind, q = (int(x) for x in np.asarray(out))  # THE host sync
-        self.syncs += 1
+        with span("device.find"):
+            # fold the newest queued mutation into this find (one dispatch
+            # per committed move); older queue entries -- only possible
+            # after host-side phases between passes -- still go out as
+            # standalone applies
+            if self._pending:
+                *older, (av, aold, anew) = self._pending
+                self._pending = []
+                for ov, oold, onew in older:
+                    self._dispatch_apply(ov, oold, onew)
+            else:
+                av, aold, anew = self.n, 1, 1  # no-op: dummy row, zero diff
+            self._uncov, self._lam, self._masks, out = fn(
+                self._uncov, self._lam, self._masks, self._mu,
+                self._contrib, self._fits_now(), self._prim, self._popcnt,
+                self._blk_edge, self._blk_pair, self._blk_node,
+                self._blk_pos, self._active_blocks(bnd_start),
+                *self._scalars(self._nb, b0, start_pos, resume_p, maxrep,
+                               av, aold, anew),
+                self._put(self._edge_window(av)))
+            with span("device.wait"):
+                host = np.asarray(out)          # THE host sync
+            self.syncs += 1
+        pos, kind, q = (int(x) for x in host)
         return pos, kind, q
 
     def _block_of(self, pos: int) -> int:
@@ -562,63 +581,66 @@ class DevicePartitionPass:
         return st.masks
 
     def fm_pass(self, perm: np.ndarray) -> bool:
-        st = self.state
-        self._perm = np.asarray(perm, dtype=np.int64)
-        self._dirty[:] = False
-        bnd = self._boundary_start(rep=False)
-        self._build_blocks(self._perm)
-        pos, improved = 0, False
-        while pos < self.n:
-            fpos, _, q = self._call_find(self._find_fm, self._block_of(pos),
-                                         pos, -1, 0, bnd)
-            if fpos >= self.n:
+        with span("device.pass", mode="fm"):
+            st = self.state
+            self._perm = np.asarray(perm, dtype=np.int64)
+            self._dirty[:] = False
+            bnd = self._boundary_start(rep=False)
+            self._build_blocks(self._perm)
+            pos, improved = 0, False
+            while pos < self.n:
+                fpos, _, q = self._call_find(
+                    self._find_fm, self._block_of(pos), pos, -1, 0, bnd)
+                if fpos >= self.n:
+                    self.pass_scans += 1
+                    break
+                v = int(self._perm[fpos])
+                st.apply(v, 1 << q)
+                st.commit()
+                self.commits += 1
+                self._mark_dirty(v)
+                improved = True
+                pos = fpos + 1
+            else:
                 self.pass_scans += 1
-                break
-            v = int(self._perm[fpos])
-            st.apply(v, 1 << q)
-            st.commit()
-            self.commits += 1
-            self._mark_dirty(v)
-            improved = True
-            pos = fpos + 1
-        else:
-            self.pass_scans += 1
-        return improved
+            return improved
 
     # ----------------------------------------------------- replication pass
     def rep_pass(self, perm: np.ndarray, max_replicas: int | None) -> bool:
         """Device-resident add/drop node sweep of ``replicate_local_search``
         (the edge-guided phase stays on the host engine; its mutations reach
         the device through the engine hook)."""
-        st = self.state
-        self._perm = np.asarray(perm, dtype=np.int64)
-        self._dirty[:] = False
-        bnd = self._boundary_start(rep=True)
-        self._build_blocks(self._perm)
-        maxrep = self.P + 1 if max_replicas is None else int(max_replicas)
-        pos, resume_p, improved = 0, -1, False
-        while pos < self.n:
-            fpos, kind, q = self._call_find(
-                self._find_rep, self._block_of(pos), pos, resume_p, maxrep,
-                bnd)
-            if fpos >= self.n:
+        with span("device.pass", mode="rep"):
+            st = self.state
+            self._perm = np.asarray(perm, dtype=np.int64)
+            self._dirty[:] = False
+            bnd = self._boundary_start(rep=True)
+            self._build_blocks(self._perm)
+            maxrep = (self.P + 1 if max_replicas is None
+                      else int(max_replicas))
+            pos, resume_p, improved = 0, -1, False
+            while pos < self.n:
+                fpos, kind, q = self._call_find(
+                    self._find_rep, self._block_of(pos), pos, resume_p,
+                    maxrep, bnd)
+                if fpos >= self.n:
+                    self.pass_scans += 1
+                    break
+                v = int(self._perm[fpos])
+                m = int(st.masks[v])
+                if kind == 0:  # add replica q, move on (host `continue`)
+                    st.apply(v, m | (1 << q))
+                    pos, resume_p = fpos + 1, -1
+                else:          # drop replica q, resume the node at q + 1
+                    st.apply(v, m & ~(1 << q))
+                    pos, resume_p = fpos, q + 1
+                st.commit()
+                self.commits += 1
+                self._mark_dirty(v)
+                improved = True
+            else:
                 self.pass_scans += 1
-                break
-            v = int(self._perm[fpos])
-            m = int(st.masks[v])
-            if kind == 0:  # add replica q, then move on (host `continue`)
-                st.apply(v, m | (1 << q))
-                pos, resume_p = fpos + 1, -1
-            else:          # drop replica q, resume same node at p = q + 1
-                st.apply(v, m & ~(1 << q))
-                pos, resume_p = fpos, q + 1
-            st.commit()
-            self.commits += 1
-            self._mark_dirty(v)
-            improved = True
-        else:
-            self.pass_scans += 1
-        return improved
+            return improved
 
 
 # ==========================================================================
@@ -652,12 +674,27 @@ class DeviceScheduleWindows:
         self._dirty = True
         self._win_fns: dict = {}
         self.syncs = 0
-        self.refreshes = 0
+        self.h2d_bytes = 0
         SCHEDULE_TOTALS["attaches"] += 1
 
     def _synced(self) -> None:
         self.syncs += 1
         SCHEDULE_TOTALS["syncs"] += 1
+
+    def _put(self, a: np.ndarray) -> jax.Array:
+        """Upload one host array, counted in ``h2d_bytes`` here and in
+        ``SCHEDULE_TOTALS``."""
+        self._count(a.nbytes)
+        return jnp.asarray(a)
+
+    def _scalars(self, *xs: int) -> list:
+        """Upload int32 scalars, counted like ``_put``."""
+        self._count(4 * len(xs))
+        return [jnp.int32(x) for x in xs]
+
+    def _count(self, nbytes: int) -> None:
+        self.h2d_bytes += nbytes
+        SCHEDULE_TOTALS["h2d_bytes"] += nbytes
 
     def mark_dirty(self) -> None:
         self._dirty = True
@@ -671,12 +708,12 @@ class DeviceScheduleWindows:
         def rows(ll):
             a = np.zeros((self.Sp, P), dtype=np.int32)
             a[:self.S] = np.asarray(ll[:self.S])
-            return jnp.asarray(a)
+            return self._put(a)
 
         def tops(tt):
             a = np.zeros((self.Sp, 3), dtype=np.int32)
             a[:self.S] = np.asarray(tt[:self.S])
-            return jnp.asarray(a)
+            return self._put(a)
 
         self._sent, self._recv, self._work = (
             rows(s.sent), rows(s.recv), rows(s.work))
@@ -684,9 +721,8 @@ class DeviceScheduleWindows:
             tops(s._stop), tops(s._rtop), tops(s._wtop))
         sc = np.zeros(self.Sp, dtype=np.int32)
         sc[:self.S] = np.asarray(s._scost[:self.S])
-        self._scost = jnp.asarray(sc)
+        self._scost = self._put(sc)
         self._dirty = False
-        self.refreshes += 1
 
     def _win_fn(self, kind: str, Wp: int):
         key = (kind, Wp)
@@ -727,42 +763,47 @@ class DeviceScheduleWindows:
 
     def price_comm_moves(self, v: int, dst: int, ts: np.ndarray) -> np.ndarray:
         """Fused-window twin of ``schedule_front.price_comm_moves``."""
-        if self._dirty:
-            self._refresh()
-        sched = self.sched
-        src, s = sched.comms[(v, dst)]
-        mu = sched.inst.dag.mu[v]
-        d0 = sched._comm_step_delta(s, src, dst, -mu)
-        ts = np.asarray(ts, dtype=np.int64)
-        lo, W = int(ts[0]), len(ts)
-        fn = self._win_fn("comm", _pow2(W))
-        out = fn(self._sent, self._recv, self._stop, self._rtop, self._wtop,
-                 self._scost, jnp.int32(lo), jnp.int32(src), jnp.int32(dst),
-                 jnp.int32(int(mu)))
-        self._synced()
-        deltas = d0 + np.asarray(out[:W], dtype=np.float64)
-        deltas[ts == s] = 0.0
-        return deltas
+        with span("windows.price", kind="comm"):
+            if self._dirty:
+                self._refresh()
+            sched = self.sched
+            src, s = sched.comms[(v, dst)]
+            mu = sched.inst.dag.mu[v]
+            d0 = sched._comm_step_delta(s, src, dst, -mu)
+            ts = np.asarray(ts, dtype=np.int64)
+            lo, W = int(ts[0]), len(ts)
+            fn = self._win_fn("comm", _pow2(W))
+            out = fn(self._sent, self._recv, self._stop, self._rtop,
+                     self._wtop, self._scost,
+                     *self._scalars(lo, src, dst, int(mu)))
+            self._synced()
+            with span("windows.wait"):
+                deltas = d0 + np.asarray(out[:W], dtype=np.float64)
+            deltas[ts == s] = 0.0
+            return deltas
 
     def price_comp_moves(self, v: int, p: int, ts: np.ndarray) -> np.ndarray:
         """Fused-window twin of ``schedule_front.price_comp_moves``."""
-        if self._dirty:
-            self._refresh()
-        sched = self.sched
-        s = sched.assign[v][p]
-        om = sched.inst.dag.omega[v]
-        w1_minus = sched._kind_max_if("work", s, p, -om)
-        d_s = sched._step_cost(w1_minus, sched.h_of(s)) - sched._scost[s]
-        ts = np.asarray(ts, dtype=np.int64)
-        lo, W = int(ts[0]), len(ts)
-        fn = self._win_fn("comp", _pow2(W))
-        out = fn(self._work, self._recv, self._stop, self._rtop, self._wtop,
-                 self._scost, jnp.int32(lo), jnp.int32(p), jnp.int32(0),
-                 jnp.int32(int(om)))
-        self._synced()
-        deltas = d_s + np.asarray(out[:W], dtype=np.float64)
-        deltas[ts == s] = 0.0
-        return deltas
+        with span("windows.price", kind="comp"):
+            if self._dirty:
+                self._refresh()
+            sched = self.sched
+            s = sched.assign[v][p]
+            om = sched.inst.dag.omega[v]
+            w1_minus = sched._kind_max_if("work", s, p, -om)
+            d_s = (sched._step_cost(w1_minus, sched.h_of(s))
+                   - sched._scost[s])
+            ts = np.asarray(ts, dtype=np.int64)
+            lo, W = int(ts[0]), len(ts)
+            fn = self._win_fn("comp", _pow2(W))
+            out = fn(self._work, self._recv, self._stop, self._rtop,
+                     self._wtop, self._scost,
+                     *self._scalars(lo, p, 0, int(om)))
+            self._synced()
+            with span("windows.wait"):
+                deltas = d_s + np.asarray(out[:W], dtype=np.float64)
+            deltas[ts == s] = 0.0
+            return deltas
 
     def _node_fn(self, Tp: int):
         key = ("node", Tp)
@@ -795,27 +836,29 @@ class DeviceScheduleWindows:
         cells = _node_move_cells(sched, v)
         if len(cells) < DEVICE_MIN_STEPS:
             return price_node_moves(sched, v)
-        if self._dirty:
-            self._refresh()
-        steps = sorted(cells)
-        T = len(steps)
-        Tp = _pow2(T)
-        ts = np.zeros(Tp, dtype=np.int64)
-        ts[:T] = steps
-        dw = np.zeros((Tp, P, P), dtype=np.int32)
-        ds = np.zeros((Tp, P, P), dtype=np.int32)
-        dr = np.zeros((Tp, P, P), dtype=np.int32)
-        for i, t in enumerate(steps):
-            w, se, r = cells[t]
-            dw[i], ds[i], dr[i] = w, se, r
-        out = self._node_fn(Tp)(self._work, self._sent, self._recv,
-                                self._scost, jnp.asarray(ts),
-                                jnp.asarray(dw), jnp.asarray(ds),
-                                jnp.asarray(dr))
-        self._synced()
-        deltas = np.asarray(out, dtype=np.float64)
-        deltas[p] = 0.0
-        return deltas
+        with span("windows.price", kind="node"):
+            if self._dirty:
+                self._refresh()
+            steps = sorted(cells)
+            T = len(steps)
+            Tp = _pow2(T)
+            ts = np.zeros(Tp, dtype=np.int32)
+            ts[:T] = steps
+            dw = np.zeros((Tp, P, P), dtype=np.int32)
+            ds = np.zeros((Tp, P, P), dtype=np.int32)
+            dr = np.zeros((Tp, P, P), dtype=np.int32)
+            for i, t in enumerate(steps):
+                w, se, r = cells[t]
+                dw[i], ds[i], dr[i] = w, se, r
+            out = self._node_fn(Tp)(self._work, self._sent, self._recv,
+                                    self._scost, self._put(ts),
+                                    self._put(dw), self._put(ds),
+                                    self._put(dr))
+            self._synced()
+            with span("windows.wait"):
+                deltas = np.asarray(out, dtype=np.float64)
+            deltas[p] = 0.0
+            return deltas
 
 
 def _node_move_cells(sched, v: int) -> dict:

@@ -88,6 +88,7 @@ def _pallas_call(Rp: int, Mp: int, block_r: int, interpret: bool):
         out_specs=pl.BlockSpec((block_r, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, 1), jnp.int32),
         interpret=interpret,
+        name="min_cover",
     ))
 
 
@@ -123,6 +124,7 @@ def _pallas_dlam_call(Rp: int, Mp: int, block_r: int, interpret: bool):
         out_specs=pl.BlockSpec((block_r, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, 1), jnp.int32),
         interpret=interpret,
+        name="front_dlam",
     ))
 
 
