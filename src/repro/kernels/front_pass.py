@@ -56,6 +56,14 @@ and step costs as persistent padded device arrays and fuses the
 ``price_comm_moves`` / ``price_comp_moves`` gathers and the node-move
 (P x P) delta-matrix fold into single jitted programs (int32, same integer
 contract; float-weight instances fall back to the numpy fronts).
+
+Program cache: every jitted program here (find, apply, window, node fold)
+comes from a process-wide bounded ``lru_cache`` keyed by the static values
+its body closes over, so each signature is traced, lowered and compiled
+once per process and every later attach or pricer with that signature
+reuses the same ``jax.jit`` object.  JAX keys its own in-memory caches by
+the function object, so a fresh closure per attach would miss them all.
+``program_cache_stats`` reports hits and misses.
 """
 from __future__ import annotations
 
@@ -65,6 +73,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.partition.engine import _tables
 from ..spans import span
 from .gain import _NO_COVER, front_dlam
 
@@ -82,6 +91,13 @@ DEVICE_MIN_STEPS = 8
 
 _R_BLK_MIN = 2048
 _INT32_BUDGET = 2 ** 30  # headroom below int32 max for any partial sum
+
+# Bound of each program cache.  A multilevel run attaches at a few levels
+# (one find/apply signature each) and prices a few pow2 window lengths per
+# (L, g); 64 covers that working set while a long run or a test session
+# that visits many shapes cannot pin every executable for the life of the
+# process (the reason ``gain._PALLAS_CACHE_SIZE`` gives).
+_PROGRAM_CACHE_SIZE = 64
 
 # Process-wide totals of the device passes, so a caller that only sees the
 # public entry points can tell which levels ran on the device and how.
@@ -101,6 +117,13 @@ def _integer_valued(a: np.ndarray) -> bool:
 
 def _pow2(x: int) -> int:
     return 1 << max(int(x) - 1, 1).bit_length()
+
+
+def _popcount_columns(P: int) -> np.ndarray:
+    """(2^P,) int32 popcounts in popcount-column order, ``_NO_COVER`` at
+    column 0 (the empty subset)."""
+    order_pc = _tables(P)[2]
+    return np.concatenate(([_NO_COVER], order_pc)).astype(np.int32)
 
 
 # ==========================================================================
@@ -145,16 +168,18 @@ def attach(state, cap: float):
     return dev
 
 
-def find_program(mode: str, *, n: int, E: int, P: int, R_blk: int, pc,
+@functools.lru_cache(maxsize=_PROGRAM_CACHE_SIZE)
+def find_program(mode: str, *, n: int, E: int, P: int, R_blk: int,
                  use_pallas: bool, interpret: bool):
     """The jitted find program of a ``"fm"`` or ``"rep"`` pass.
 
     ``n``/``E`` are the real node and edge counts (row n / E of the device
-    tables is the dummy), ``R_blk`` the pow2 rows of one front block and
-    ``pc`` the (2^P,) popcount-ordered column popcounts with the
-    ``_NO_COVER`` sentinel at column 0.  Everything else is an argument,
-    so the program can be lowered from shapes alone.
+    tables is the dummy), ``R_blk`` the pow2 rows of one front block.  The
+    popcount columns are a constant of ``P``.  Everything else is an
+    argument, so the program can be lowered from shapes alone.  Cached per
+    signature: equal arguments return the same ``jax.jit`` object.
     """
+    pc = jnp.asarray(_popcount_columns(P))
     nsub = 1 << P
     B_blk = R_blk // P
     BIG = np.int32(np.iinfo(np.int32).max)
@@ -281,6 +306,28 @@ def find_program(mode: str, *, n: int, E: int, P: int, R_blk: int, pc,
     return functools.partial(jax.jit, donate_argnums=(0, 1, 2))(find)
 
 
+@functools.lru_cache(maxsize=_PROGRAM_CACHE_SIZE)
+def _apply_program(E: int):
+    """The standalone apply program for ``E`` real edges (row E is the
+    dummy); ``contrib`` and ``pc`` are arguments."""
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+    def apply_(uncov, lam, masks, v, old, new, e_win, contrib, pc):
+        diff = contrib[new] - contrib[old]
+        valid = e_win < E
+        uncov = uncov.at[e_win].add(
+            jnp.where(valid[:, None], diff[None, :], 0))
+        rows = uncov[e_win]
+        lam_new = jnp.min(
+            jnp.where(rows == 0, pc[None, :], _NO_COVER),
+            axis=1).astype(jnp.int32)
+        lam = lam.at[e_win].set(jnp.where(valid, lam_new, lam[e_win]))
+        masks = masks.at[v].set(new)
+        return uncov, lam, masks
+
+    return apply_
+
+
 class DevicePartitionPass:
     """Device mirror of a ``PartitionState`` plus the fused pass programs.
 
@@ -315,9 +362,7 @@ class DevicePartitionPass:
         # column permutation: subset 0 first, then popcount order
         self.colmap = np.concatenate(
             ([0], np.asarray(state._order, dtype=np.int64)))
-        pc_p = np.concatenate(
-            ([_NO_COVER], np.asarray(state._order_pc, dtype=np.int64)))
-        self._pc = self._put(pc_p.astype(np.int32))
+        self._pc = self._put(_popcount_columns(self.P))
         self._contrib = self._put(
             np.ascontiguousarray(state._contrib[:, self.colmap],
                                  dtype=np.int32))
@@ -337,10 +382,9 @@ class DevicePartitionPass:
         self._fits = np.zeros((self.n + 1, self.P), dtype=bool)
         self._last_loads = None
         self._dirty = np.zeros(self.n, dtype=bool)
-        self._apply_fn = self._make_apply()
+        self._apply_fn = _apply_program(self.E)
         kw = dict(n=self.n, E=self.E, P=self.P, R_blk=self.R_blk,
-                  pc=self._pc, use_pallas=self.use_pallas,
-                  interpret=self.interpret)
+                  use_pallas=self.use_pallas, interpret=self.interpret)
         self._find_fm = find_program("fm", **kw)
         self._find_rep = find_program("rep", **kw)
         # instrumentation (sync = blocking device->host read)
@@ -422,25 +466,6 @@ class DevicePartitionPass:
         pending, self._pending = self._pending, []
         for v, old, new in pending:
             self._dispatch_apply(v, old, new)
-
-    def _make_apply(self):
-        E = self.E
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def apply_(uncov, lam, masks, v, old, new, e_win, contrib, pc):
-            diff = contrib[new] - contrib[old]
-            valid = e_win < E
-            uncov = uncov.at[e_win].add(
-                jnp.where(valid[:, None], diff[None, :], 0))
-            rows = uncov[e_win]
-            lam_new = jnp.min(
-                jnp.where(rows == 0, pc[None, :], _NO_COVER),
-                axis=1).astype(jnp.int32)
-            lam = lam.at[e_win].set(jnp.where(valid, lam_new, lam[e_win]))
-            masks = masks.at[v].set(new)
-            return uncov, lam, masks
-
-        return apply_
 
     # ------------------------------------------------------- block builder
     def _build_blocks(self, perm: np.ndarray) -> None:
@@ -672,7 +697,6 @@ class DeviceScheduleWindows:
         self.L = int(sched.inst.L)
         self.g = int(sched.inst.g)
         self._dirty = True
-        self._win_fns: dict = {}
         self.syncs = 0
         self.h2d_bytes = 0
         SCHEDULE_TOTALS["attaches"] += 1
@@ -724,43 +748,6 @@ class DeviceScheduleWindows:
         self._scost = self._put(sc)
         self._dirty = False
 
-    def _win_fn(self, kind: str, Wp: int):
-        key = (kind, Wp)
-        fn = self._win_fns.get(key)
-        if fn is not None:
-            return fn
-        L, g = self.L, self.g
-
-        def step_cost(w1, h):
-            return jnp.where(h >= 1, w1 + L + g * h, w1)
-
-        if kind == "comm":
-            def win(sent, recv, stop, rtop, wtop, scost, lo, src, dst, mu):
-                idx = jnp.clip(lo + jnp.arange(Wp), 0, sent.shape[0] - 1)
-                s_alt = jnp.where(stop[idx, 1] == src, stop[idx, 2],
-                                  stop[idx, 0])
-                s_new = sent[idx, src] + mu
-                r_alt = jnp.where(rtop[idx, 1] == dst, rtop[idx, 2],
-                                  rtop[idx, 0])
-                r_new = recv[idx, dst] + mu
-                h = jnp.maximum(jnp.maximum(s_alt, s_new),
-                                jnp.maximum(r_alt, r_new))
-                return step_cost(wtop[idx, 0], h) - scost[idx]
-        else:
-            def win(sent, recv, stop, rtop, wtop, scost, lo, src, dst, mu):
-                # comp re-timing: src slot carries p, mu carries omega
-                idx = jnp.clip(lo + jnp.arange(Wp), 0, sent.shape[0] - 1)
-                w_alt = jnp.where(wtop[idx, 1] == src, wtop[idx, 2],
-                                  wtop[idx, 0])
-                w_new = sent[idx, src] + mu  # sent slot carries work rows
-                w1 = jnp.maximum(w_alt, w_new)
-                h = jnp.maximum(stop[idx, 0], rtop[idx, 0])
-                return step_cost(w1, h) - scost[idx]
-
-        fn = jax.jit(win)
-        self._win_fns[key] = fn
-        return fn
-
     def price_comm_moves(self, v: int, dst: int, ts: np.ndarray) -> np.ndarray:
         """Fused-window twin of ``schedule_front.price_comm_moves``."""
         with span("windows.price", kind="comm"):
@@ -772,7 +759,7 @@ class DeviceScheduleWindows:
             d0 = sched._comm_step_delta(s, src, dst, -mu)
             ts = np.asarray(ts, dtype=np.int64)
             lo, W = int(ts[0]), len(ts)
-            fn = self._win_fn("comm", _pow2(W))
+            fn = _win_program("comm", _pow2(W), self.L, self.g)
             out = fn(self._sent, self._recv, self._stop, self._rtop,
                      self._wtop, self._scost,
                      *self._scalars(lo, src, dst, int(mu)))
@@ -795,7 +782,7 @@ class DeviceScheduleWindows:
                    - sched._scost[s])
             ts = np.asarray(ts, dtype=np.int64)
             lo, W = int(ts[0]), len(ts)
-            fn = self._win_fn("comp", _pow2(W))
+            fn = _win_program("comp", _pow2(W), self.L, self.g)
             out = fn(self._work, self._recv, self._stop, self._rtop,
                      self._wtop, self._scost,
                      *self._scalars(lo, p, 0, int(om)))
@@ -804,26 +791,6 @@ class DeviceScheduleWindows:
                 deltas = d_s + np.asarray(out[:W], dtype=np.float64)
             deltas[ts == s] = 0.0
             return deltas
-
-    def _node_fn(self, Tp: int):
-        key = ("node", Tp)
-        fn = self._win_fns.get(key)
-        if fn is not None:
-            return fn
-        L, g = self.L, self.g
-
-        def fold(work, sent, recv, scost, ts, dw, ds, dr):
-            # ts: (Tp,) touched steps; d*: (Tp, P, P) candidate x processor
-            w1 = (work[ts][:, None, :] + dw).max(axis=2)
-            s1 = (sent[ts][:, None, :] + ds).max(axis=2)
-            r1 = (recv[ts][:, None, :] + dr).max(axis=2)
-            h = jnp.maximum(s1, r1)
-            step = jnp.where(h >= 1, w1 + L + g * h, w1)
-            return (step - scost[ts][:, None]).sum(axis=0)
-
-        fn = jax.jit(fold)
-        self._win_fns[key] = fn
-        return fn
 
     def price_node_moves(self, v: int) -> np.ndarray:
         """Fused twin of ``schedule_front.price_node_moves``: the per-
@@ -850,15 +817,82 @@ class DeviceScheduleWindows:
             for i, t in enumerate(steps):
                 w, se, r = cells[t]
                 dw[i], ds[i], dr[i] = w, se, r
-            out = self._node_fn(Tp)(self._work, self._sent, self._recv,
-                                    self._scost, self._put(ts),
-                                    self._put(dw), self._put(ds),
-                                    self._put(dr))
+            fn = _node_program(Tp, self.L, self.g)
+            out = fn(self._work, self._sent, self._recv, self._scost,
+                     self._put(ts), self._put(dw), self._put(ds),
+                     self._put(dr))
             self._synced()
             with span("windows.wait"):
                 deltas = np.asarray(out, dtype=np.float64)
             deltas[p] = 0.0
             return deltas
+
+
+@functools.lru_cache(maxsize=_PROGRAM_CACHE_SIZE)
+def _win_program(kind: str, Wp: int, L: int, g: int):
+    """The ``"comm"`` or ``"comp"`` window pricer over ``Wp`` supersteps
+    for BSP parameters ``L`` and ``g`` (both closed over)."""
+
+    def step_cost(w1, h):
+        return jnp.where(h >= 1, w1 + L + g * h, w1)
+
+    if kind == "comm":
+        def win(sent, recv, stop, rtop, wtop, scost, lo, src, dst, mu):
+            idx = jnp.clip(lo + jnp.arange(Wp), 0, sent.shape[0] - 1)
+            s_alt = jnp.where(stop[idx, 1] == src, stop[idx, 2],
+                              stop[idx, 0])
+            s_new = sent[idx, src] + mu
+            r_alt = jnp.where(rtop[idx, 1] == dst, rtop[idx, 2],
+                              rtop[idx, 0])
+            r_new = recv[idx, dst] + mu
+            h = jnp.maximum(jnp.maximum(s_alt, s_new),
+                            jnp.maximum(r_alt, r_new))
+            return step_cost(wtop[idx, 0], h) - scost[idx]
+    else:
+        def win(sent, recv, stop, rtop, wtop, scost, lo, src, dst, mu):
+            # comp re-timing: src slot carries p, mu carries omega
+            idx = jnp.clip(lo + jnp.arange(Wp), 0, sent.shape[0] - 1)
+            w_alt = jnp.where(wtop[idx, 1] == src, wtop[idx, 2],
+                              wtop[idx, 0])
+            w_new = sent[idx, src] + mu  # sent slot carries work rows
+            w1 = jnp.maximum(w_alt, w_new)
+            h = jnp.maximum(stop[idx, 0], rtop[idx, 0])
+            return step_cost(w1, h) - scost[idx]
+
+    return jax.jit(win)
+
+
+@functools.lru_cache(maxsize=_PROGRAM_CACHE_SIZE)
+def _node_program(Tp: int, L: int, g: int):
+    """The node-move (P x P) delta fold over ``Tp`` touched supersteps for
+    BSP parameters ``L`` and ``g`` (both closed over)."""
+
+    def fold(work, sent, recv, scost, ts, dw, ds, dr):
+        # ts: (Tp,) touched steps; d*: (Tp, P, P) candidate x processor
+        w1 = (work[ts][:, None, :] + dw).max(axis=2)
+        s1 = (sent[ts][:, None, :] + ds).max(axis=2)
+        r1 = (recv[ts][:, None, :] + dr).max(axis=2)
+        h = jnp.maximum(s1, r1)
+        step = jnp.where(h >= 1, w1 + L + g * h, w1)
+        return (step - scost[ts][:, None]).sum(axis=0)
+
+    return jax.jit(fold)
+
+
+_PROGRAM_CACHES = {"find": find_program, "apply": _apply_program,
+                   "win": _win_program, "node": _node_program}
+
+
+def program_cache_stats() -> dict:
+    """Hit/miss/size counters of the per-signature program caches, in the
+    shape of ``gain.kernel_cache_stats``: ``misses`` counts the distinct
+    signatures built, ``hits`` every attach or pricer that reused one."""
+    out = {}
+    for name, fn in _PROGRAM_CACHES.items():
+        info = fn.cache_info()
+        out[name] = {"hits": info.hits, "misses": info.misses,
+                     "size": info.currsize, "maxsize": info.maxsize}
+    return out
 
 
 def _node_move_cells(sched, v: int) -> dict:

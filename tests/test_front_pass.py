@@ -17,6 +17,9 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core.frontier import device_pass, device_windows
+from repro.core.frontier.schedule_front import (price_comm_moves,
+                                                price_comp_moves,
+                                                price_node_moves)
 from repro.core.hypergraph import Dag, Hypergraph
 from repro.core.partition import PartitionState
 from repro.core.partition.cost import capacity
@@ -67,6 +70,24 @@ def small_device_floors():
     finally:
         (front_pass.DEVICE_MIN_NODES, front_pass.DEVICE_MIN_WINDOW,
          front_pass.DEVICE_MIN_STEPS) = saved
+
+
+def relabel(hg, seed):
+    """``hg`` with its nodes renamed by a seeded permutation: the same
+    shapes (n, E, degrees), other node ids."""
+    perm = np.random.default_rng(seed).permutation(hg.n)
+    omega = np.empty_like(hg.omega)
+    omega[perm] = hg.omega
+    return Hypergraph(n=hg.n, edges=[tuple(int(perm[v]) for v in e)
+                                     for e in hg.edges],
+                      omega=omega, mu=hg.mu)
+
+
+def clear_program_caches():
+    """Empty the process-wide program caches, so that a test counts its
+    own misses whatever an earlier test in this process built."""
+    for build in front_pass._PROGRAM_CACHES.values():
+        build.cache_clear()
 
 
 def sched_snap(s):
@@ -245,7 +266,116 @@ def test_attach_guards():
         assert device_pass(st_u, cap, backend="jax") is None
 
 
+@pytest.mark.parametrize("second, same_find, same_apply", [
+    ("relabelled", True, True),     # same signature: both programs reused
+    ("reshaped", False, False),     # other n and E
+    ("pallas", False, True),        # ops.force("pallas"): other find only
+])
+def test_attach_reuses_cached_programs(monkeypatch, second, same_find,
+                                       same_apply):
+    """A second attach gets the first one's jitted find and apply objects
+    exactly when its program signature matches, and both attaches stay
+    decision-identical to the numpy frontier."""
+    attached, real_attach = [], front_pass.attach
+
+    def spy(state, cap):
+        dev = real_attach(state, cap)
+        attached.append(dev)
+        return dev
+
+    monkeypatch.setattr(front_pass, "attach", spy)
+    clear_program_caches()
+    hg_a = int_hypergraph(np.random.default_rng(5), n=30, m=50)
+    hg_b = {"relabelled": relabel(hg_a, 1), "pallas": hg_a,
+            "reshaped": int_hypergraph(np.random.default_rng(6), n=36,
+                                       m=60)}[second]
+    with small_device_floors():
+        for i, hg in enumerate((hg_a, hg_b)):
+            if i and second == "pallas":
+                ops.force("pallas")
+            try:
+                _, (ma, sta), (mb, stb) = _fm_pair(hg, 4, 0.3, seed=5)
+            finally:
+                ops.force(None)
+            assert np.array_equal(ma, mb) and sta.cost == stb.cost
+    dev_a, dev_b = attached
+    assert (dev_b._find_fm is dev_a._find_fm) == same_find
+    assert (dev_b._find_rep is dev_a._find_rep) == same_find
+    assert dev_b._find_fm is not dev_b._find_rep
+    assert (dev_b._apply_fn is dev_a._apply_fn) == same_apply
+    stats = front_pass.program_cache_stats()
+    # one miss per distinct signature (fm and rep are two), a hit for
+    # every later attach that reused one
+    assert stats["find"]["misses"] == (2 if same_find else 4)
+    assert stats["find"]["hits"] == (2 if same_find else 0)
+    assert stats["apply"]["misses"] == (1 if same_apply else 2)
+    assert stats["apply"]["hits"] == (1 if same_apply else 0)
+
+
+def test_program_cache_stats_bounded():
+    """The program caches are bounded and introspectable, in the shape of
+    ``gain.kernel_cache_stats``."""
+    stats = front_pass.program_cache_stats()
+    assert set(stats) == {"find", "apply", "win", "node"}
+    for rec in stats.values():
+        assert set(rec) == {"hits", "misses", "size", "maxsize"}
+        assert rec["maxsize"] == front_pass._PROGRAM_CACHE_SIZE == 64
+        assert 0 <= rec["size"] <= rec["maxsize"]
+
+
 # ------------------------------------------------------- schedule windows
+
+def _price_every_window(sched):
+    """Price every comm, compute and node move of ``sched`` over the whole
+    superstep range through a fresh ``DeviceScheduleWindows`` and check
+    each delta vector bit for bit against the numpy fronts."""
+    win = device_windows(sched, "jax")
+    assert win is not None
+    ts = np.arange(sched.S)
+    priced = 0
+    for v, dst in sorted(sched.comms):
+        assert np.array_equal(win.price_comm_moves(v, dst, ts),
+                              price_comm_moves(sched, v, dst, ts))
+        priced += 1
+    for v, row in enumerate(sched.assign):
+        if len(row) != 1:
+            continue
+        (p, _), = row.items()
+        assert np.array_equal(win.price_node_moves(v),
+                              price_node_moves(sched, v))
+        if (v, p) not in sched.comms:
+            assert np.array_equal(win.price_comp_moves(v, p, ts),
+                                  price_comp_moves(sched, v, p, ts))
+        priced += 1
+    assert priced and win.syncs > 0
+
+
+def test_window_programs_keyed_on_L_and_g():
+    """Pricers of instances with equal ``L`` and ``g`` share their window
+    and node programs; another ``g`` gets programs of its own, never a
+    stale one built for the other parameters."""
+    clear_program_caches()
+    dag = random_dag(60, 4)
+    with small_device_floors():
+        s1 = bspg_schedule(BspInstance(dag=dag, P=4, g=2.0, L=4.0), seed=4)
+        _price_every_window(s1)
+        first = front_pass.program_cache_stats()
+        s2 = bspg_schedule(BspInstance(dag=dag, P=4, g=2.0, L=4.0), seed=4)
+        assert s2.S == s1.S
+        _price_every_window(s2)
+        shared = front_pass.program_cache_stats()
+        s4 = bspg_schedule(BspInstance(dag=dag, P=4, g=4.0, L=4.0), seed=4)
+        _price_every_window(s4)
+        other = front_pass.program_cache_stats()
+    for kind in ("win", "node"):
+        assert first[kind]["misses"] >= 1
+        assert shared[kind]["misses"] == first[kind]["misses"]
+        assert shared[kind]["hits"] > first[kind]["hits"]
+        assert other[kind]["misses"] > shared[kind]["misses"]
+    Wp = front_pass._pow2(s1.S)
+    assert (front_pass._win_program("comm", Wp, 4, 2)
+            is not front_pass._win_program("comm", Wp, 4, 4))
+
 
 def test_schedule_passes_bit_identical():
     """rebalance/comp/node passes and full hill_climb produce the same

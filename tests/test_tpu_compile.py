@@ -12,7 +12,6 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -72,10 +71,7 @@ def test_find_program_compiles_at_smoke_shape(one_chip, mode):
     n = 65534, E = 65493, P = 8, 2048-row blocks, 2048 blocks."""
     n, E, P, n_blocks = 65534, 65493, 8, 2048
     nsub, B_blk = 1 << P, R // P
-    popc = np.array([bin(m).count("1") for m in range(1, nsub)])
-    pc = jnp.asarray(np.concatenate(([gain._NO_COVER], np.sort(popc)))
-                     .astype(np.int32))
-    fn = front_pass.find_program(mode, n=n, E=E, P=P, R_blk=R, pc=pc,
+    fn = front_pass.find_program(mode, n=n, E=E, P=P, R_blk=R,
                                  use_pallas=True, interpret=False)
     s = lambda shape, dtype=jnp.int32: _spec(one_chip, shape, dtype)  # noqa
     scalars = [s(())] * 8
