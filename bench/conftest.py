@@ -6,13 +6,11 @@ import pytest
 
 from bench import harness
 
-# tiny instances, per configuration
-TINY = {"rownet_p8": {"nx": 8, "ny": 8, "nz": 16},
-        "cholesky_bsp8": {"tiles": 10}}
-
 
 @pytest.fixture
 def tiny(monkeypatch, tmp_path):
+    """Each configuration's ``instance`` overridden by its ``"tiny"`` entry,
+    the sizes the CPU tests run.  No chip run reads ``"tiny"``."""
     from repro.kernels import front_pass
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     monkeypatch.setattr(front_pass, "DEVICE_MIN_NODES", 64)
@@ -23,7 +21,10 @@ def tiny(monkeypatch, tmp_path):
     def small(path):
         data = load(path)
         if path.parent.name == "configs":
-            data["instance"].update(TINY[data["name"]])
+            if "tiny" not in data:
+                pytest.fail(f"{path.name} has no \"tiny\" key: the CPU "
+                            "tests' instance sizes")
+            data["instance"].update(data["tiny"])
         return data
 
     monkeypatch.setattr(harness, "load_json", small)

@@ -21,10 +21,23 @@ tie-breaking does.  A cell's traffic serves a pool of relabellings drawn
 from fixed seeds, in an order that the run's ``--seed`` draws
 (``bench/harness.py``).  The kind modules hand the program only the built
 ``Hypergraph`` / ``Dag``.
+
+A configuration whose ``generator`` is neither of these names a file of
+its own, ``bench/generators/<name>.py``, found by that name as the metric
+readers are (``bench/generators/__init__.py`` says what it defines).
+
+This module, like every generator file, imports nothing of the program
+(``repro``): the instance and the plain reference stay independent of the
+code under test.
 """
 from __future__ import annotations
 
+import importlib.util
+import pathlib
+
 import numpy as np
+
+GENERATOR_DIR = pathlib.Path(__file__).resolve().parent / "generators"
 
 
 def stencil27(nx: int, ny: int, nz: int) -> tuple[int, np.ndarray, np.ndarray]:
@@ -134,9 +147,26 @@ RELABEL = {"hpcg_row_net": relabel_hypergraph,
            "tiled_cholesky_dag": relabel_dag}
 
 
+def load_generator(name: str):
+    """The module ``GENERATOR_DIR/<name>.py``, which defines ``build`` and
+    ``relabel``."""
+    path = GENERATOR_DIR / f"{name}.py"
+    if not path.is_file():
+        raise KeyError(f"no instance generator {name!r}: it is neither in "
+                       f"bench/gen.py's GENERATORS nor a file {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_generator_{name.replace('.', '_').replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def instance(spec: dict, seed: int) -> dict:
     """The instance of a configuration's ``instance`` entry (generator name
     and its sizes), relabelled by the run's seed."""
     params = dict(spec)
     kind = params.pop("generator")
-    return RELABEL[kind](GENERATORS[kind](**params), seed)
+    if kind in GENERATORS:
+        return RELABEL[kind](GENERATORS[kind](**params), seed)
+    mod = load_generator(kind)
+    return mod.relabel(mod.build(**params), seed)

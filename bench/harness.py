@@ -9,9 +9,16 @@ edit here:
 * ``bench/configs/<config>.json`` holds the deployment; its ``kind`` names
   ``bench/kinds/<kind>.py``, the module that builds the instance, calls the
   program's entry point and checks the answer with ``bench/reference``;
+* its ``instance.generator`` is one of ``bench/gen.py``'s built-in
+  generators or names ``bench/generators/<generator>.py``;
+* its ``"tiny"`` entry holds the instance sizes the CPU tests use in place
+  of ``instance``'s (``bench/conftest.py``); no chip run reads it;
 * ``bench/workloads/<traffic>.json`` holds the traffic parameters;
 * ``bench/metrics/<metric>.py`` holds a ``read(ctx)`` for each metric that
   ``BENCHMARK.json`` lists for the cell; ``None`` leaves the metric out.
+  In a traced run ``ctx.trace`` is ``bench/span_reduce.py``'s reduction
+  of the profiler's trace: device time, programs and kernels, idle gaps
+  named by span, and the program's ``spans``.
 
 A run: the traffic names a pool of ``pool`` instances, member ``i`` the
 configuration's instance relabelled by the fixed seed ``i``; ``--seed``
@@ -267,11 +274,14 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
 
     breakdown = None
     if trace:
-        from bench import trace_reduce
+        from bench import span_reduce
+        t_reduce = time.perf_counter()
         try:
-            ctx.trace = trace_reduce.reduce_dir(trace_dir)
+            ctx.trace = span_reduce.reduce_dir(trace_dir)
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
+        print(f"trace: reduce_s={time.perf_counter() - t_reduce} "
+              f"spans={json.dumps(ctx.trace['spans'])}", file=out, flush=True)
         device["busy_s"] = ctx.trace["busy_s"]
         device["window_s"] = ctx.trace["window_s"]
         breakdown = {"device_ops": ctx.trace["device_ops"][:10],

@@ -20,10 +20,16 @@ added:
   (the median of the events' durations), ``self_s`` (each event's duration
   less what its direct children on the same host line cover, children of
   any name) and ``self_p50_s`` (the median of the events' self times).
+
+The benchmark's traced runs reduce their trace with ``reduce_dir``, after
+the window has closed, and hand the result to the metric readers as
+``ctx.trace``.
 """
 from __future__ import annotations
 
 import bisect
+import glob
+import os
 import statistics
 
 from bench import trace_reduce
@@ -180,3 +186,12 @@ def reduce_profile(pd) -> dict:
 def reduce_file(path: str) -> dict:
     from jax.profiler import ProfileData
     return reduce_profile(ProfileData.from_file(path))
+
+
+def reduce_dir(trace_dir: str) -> dict:
+    """Reduce the one ``*.xplane.pb`` that a ``start_trace`` wrote."""
+    paths = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not paths:
+        raise FileNotFoundError(f"no xplane.pb under {trace_dir}")
+    return reduce_file(paths[-1])

@@ -63,6 +63,9 @@ def test_every_file_loads():
         assert cfg["name"] == c["name"]
         assert sorted(cfg["reduced"]) == sorted(c["reduced"])
         assert hasattr(harness.load_kind(cfg["kind"]), "Cell")
+        assert "tiny" in cfg, (f"{c['file']} has no \"tiny\" key: the "
+                               "instance sizes the CPU tests run")
+        assert set(cfg["tiny"]) <= set(cfg["instance"]) - {"generator"}
     for w in SPEC["workloads"]:
         assert w["config"] in cfgs
         traffic = harness.load_json(

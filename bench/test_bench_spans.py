@@ -1,14 +1,16 @@
 """The span reduction (``span_reduce``): its span table on a hand-built
 trace whose numbers are known, idle gaps named by a span that began long
-before them, and every key ``trace_reduce`` returns left as it reads."""
+before them, every key ``trace_reduce`` returns left as it reads, and the
+metric readers that read the span table."""
 from __future__ import annotations
 
 import pathlib
+import shutil
 from types import SimpleNamespace as NS
 
 import pytest
 
-from bench import span_reduce, trace_reduce
+from bench import harness, span_reduce, trace_reduce
 from bench.test_bench_trace import _ev, _profile
 
 DATA = pathlib.Path(__file__).resolve().parent / "testdata"
@@ -96,14 +98,64 @@ def test_gap_split_at_span_edges():
                     "device.find": pytest.approx(600e-9)}
 
 
-@pytest.mark.parametrize("source", ["hand-built", "cpu_fixture.xplane.pb",
-                                    "v5e_find.xplane.pb"])
-def test_trace_reduce_keys_unchanged(source):
-    if source == "hand-built":
+@pytest.mark.parametrize("source,via", [
+    ("hand-built", "profile"), ("cpu_fixture.xplane.pb", "file"),
+    ("v5e_find.xplane.pb", "file"), ("cpu_fixture.xplane.pb", "dir"),
+    ("v5e_find.xplane.pb", "dir")])
+def test_trace_reduce_keys_unchanged(source, via, tmp_path):
+    if via == "profile":
         old = trace_reduce.reduce_profile(_profile())
         new = span_reduce.reduce_profile(_profile())
     else:
         old = trace_reduce.reduce_file(str(DATA / source))
+    if via == "file":
         new = span_reduce.reduce_file(str(DATA / source))
+    elif via == "dir":                   # laid out as start_trace writes
+        run = tmp_path / "plugins" / "profile" / "2026_01_01_00_00_00"
+        run.mkdir(parents=True)
+        shutil.copy(DATA / source, run / "host.xplane.pb")
+        new = span_reduce.reduce_dir(str(tmp_path))
     assert new.pop("spans") == {}        # recorded before the spans existed
     assert new == old
+
+
+def test_reduce_dir_without_a_trace(tmp_path):
+    with pytest.raises(FileNotFoundError):
+        span_reduce.reduce_dir(str(tmp_path))
+
+
+def _span(seconds, self_s):
+    return {"count": 1, "seconds": seconds, "p50_s": seconds,
+            "self_s": self_s, "self_p50_s": self_s}
+
+
+SPANS = {"partition.initial": _span(3.0, 1.0),
+         "partition.level": _span(8.0, 2.0),
+         "partition.alternate": _span(0.5, 0.25),
+         "partition.coarsen": _span(0.75, 0.75),
+         "device.pass": _span(5.0, 1.5),
+         "schedule.initial": _span(20.0, 16.0),
+         "schedule.level": _span(2.0, 0.5),
+         "windows.price": _span(4.0, 0.125)}
+
+
+@pytest.mark.parametrize("metric,kind,want", [
+    ("host_refine_s.partition", "partition", (1.0 + 2.0 + 0.25) / 2),
+    ("device_pass_s.partition", "partition", 5.0 / 2),
+    ("host_refine_s.schedule", "schedule", (16.0 + 0.5) / 2),
+    ("window_s.schedule", "schedule", 4.0 / 2)])
+def test_span_metric_readers(metric, kind, want):
+    """Per solve in the window, from the reduced trace's span table; None
+    where there are no spans, none of the metric's own, or another kind."""
+    read = harness.load_metric(metric).read
+
+    def ctx(kind=kind, trace=None):
+        return harness.Context(kind=kind, setup_s=1.0, solves=2, trace=trace)
+
+    assert read(ctx(trace={"spans": SPANS})) == pytest.approx(want)
+    assert read(ctx(trace=None)) is None
+    assert read(ctx(trace={"busy_s": 1.0, "window_s": 2.0})) is None
+    assert read(ctx(trace={"spans": {}})) is None
+    assert read(ctx(trace={"spans": {"device.find": _span(1.0, 1.0)}})) is None
+    other = "schedule" if kind == "partition" else "partition"
+    assert read(ctx(kind=other, trace={"spans": SPANS})) is None

@@ -26,8 +26,6 @@ events.  All events are on one clock, in nanoseconds.
 from __future__ import annotations
 
 import bisect
-import glob
-import os
 import re
 
 SOLVE = "solve"
@@ -206,12 +204,3 @@ def _gaps(merged, lo, hi, host_spans, solves):
 def reduce_file(path: str) -> dict:
     from jax.profiler import ProfileData
     return reduce_profile(ProfileData.from_file(path))
-
-
-def reduce_dir(trace_dir: str) -> dict:
-    """Reduce the one ``*.xplane.pb`` that a ``start_trace`` wrote."""
-    paths = sorted(glob.glob(os.path.join(
-        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
-    if not paths:
-        raise FileNotFoundError(f"no xplane.pb under {trace_dir}")
-    return reduce_file(paths[-1])
