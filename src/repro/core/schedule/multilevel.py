@@ -350,9 +350,10 @@ def _refine_level(sched: Schedule, finest: bool,
         # caller's AdvancedOptions (pass selection, use_fronts) carry
         # through to refinement; the round budget and split toggle are
         # per-level knobs of the V-cycle
-        advanced_heuristic(sched, dataclasses.replace(
-            adv_opts or AdvancedOptions(), max_rounds=rounds,
-            superstep_splitting=opts.superstep_splits))
+        with span("schedule.advanced", rounds=rounds):
+            advanced_heuristic(sched, dataclasses.replace(
+                adv_opts or AdvancedOptions(), max_rounds=rounds,
+                superstep_splitting=opts.superstep_splits))
     else:
         sched.prune_useless_comms()
         sched.compact()
@@ -438,7 +439,8 @@ def multilevel_schedule(inst: BspInstance,
             cmap = _compose_cmaps(cmaps, li, prev)
             li_inst = inst if li == 0 else BspInstance(levels[li], inst.P,
                                                        inst.g, inst.L)
-            sched = Schedule.from_projection(li_inst, sched, cmap)
+            with span("schedule.project", level=li, n=levels[li].n):
+                sched = Schedule.from_projection(li_inst, sched, cmap)
             prev = li
             projected = float(sched.current_cost())
             _refine_level(sched, li == 0, opts, seed + li, adv_opts=adv_opts)

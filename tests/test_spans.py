@@ -132,7 +132,8 @@ def test_partition_spans_nest(floors, tmp_path):
 @pytest.mark.parametrize("coarsest_n", [600, 5000])
 def test_schedule_spans_nest(floors, tmp_path, coarsest_n):
     """A V-cycle, and an instance at or below the coarsest size, whose
-    flat solve is the V-cycle's only (initial) solve."""
+    flat solve is the V-cycle's only (initial) solve.  Each refined level
+    projects once and runs the advanced heuristic once, both inside it."""
     plain = _schedule(coarsest_n)
     s0 = front_pass.SCHEDULE_TOTALS["syncs"]
     traced, pd = _traced(lambda: _schedule(coarsest_n), tmp_path)
@@ -151,6 +152,14 @@ def test_schedule_spans_nest(floors, tmp_path, coarsest_n):
     assert all(_within(lines, "windows.price", top - {"schedule.coarsen"}))
     for name in ran:
         assert all(_within(lines, name, {"solve"})), name
+    per_level = {"schedule.project", "schedule.advanced"}
+    if "schedule.level" in ran:
+        for name in per_level:
+            assert (table[name]["count"]
+                    == table["schedule.level"]["count"]), name
+            assert all(_within(lines, name, {"schedule.level"})), name
+    else:
+        assert not per_level & set(table)
     assert _covered_share(lines, top) > 0.9
 
 
