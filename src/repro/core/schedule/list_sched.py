@@ -119,6 +119,16 @@ def _best_window_move(sched, s: int, lo: int, hi: int, deltas,
     return best_s, best_d
 
 
+def _long_comm_windows(sched: Schedule, keys, start: int):
+    """``(v, dst, lo, W)`` of each comm in ``keys[start:]`` whose window
+    spans at least ``_COMM_FRONT_MIN_WINDOW`` supersteps, lazily."""
+    for j in range(start, len(keys)):
+        v, dst = keys[j]
+        lo, hi = _comm_window(sched, v, dst)
+        if hi - lo + 1 >= _COMM_FRONT_MIN_WINDOW:
+            yield v, dst, lo, hi - lo + 1
+
+
 def rebalance_comms(sched: Schedule, max_passes: int = 4,
                     use_fronts: bool = True,
                     backend: str | None = None) -> bool:
@@ -133,7 +143,10 @@ def rebalance_comms(sched: Schedule, max_passes: int = 4,
     ``backend="jax"`` (on integer-weight instances) routes long windows
     through the device-resident fused pricer (``frontier.device_windows``)
     instead -- same deltas bit-for-bit, ``_best_window_move`` stays the
-    single decision home.
+    single decision home.  A device sync prices the visited window with
+    the pass's next long ones (``DeviceScheduleWindows.comm_window``);
+    every move drops those rows (``mark_dirty``), so each is read against
+    the schedule it was priced on.
     """
     from ..frontier import device_windows, price_comm_moves
 
@@ -141,16 +154,19 @@ def rebalance_comms(sched: Schedule, max_passes: int = 4,
     improved_any = False
     for _ in range(max_passes):
         improved = False
-        for (v, dst) in sorted(sched.comms.keys()):
+        keys = sorted(sched.comms.keys())
+        for i, (v, dst) in enumerate(keys):
             src, s = sched.comms[(v, dst)]
             lo, hi = _comm_window(sched, v, dst)
             if hi < lo:
                 continue
             if use_fronts and hi - lo + 1 >= _COMM_FRONT_MIN_WINDOW:
-                ts = np.arange(lo, hi + 1)
-                deltas = (win.price_comm_moves(v, dst, ts)
+                deltas = (win.comm_window(
+                              v, dst, lo, hi - lo + 1,
+                              _long_comm_windows(sched, keys, i + 1))
                           if win is not None
-                          else price_comm_moves(sched, v, dst, ts))
+                          else price_comm_moves(sched, v, dst,
+                                                np.arange(lo, hi + 1)))
             else:
                 deltas = None
             best_s, _ = _best_window_move(

@@ -68,6 +68,7 @@ the function object, so a fresh closure per attach would miss them all.
 from __future__ import annotations
 
 import functools
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -89,6 +90,11 @@ DEVICE_MIN_WINDOW = 16
 # Minimum touched-superstep count for the fused node-move fold.
 DEVICE_MIN_STEPS = 8
 
+# Comm windows the rebalancing sweep prices per device sync: the visited
+# window and the next long ones of the pass, a batch that lasts until the
+# schedule next changes (``DeviceScheduleWindows.comm_window``).
+DEVICE_COMM_BATCH = 32
+
 _R_BLK_MIN = 2048
 _INT32_BUDGET = 2 ** 30  # headroom below int32 max for any partial sum
 
@@ -105,9 +111,11 @@ _PROGRAM_CACHE_SIZE = 64
 # (n, use_pallas, interpret): {"attaches", "syncs", "commits", "pass_scans",
 # "h2d_bytes"}.
 PARTITION_TOTALS: dict[tuple[int, bool, bool], dict[str, int]] = {}
-# Every ``DeviceScheduleWindows`` built, every host sync it made and every
-# byte it uploaded.
-SCHEDULE_TOTALS = {"attaches": 0, "syncs": 0, "h2d_bytes": 0}
+# Every ``DeviceScheduleWindows`` built, every host sync it made, every
+# byte it uploaded, every comm window it priced and every priced-ahead
+# window a mutation dropped unused.
+SCHEDULE_TOTALS = {"attaches": 0, "syncs": 0, "h2d_bytes": 0, "windows": 0,
+                   "discarded": 0}
 
 
 def _integer_valued(a: np.ndarray) -> bool:
@@ -689,6 +697,11 @@ class DeviceScheduleWindows:
     ``schedule_front.price_comm_moves`` / ``price_comp_moves`` /
     ``price_node_moves`` -- integer device arithmetic plus the host's exact
     float64 scalar terms -- so every decision matches the numpy fronts.
+
+    Comm windows price in batches of up to ``DEVICE_COMM_BATCH`` rows, one
+    sync each; ``comm_window`` keeps the rows a batch priced ahead in
+    ``ahead`` until they are visited or ``mark_dirty`` drops them, so a
+    row is only ever read against the state it was priced on.
     """
 
     def __init__(self, sched) -> None:
@@ -697,31 +710,29 @@ class DeviceScheduleWindows:
         self.L = int(sched.inst.L)
         self.g = int(sched.inst.g)
         self._dirty = True
+        self.ahead: dict[tuple[int, int], np.ndarray] = {}
         self.syncs = 0
         self.h2d_bytes = 0
+        self.windows = 0     # comm windows priced
+        self.discarded = 0   # priced ahead, dropped unused by a mutation
         SCHEDULE_TOTALS["attaches"] += 1
 
-    def _synced(self) -> None:
-        self.syncs += 1
-        SCHEDULE_TOTALS["syncs"] += 1
+    def _tally(self, key: str, k: int) -> None:
+        """Add ``k`` to counter ``key`` here and in ``SCHEDULE_TOTALS``."""
+        setattr(self, key, getattr(self, key) + k)
+        SCHEDULE_TOTALS[key] += k
 
     def _put(self, a: np.ndarray) -> jax.Array:
-        """Upload one host array, counted in ``h2d_bytes`` here and in
-        ``SCHEDULE_TOTALS``."""
-        self._count(a.nbytes)
+        """Upload one host array, counted in ``h2d_bytes``."""
+        self._tally("h2d_bytes", a.nbytes)
         return jnp.asarray(a)
 
-    def _scalars(self, *xs: int) -> list:
-        """Upload int32 scalars, counted like ``_put``."""
-        self._count(4 * len(xs))
-        return [jnp.int32(x) for x in xs]
-
-    def _count(self, nbytes: int) -> None:
-        self.h2d_bytes += nbytes
-        SCHEDULE_TOTALS["h2d_bytes"] += nbytes
-
     def mark_dirty(self) -> None:
+        """The schedule changed: refresh before the next price and drop
+        every row priced ahead."""
         self._dirty = True
+        self._tally("discarded", len(self.ahead))
+        self.ahead.clear()
 
     def _refresh(self) -> None:
         s = self.sched
@@ -748,48 +759,87 @@ class DeviceScheduleWindows:
         self._scost = self._put(sc)
         self._dirty = False
 
-    def price_comm_moves(self, v: int, dst: int, ts: np.ndarray) -> np.ndarray:
-        """Fused-window twin of ``schedule_front.price_comm_moves``."""
+    def _price_windows(self, kind: str, wins, W: int, Kp: int) -> np.ndarray:
+        """One sync: refresh if dirty, upload the window rows ``wins`` --
+        ``(lo, a, b, weight)`` each, see ``_win_program`` -- as one
+        zero-padded (Kp, 4) int32 array, run the ``kind`` window program
+        over ``W``, the longest window, and read the whole result back."""
+        if self._dirty:
+            self._refresh()
+        packed = np.zeros((Kp, 4), dtype=np.int32)
+        packed[:len(wins)] = wins
+        fn = _win_program(kind, Kp, _pow2(W), self.L, self.g)
+        out = fn(self._sent if kind == "comm" else self._work, self._recv,
+                 self._stop, self._rtop, self._wtop, self._scost,
+                 self._put(packed))
+        self._tally("syncs", 1)
+        with span("windows.wait"):
+            return np.asarray(out)
+
+    def price_comm_batch(self, items) -> list[np.ndarray]:
+        """Price comm windows in one sync.  ``items`` holds up to
+        ``DEVICE_COMM_BATCH`` tuples ``(v, dst, lo, W)``; row i equals
+        ``schedule_front.price_comm_moves(sched, v, dst, range(lo, lo + W))``
+        bit for bit."""
         with span("windows.price", kind="comm"):
-            if self._dirty:
-                self._refresh()
             sched = self.sched
-            src, s = sched.comms[(v, dst)]
-            mu = sched.inst.dag.mu[v]
-            d0 = sched._comm_step_delta(s, src, dst, -mu)
-            ts = np.asarray(ts, dtype=np.int64)
-            lo, W = int(ts[0]), len(ts)
-            fn = _win_program("comm", _pow2(W), self.L, self.g)
-            out = fn(self._sent, self._recv, self._stop, self._rtop,
-                     self._wtop, self._scost,
-                     *self._scalars(lo, src, dst, int(mu)))
-            self._synced()
-            with span("windows.wait"):
-                deltas = d0 + np.asarray(out[:W], dtype=np.float64)
-            deltas[ts == s] = 0.0
-            return deltas
+            mu = sched.inst.dag.mu
+            heads = [sched.comms[(v, dst)] for v, dst, _, _ in items]
+            out = self._price_windows(
+                "comm",
+                [(lo, src, dst, int(mu[v]))
+                 for (v, dst, lo, _), (src, _) in zip(items, heads)],
+                max(W for *_, W in items),
+                _pow2(max(len(items), DEVICE_COMM_BATCH)))
+            self._tally("windows", len(items))
+            rows = []
+            for (v, dst, lo, W), (src, s), got in zip(items, heads, out):
+                d0 = sched._comm_step_delta(s, src, dst, -mu[v])
+                deltas = d0 + got[:W].astype(np.float64)
+                if lo <= s < lo + W:
+                    deltas[s - lo] = 0.0
+                rows.append(deltas)
+            return rows
+
+    def comm_window(self, v: int, dst: int, lo: int, W: int,
+                    ahead) -> np.ndarray:
+        """Deltas of comm ``(v, dst)`` over supersteps ``lo .. lo + W - 1``.
+
+        Read from ``self.ahead`` when a batch priced them since the last
+        ``mark_dirty``; otherwise priced in a new batch together with the
+        next ``DEVICE_COMM_BATCH - 1`` windows that the iterator ``ahead``
+        yields (``(v, dst, lo, W)`` each, in visit order), whose rows wait
+        in ``self.ahead``.
+        """
+        row = self.ahead.pop((v, dst), None)
+        if row is None:
+            items = [(v, dst, lo, W),
+                     *itertools.islice(ahead, DEVICE_COMM_BATCH - 1)]
+            row, *rest = self.price_comm_batch(items)
+            self.ahead = {(it[0], it[1]): r
+                          for it, r in zip(items[1:], rest)}
+        return row
+
+    def price_comm_moves(self, v: int, dst: int, ts: np.ndarray) -> np.ndarray:
+        """Fused-window twin of ``schedule_front.price_comm_moves``: a
+        one-row ``price_comm_batch``."""
+        (deltas,) = self.price_comm_batch([(v, dst, int(ts[0]), len(ts))])
+        return deltas
 
     def price_comp_moves(self, v: int, p: int, ts: np.ndarray) -> np.ndarray:
         """Fused-window twin of ``schedule_front.price_comp_moves``."""
         with span("windows.price", kind="comp"):
-            if self._dirty:
-                self._refresh()
             sched = self.sched
             s = sched.assign[v][p]
             om = sched.inst.dag.omega[v]
+            lo, W = int(ts[0]), len(ts)
+            out = self._price_windows("comp", [(lo, p, 0, int(om))], W, 1)
             w1_minus = sched._kind_max_if("work", s, p, -om)
             d_s = (sched._step_cost(w1_minus, sched.h_of(s))
                    - sched._scost[s])
-            ts = np.asarray(ts, dtype=np.int64)
-            lo, W = int(ts[0]), len(ts)
-            fn = _win_program("comp", _pow2(W), self.L, self.g)
-            out = fn(self._work, self._recv, self._stop, self._rtop,
-                     self._wtop, self._scost,
-                     *self._scalars(lo, p, 0, int(om)))
-            self._synced()
-            with span("windows.wait"):
-                deltas = d_s + np.asarray(out[:W], dtype=np.float64)
-            deltas[ts == s] = 0.0
+            deltas = d_s + out[0, :W].astype(np.float64)
+            if lo <= s < lo + W:
+                deltas[s - lo] = 0.0
             return deltas
 
     def price_node_moves(self, v: int) -> np.ndarray:
@@ -821,7 +871,7 @@ class DeviceScheduleWindows:
             out = fn(self._work, self._sent, self._recv, self._scost,
                      self._put(ts), self._put(dw), self._put(ds),
                      self._put(dr))
-            self._synced()
+            self._tally("syncs", 1)
             with span("windows.wait"):
                 deltas = np.asarray(out, dtype=np.float64)
             deltas[p] = 0.0
@@ -829,35 +879,33 @@ class DeviceScheduleWindows:
 
 
 @functools.lru_cache(maxsize=_PROGRAM_CACHE_SIZE)
-def _win_program(kind: str, Wp: int, L: int, g: int):
-    """The ``"comm"`` or ``"comp"`` window pricer over ``Wp`` supersteps
-    for BSP parameters ``L`` and ``g`` (both closed over)."""
+def _win_program(kind: str, Kp: int, Wp: int, L: int, g: int):
+    """The ``"comm"`` or ``"comp"`` window pricer of ``Kp`` windows of
+    ``Wp`` supersteps each, for BSP parameters ``L`` and ``g`` (both
+    closed over).  Row k of ``wins`` is ``(lo, a, b, weight)``: window k
+    starts at superstep ``lo``; a comm moves ``weight`` = mu from
+    processor a to b, a compute re-times ``weight`` = omega on processor
+    a.  Returns the (Kp, Wp) int32 insertion deltas."""
 
     def step_cost(w1, h):
         return jnp.where(h >= 1, w1 + L + g * h, w1)
 
-    if kind == "comm":
-        def win(sent, recv, stop, rtop, wtop, scost, lo, src, dst, mu):
-            idx = jnp.clip(lo + jnp.arange(Wp), 0, sent.shape[0] - 1)
-            s_alt = jnp.where(stop[idx, 1] == src, stop[idx, 2],
+    def win(rows, recv, stop, rtop, wtop, scost, wins):
+        lo, a, b, wt = (wins[:, k, None] for k in range(4))
+        idx = jnp.clip(lo + jnp.arange(Wp), 0, rows.shape[0] - 1)
+        if kind == "comm":      # rows are the sent rows
+            s_alt = jnp.where(stop[idx, 1] == a, stop[idx, 2],
                               stop[idx, 0])
-            s_new = sent[idx, src] + mu
-            r_alt = jnp.where(rtop[idx, 1] == dst, rtop[idx, 2],
+            r_alt = jnp.where(rtop[idx, 1] == b, rtop[idx, 2],
                               rtop[idx, 0])
-            r_new = recv[idx, dst] + mu
-            h = jnp.maximum(jnp.maximum(s_alt, s_new),
-                            jnp.maximum(r_alt, r_new))
+            h = jnp.maximum(jnp.maximum(s_alt, rows[idx, a] + wt),
+                            jnp.maximum(r_alt, recv[idx, b] + wt))
             return step_cost(wtop[idx, 0], h) - scost[idx]
-    else:
-        def win(sent, recv, stop, rtop, wtop, scost, lo, src, dst, mu):
-            # comp re-timing: src slot carries p, mu carries omega
-            idx = jnp.clip(lo + jnp.arange(Wp), 0, sent.shape[0] - 1)
-            w_alt = jnp.where(wtop[idx, 1] == src, wtop[idx, 2],
-                              wtop[idx, 0])
-            w_new = sent[idx, src] + mu  # sent slot carries work rows
-            w1 = jnp.maximum(w_alt, w_new)
-            h = jnp.maximum(stop[idx, 0], rtop[idx, 0])
-            return step_cost(w1, h) - scost[idx]
+        # comp: rows are the work rows
+        w_alt = jnp.where(wtop[idx, 1] == a, wtop[idx, 2], wtop[idx, 0])
+        w1 = jnp.maximum(w_alt, rows[idx, a] + wt)
+        h = jnp.maximum(stop[idx, 0], rtop[idx, 0])
+        return step_cost(w1, h) - scost[idx]
 
     return jax.jit(win)
 

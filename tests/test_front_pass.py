@@ -25,10 +25,11 @@ from repro.core.partition import PartitionState
 from repro.core.partition.cost import capacity
 from repro.core.partition.heuristic import (fm_refine, greedy_initial,
                                             replicate_local_search)
-from repro.core.schedule import BspInstance, bspg_schedule
+import repro.core.frontier as frontier
+from repro.core.schedule import BspInstance, bspg_schedule, list_sched
 from repro.core.schedule.list_sched import (comp_rebalance_pass, hill_climb,
                                             node_move_pass, rebalance_comms)
-from repro.datagen import spmv_dataset, tiny_dataset
+from repro.datagen import large_sptrsv_dag, spmv_dataset, tiny_dataset
 from repro.kernels import front_pass, gain, ops
 
 
@@ -55,6 +56,19 @@ def random_dag(n, seed, fanin=3, p_edge=0.5, n_src=8, weighted=False):
     omega = rng.uniform(0.5, 4.0, size=n) if weighted else None
     mu = rng.uniform(0.5, 3.0, size=n) if weighted else None
     return Dag(n=n, edge_list=edges, omega=omega, mu=mu)
+
+
+def deep_dag(n, width, seed):
+    """``width`` chains of dependencies plus random earlier parents: a deep
+    DAG whose flat schedules hold comm windows of 12 supersteps or more."""
+    rng = np.random.default_rng(seed)
+    edges = set()
+    for v in range(width, n):
+        edges.add((v - width, v))
+        for u in rng.choice(v, size=2, replace=False):
+            if rng.random() < 0.5:
+                edges.add((int(u), v))
+    return Dag(n=n, edge_list=sorted(edges))
 
 
 @contextlib.contextmanager
@@ -372,9 +386,9 @@ def test_window_programs_keyed_on_L_and_g():
         assert shared[kind]["misses"] == first[kind]["misses"]
         assert shared[kind]["hits"] > first[kind]["hits"]
         assert other[kind]["misses"] > shared[kind]["misses"]
-    Wp = front_pass._pow2(s1.S)
-    assert (front_pass._win_program("comm", Wp, 4, 2)
-            is not front_pass._win_program("comm", Wp, 4, 4))
+    Kp, Wp = front_pass.DEVICE_COMM_BATCH, front_pass._pow2(s1.S)
+    assert (front_pass._win_program("comm", Kp, Wp, 4, 2)
+            is not front_pass._win_program("comm", Kp, Wp, 4, 4))
 
 
 def test_schedule_passes_bit_identical():
@@ -416,6 +430,101 @@ def test_shipped_tiny_dag_bit_identical():
         hill_climb(sa, seed=0)
         hill_climb(sb, seed=0, backend="jax")
     assert sched_snap(sa) == sched_snap(sb)
+
+
+def test_price_comm_batch_matches_numpy():
+    """Every row of one batched sync equals the numpy front bit for bit:
+    windows of mixed lengths, a batch shorter than ``Kp`` and a full one,
+    windows that end at the last superstep and windows that hold the
+    comm's own superstep (priced 0 there)."""
+    sched = bspg_schedule(BspInstance(large_sptrsv_dag(n=600, seed=0), P=4,
+                                      g=2, L=4), seed=0)
+    S = sched.S
+    win = device_windows(sched, "jax")
+    items = []
+    for k, (v, dst) in enumerate(sorted(sched.comms)):
+        _, s = sched.comms[(v, dst)]
+        lo, W = [(0, S),                          # whole range
+                 (s, 1),                          # the own superstep alone
+                 (S - 1, 1),                      # the last superstep alone
+                 (max(s - 3, 0), min(7, S - max(s - 3, 0))),
+                 (s + 1, S - s - 1)][k % 5]       # after s, to the end
+        if W >= 1:
+            items.append((v, dst, lo, W))
+    K = front_pass.DEVICE_COMM_BATCH
+    assert len(items) > K and len({W for *_, W in items}) > 3
+    assert any(lo <= sched.comms[(v, dst)][1] < lo + W
+               for v, dst, lo, W in items)
+    for batch in (items[:5], items[5:5 + K]):
+        syncs = win.syncs
+        rows = win.price_comm_batch(batch)
+        assert win.syncs == syncs + 1
+        for (v, dst, lo, W), row in zip(batch, rows, strict=True):
+            assert np.array_equal(
+                row, price_comm_moves(sched, v, dst, np.arange(lo, lo + W)))
+    assert win.windows == 5 + K and win.discarded == 0
+
+
+def _lookahead_instances():
+    """The random DAGs of ``test_schedule_passes_bit_identical`` and the
+    shipped tiny DAG, each with its seed."""
+    for seed in range(4):
+        n = int(np.random.default_rng(seed).integers(40, 110))
+        P = int(np.random.default_rng(seed + 1).integers(2, 6))
+        yield BspInstance(dag=random_dag(n, seed), P=P, g=2.0, L=4.0), seed
+    yield BspInstance(dag=random_dag(80, 5), P=4, g=2.0, L=4.0), 5
+    yield BspInstance(dag=tiny_dataset()[0], P=4, g=2.0, L=4.0), 0
+    yield BspInstance(dag=deep_dag(160, 4, 1), P=4, g=2.0, L=4.0), 1
+
+
+@pytest.mark.parametrize("batch", [1, 2, None])
+def test_rebalance_comms_lookahead_bit_identical(monkeypatch, batch):
+    """``rebalance_comms`` on the device path, whose syncs price the
+    visited comm window with the next ones, ends where numpy ends.  The
+    random DAGs' flat schedules hold no window of the default 12
+    supersteps, so the floor is lowered: every window of 2 or more prices
+    on the device."""
+    if batch is not None:
+        monkeypatch.setattr(front_pass, "DEVICE_COMM_BATCH", batch)
+    monkeypatch.setattr(list_sched, "_COMM_FRONT_MIN_WINDOW", 2)
+    t0 = dict(front_pass.SCHEDULE_TOTALS)
+    for inst, seed in _lookahead_instances():
+        sa = bspg_schedule(inst, seed=seed)
+        sb = bspg_schedule(inst, seed=seed)
+        rebalance_comms(sa)
+        rebalance_comms(sb, backend="jax")
+        assert sched_snap(sa) == sched_snap(sb)
+    d = {k: front_pass.SCHEDULE_TOTALS[k] - t0[k] for k in t0}
+    assert d["syncs"] > 0
+    if front_pass.DEVICE_COMM_BATCH == 1:
+        assert d["windows"] == d["syncs"] and d["discarded"] == 0
+    else:
+        assert d["windows"] > d["syncs"] and d["discarded"] > 0
+
+
+@pytest.mark.parametrize("n, width, seed", [(120, 4, 0), (240, 6, 3)])
+def test_comm_lookahead_sync_accounting(monkeypatch, n, width, seed):
+    """Rows used equal the numpy path's long-window visits; syncs stay
+    within ``moves + passes + ceil(windows / K)`` and under the windows."""
+    inst = BspInstance(deep_dag(n, width, seed), P=4, g=2.0, L=4.0)
+    sa, sb = bspg_schedule(inst, seed=0), bspg_schedule(inst, seed=0)
+    visits, moves = [], []
+    price = frontier.price_comm_moves
+    monkeypatch.setattr(frontier, "price_comm_moves",
+                        lambda *a: visits.append(a[1:3]) or price(*a))
+    rebalance_comms(sa, max_passes=4)
+    move = sb.move_comm
+    monkeypatch.setattr(sb, "move_comm",
+                        lambda *a: moves.append(a) or move(*a))
+    t0 = dict(front_pass.SCHEDULE_TOTALS)
+    rebalance_comms(sb, max_passes=4, backend="jax")
+    d = {k: front_pass.SCHEDULE_TOTALS[k] - t0[k] for k in t0}
+    assert sched_snap(sa) == sched_snap(sb)
+    K = front_pass.DEVICE_COMM_BATCH
+    assert moves and d["discarded"] > 0
+    assert d["windows"] - d["discarded"] == len(visits)
+    assert d["syncs"] <= len(moves) + 4 + -(-d["windows"] // K)
+    assert d["syncs"] < d["windows"]
 
 
 def test_schedule_float_weights_fall_back():

@@ -204,9 +204,11 @@ def test_schedule_h2d_bytes(floors):
     rows = sum(int(a.nbytes) for a in (
         win._sent, win._recv, win._work, win._stop, win._rtop, win._wtop,
         win._scost))
-    assert win.h2d_bytes == rows + 4 * 4          # the refresh, 4 scalars
+    # the refresh, then one (Kp, 4) int32 array of window rows per batch
+    per_batch = front_pass.DEVICE_COMM_BATCH * 4 * 4
+    assert win.h2d_bytes == rows + per_batch
     win.price_comm_moves(v, dst, np.arange(0, sched.S))   # no refresh
-    assert win.h2d_bytes == rows + 2 * 4 * 4
+    assert win.h2d_bytes == rows + 2 * per_batch
     assert front_pass.SCHEDULE_TOTALS["h2d_bytes"] - t0 == win.h2d_bytes
 
 

@@ -86,3 +86,17 @@ def test_find_program_compiles_at_smoke_shape(one_chip, mode):
     # the kernel carries its own name into the compiled program, so a
     # trace or an HLO dump tells it from any other Pallas kernel
     assert "front_dlam" in text
+
+
+@pytest.mark.parametrize("kind, Kp", [("comm", front_pass.DEVICE_COMM_BATCH),
+                                      ("comp", 1)])
+def test_window_program_compiles_at_symgs_shape(one_chip, kind, Kp):
+    """A schedule window program at the SYMGS cell's shapes: 256 padded
+    supersteps, P = 8, a batch of ``Kp`` windows of up to 256 supersteps,
+    g = 4, L = 20."""
+    Sp, P, Wp = 256, 8, 256
+    fn = front_pass._win_program(kind, Kp, Wp, 20, 4)
+    s = lambda shape: _spec(one_chip, shape)  # noqa
+    compiled = fn.lower(s((Sp, P)), s((Sp, P)), s((Sp, 3)), s((Sp, 3)),
+                        s((Sp, 3)), s((Sp,)), s((Kp, 4))).compile()
+    assert compiled.out_info.shape == (Kp, Wp)
