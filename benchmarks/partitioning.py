@@ -180,71 +180,6 @@ def bench_engine(P=4, eps=0.05, seed=0):
     return {"scale": rows, "replication_large": large}
 
 
-def bench_frontier(P=4, eps=0.05, seed=0):
-    """Frontier layer old-vs-new at scale (PR 3 tentpole).
-
-    Times ``partition_heuristic`` with the pre-frontier per-node rescan
-    (``frontier="off"``), the batched NumPy front path (default) and the
-    JAX backend (Pallas gain kernel on TPU, jnp fallback elsewhere --
-    included for the record; on CPU device dispatch costs more than the
-    batched reduction saves).  All three are decision-identical, so the
-    only deliverable difference is wall-clock; a cost mismatch is a bug.
-    Also times the end-to-end replication pipeline old-vs-new at the
-    smallest size.
-    """
-    sizes = (2048, 4096, 6000)
-    try:  # the jax rows are optional: the rest of the repo runs numpy-only
-        import jax  # noqa: F401
-        modes = ("off", "numpy", "jax")
-    except ImportError:
-        modes = ("off", "numpy")
-    rows = []
-    for n in sizes:
-        nz = synthetic_sparse_matrix(n, n, seed=seed + n)
-        hg = row_net_hypergraph(nz, n, name=f"spmv_rn_{n}")
-        timings, costs = {}, {}
-        for mode in modes:
-            if mode == "jax":
-                # untimed run first: front sizes are padded per instance
-                # size, so this compiles exactly the jit shapes the timed
-                # run uses (steady-state, not compilation)
-                partition_heuristic(hg, P, eps, seed=seed, frontier="jax")
-            t0 = time.perf_counter()
-            res = partition_heuristic(hg, P, eps, seed=seed, frontier=mode)
-            timings[mode] = time.perf_counter() - t0
-            costs[mode] = float(res.cost)
-        assert len(set(costs.values())) == 1, costs
-        row = {
-            "n": hg.n, "edges": len(hg.edges), "pins": int(hg.num_pins),
-            "P": P, "eps": eps, "cost": costs["numpy"],
-            "seconds_off": timings["off"],
-            "seconds_numpy": timings["numpy"],
-            "speedup_numpy": timings["off"] / timings["numpy"],
-        }
-        if "jax" in timings:
-            row["seconds_jax"] = timings["jax"]
-            row["speedup_jax"] = timings["off"] / timings["jax"]
-        rows.append(row)
-    # end-to-end replication pipeline, old vs new front pricing
-    n = sizes[0]
-    nz = synthetic_sparse_matrix(n, n, seed=seed)
-    hg = row_net_hypergraph(nz, n, name="spmv_rn_rep")
-    t0 = time.perf_counter()
-    base_off, rep_off = partition_with_replication(
-        hg, P, eps, mode="rep", exact_node_limit=0, seed=seed, frontier="off")
-    t_off = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    base_on, rep_on = partition_with_replication(
-        hg, P, eps, mode="rep", exact_node_limit=0, seed=seed)
-    t_on = time.perf_counter() - t0
-    assert rep_off.cost == rep_on.cost and base_off.cost == base_on.cost
-    replication = {"n": n, "base_cost": float(base_on.cost),
-                   "rep_cost": float(rep_on.cost),
-                   "seconds_off": t_off, "seconds_numpy": t_on,
-                   "speedup_numpy": t_off / t_on}
-    return {"scale": rows, "replication": replication}
-
-
 def bench_multilevel(P=8, eps=0.05, seed=0, sizes=None, flat_limit=None):
     """Flat vs multilevel V-cycle at scale (PR 4 tentpole).
 
@@ -293,35 +228,24 @@ def bench_multilevel(P=8, eps=0.05, seed=0, sizes=None, flat_limit=None):
 
 def bench_device_resident(P=4, eps=0.05, seed=0, sizes=None,
                           pallas_row=True):
-    """Device-resident FM pass vs per-front dispatch vs numpy (PR 6).
+    """Device-resident FM pass vs numpy (PR 6).
 
     Times one ``fm_refine`` call per variant on integer-weight row-net
-    instances: the numpy frontier (PR 3 host path), the per-front jax
-    dispatch (PR 3 jax path, forced by raising the device floor above n),
-    the whole-pass device-resident program (one host sync per committed
-    move), and -- at the smallest size only, interpret mode is slow off
-    the TPU -- the Pallas find-pricing path (``seconds_device_pallas``,
-    with ``pallas_interpret`` saying how it ran).  All variants are decision-identical, so a
-    cost mismatch is a bug; host-sync counters come from an instrumented
-    ``run_fm`` on the same instance and land in ``BENCH_partition.json``
-    as ``device_resident`` via ``run.py``.
-
-    The ``price_*`` fields isolate the pricing deliverable: one fused
-    device scan over every candidate row of a pass vs the PR 3 per-front
-    dispatch (host row gather + one ``min_cover_lambdas`` call per
-    front) -- the fused path wins on CPU (~2.3x at n=8192, 262k rows).
-    End-to-end ``seconds_device`` still trails numpy on CPU because each
-    committed move costs a find dispatch plus an apply dispatch (the
-    one-sync contract); the commit-batching follow-up and the compiled
-    TPU path are ROADMAP open item 3.
+    instances: the numpy frontier (the host path), the whole-pass
+    device-resident program (one host sync per committed move), and -- at
+    the smallest size only, interpret mode is slow off the TPU -- the
+    Pallas find-pricing path (``seconds_device_pallas``, with
+    ``pallas_interpret`` saying how it ran).  All variants are
+    decision-identical, so a cost mismatch is a bug; host-sync counters
+    come from an instrumented ``run_fm`` on the same instance and land in
+    ``BENCH_partition.json`` as ``device_resident`` via ``run.py``.
     """
     from repro.kernels import front_pass, gain
 
     sizes = sizes or ((8192, 16384, 32768) if FULL else (4096, 8192))
     # generators trim empty rows, so instances land slightly under the
     # nominal size -- pin the attach floor below the smallest instance for
-    # the duration of the bench (the per-front variant force-raises it
-    # per size anyway)
+    # the duration of the bench
     floor_saved = front_pass.DEVICE_MIN_NODES
     front_pass.DEVICE_MIN_NODES = min(min(sizes) // 2, floor_saved)
     try:
@@ -335,7 +259,7 @@ def _device_resident_rows(sizes, P, eps, seed, pallas_row):
     from repro.core.partition import PartitionState
     from repro.core.partition.cost import capacity
     from repro.core.partition.heuristic import fm_refine, greedy_initial
-    from repro.kernels import front_pass, gain, ops
+    from repro.kernels import ops
     from repro.core.frontier import device_pass
 
     rows = []
@@ -355,61 +279,26 @@ def _device_resident_rows(sizes, P, eps, seed, pallas_row):
             return time.perf_counter() - t0, float(st.cost)
 
         t_np, c_np = timed("numpy")
-        saved = front_pass.DEVICE_MIN_NODES
-        front_pass.DEVICE_MIN_NODES = n + 1      # force per-front dispatch
-        try:
-            t_pf, c_pf = timed("jax", warm=True)
-        finally:
-            front_pass.DEVICE_MIN_NODES = saved
         t_dev, c_dev = timed("jax", warm=True)
-        assert c_np == c_pf == c_dev, (n, c_np, c_pf, c_dev)
+        assert c_np == c_dev, (n, c_np, c_dev)
 
         # instrumented run: host syncs per committed move
         st = PartitionState(hg, P, masks=m0.copy())
         dev = device_pass(st, capacity(hg, P, eps) + 1e-9, backend="jax")
         try:
             dev.run_fm(np.random.default_rng(seed), 6)
-            # counter snapshot BEFORE the pricing microbench below -- its
-            # extra find dispatches are timing probes, not sweep syncs
             counters = {"syncs": dev.syncs, "commits": dev.commits,
                         "pass_scans": dev.pass_scans,
                         "apply_dispatches": dev.apply_dispatches}
-            # pricing microbench (the acceptance row): every candidate row
-            # of a full pass, priced by one fused device scan (what each
-            # find dispatches) vs the PR 3 per-front path (host row gather
-            # + one min_cover_lambdas call per front) over the same rows
-            all_bnd = np.ones(hg.n, dtype=bool)
-            reps = 5
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                dev._call_find(dev._find_fm, 0, 0, -1, 0, all_bnd)
-            t_fused = (time.perf_counter() - t0) / reps
-            edges_np = np.asarray(dev._blk_edge).ravel()
-            n_rows = edges_np.size
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                for lo in range(0, n_rows, dev.R_blk):
-                    rows_h = st.uncov[np.minimum(edges_np[lo:lo + dev.R_blk],
-                                                 len(hg.edges) - 1)]
-                    lam = gain.min_cover_lambdas(rows_h, st._order,
-                                                 st._order_pc)
-                    np.argmin(np.maximum(lam - 1, 0))
-            t_perfront = (time.perf_counter() - t0) / reps
         finally:
             dev.detach()
         row = {
             "n": hg.n, "edges": len(hg.edges), "pins": int(hg.num_pins),
             "P": P, "eps": eps, "cost": c_np,
             "seconds_numpy": t_np,
-            "seconds_perfront_jax": t_pf,
             "seconds_device": t_dev,
             "speedup_vs_numpy": t_np / t_dev,
-            "speedup_vs_perfront": t_pf / t_dev,
             **counters,
-            "front_rows": int(n_rows),
-            "price_seconds_fused": t_fused,
-            "price_seconds_perfront": t_perfront,
-            "price_speedup": t_perfront / max(t_fused, 1e-9),
         }
         if pallas_row and n == sizes[0]:
             # the Pallas find-pricing path: compiled on a TPU (where it is
@@ -549,7 +438,6 @@ def run_all():
     results["table1"] = table1_eps_sweep()
     results["forms"] = table_forms()
     results["engine"] = bench_engine()
-    results["frontier"] = bench_frontier()
     results["multilevel"] = bench_multilevel()
     results["device"] = bench_device_resident()
     results["parallel"] = bench_parallel()

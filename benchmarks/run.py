@@ -50,8 +50,6 @@ def main() -> None:
     bench = {
         "engine_scale": part["engine"]["scale"],
         "replication_large": part["engine"]["replication_large"],
-        "frontier_scale": part["frontier"]["scale"],
-        "frontier_replication": part["frontier"]["replication"],
         "multilevel_scale": part["multilevel"]["scale"],
         "device_resident": part["device"],
         "parallel_scale": part["parallel"]["scale"],
@@ -69,23 +67,12 @@ def main() -> None:
         _emit(f"partition_engine_n{row['n']}", row["engine_seconds"],
               f"inst_per_sec={row['engine_instances_per_sec']:.2f};"
               f"cost={row['engine_cost']:.0f}" + spd)
-    for row in part["frontier"]["scale"]:
-        jx = (f"speedup_jax={row['speedup_jax']:.2f}x;"
-              if "speedup_jax" in row else "")
-        _emit(f"partition_frontier_n{row['n']}", row["seconds_numpy"],
-              f"speedup_numpy={row['speedup_numpy']:.2f}x;" + jx
-              + f"cost={row['cost']:.0f}")
-    frep = part["frontier"]["replication"]
-    _emit(f"partition_frontier_rep_n{frep['n']}", frep["seconds_numpy"],
-          f"speedup_numpy={frep['speedup_numpy']:.2f}x;"
-          f"rep_cost={frep['rep_cost']:.0f}")
     for row in part["device"].get("scale", []):
         pi = (f";pallas={row['seconds_device_pallas']:.2f}s"
               f";interpret={row['pallas_interpret']}"
               if "seconds_device_pallas" in row else "")
         _emit(f"partition_device_n{row['n']}", row["seconds_device"],
               f"speedup_vs_numpy={row['speedup_vs_numpy']:.2f}x;"
-              f"speedup_vs_perfront={row['speedup_vs_perfront']:.2f}x;"
               f"syncs={row['syncs']};commits={row['commits']}" + pi)
     for row in part["parallel"].get("scale", []):
         rel = (f"speedup_vs_w1={row['speedup_vs_w1']:.2f}x;"
@@ -127,9 +114,7 @@ def main() -> None:
     # at scale plus the cost-reduction trajectory -- future PRs diff this.
     sched_bench = {
         "engine_scale": sched["engine"],
-        "frontier_scale": sched["frontier"],
         "multilevel_scale": sched["multilevel"],
-        "split_scale": sched["split"],
         "device_resident": sched["device"],
         "cost_reduction": sched["table2"],
     }
@@ -142,12 +127,6 @@ def main() -> None:
               f"speedup_baseline={row['speedup_baseline']:.1f}x;"
               f"cost={row['advanced_cost']:.0f};"
               f"costs_match={row['costs_match']}")
-    for row in sched["frontier"]:
-        _emit(f"schedule_frontier_{row['name']}",
-              row["advanced_seconds_front"],
-              f"hc_speedup={row['hill_climb_speedup']:.2f}x;"
-              f"adv_speedup={row['advanced_speedup']:.2f}x;"
-              f"adv_cost={row['advanced_cost_front']:.0f}")
     for row in sched["device"]:
         _emit(f"schedule_device_{row['name']}", row["seconds_device"],
               f"speedup_vs_numpy={row['speedup_vs_numpy']:.2f}x;"
@@ -161,14 +140,6 @@ def main() -> None:
         _emit(f"schedule_multilevel_{row['name']}", row["ml_seconds"],
               flat + f"ml_cost={row['ml_cost']:.0f};"
               f"S={row['ml_supersteps']};replicas={row['ml_replicas']}")
-    for row in sched["split"]:
-        guarded = (f"guarded={row['guarded_seconds']:.1f}s;"
-                   f"retired={row['guard_retired_seconds']:.1f}s;"
-                   f"not_worse={row['split_not_worse_than_guarded']};"
-                   if "guarded_seconds" in row else "")
-        _emit(f"schedule_split_{row['name']}", row["split_seconds"],
-              guarded + f"split_cost={row['split_cost']:.0f};"
-              f"S={row['split_supersteps']}")
 
     # ---- online serving replay (machine-readable) ------------------------
     # BENCH_serve.json at the repo root: drift + stationary replays of the
@@ -229,14 +200,6 @@ def parallel_smoke() -> None:
     print(json.dumps({"partition": partitioning.parallel_smoke()}, indent=1))
 
 
-def schedule_split_smoke() -> None:
-    """``run.py --schedule-split-smoke``: CI-sized proof of the guard
-    retirement -- the guard-off split-enabled V-cycle must not cost more
-    than the old guarded driver on replication-hungry psdd instances."""
-    from benchmarks import scheduling
-    print(json.dumps({"schedule": scheduling.split_smoke()}, indent=1))
-
-
 def serve_smoke() -> None:
     """``run.py --serve-smoke``: CI-sized drift replay asserting the online
     placement contracts -- online <= static-replicated comm cost, valid
@@ -255,8 +218,6 @@ if __name__ == "__main__":
         device_smoke()
     elif "--parallel-smoke" in sys.argv:
         parallel_smoke()
-    elif "--schedule-split-smoke" in sys.argv:
-        schedule_split_smoke()
     elif "--serve-smoke" in sys.argv:
         serve_smoke()
     else:

@@ -190,64 +190,16 @@ def engine_scale(P=8, g=4, L=20):
     return rows
 
 
-def frontier_scale(P=8, g=4, L=20):
-    """Frontier layer old-vs-new on the scheduling stack (PR 3 tentpole).
-
-    Per instance: the hill climber with node moves priced per-target
-    (``use_fronts=False``, the pre-frontier loop) vs one batched front per
-    node, and the advanced heuristic with the first-improvement SR sweep
-    vs the frontier SR pass (whole ``(p1, p2)`` front priced purely, only
-    the winner committed through a transaction).  The hill-climb pair is
-    decision-identical (costs must match); the SR pair deliberately
-    differs in decision rule, so both costs are recorded.
-    """
-    instances = [
-        ("sptrsv_6000", sptrsv_dag(n=6000, band=48, seed=0)),
-        ("sptrsv_3000", sptrsv_dag(n=3000, band=32, seed=0)),
-        ("psdd_2035", psdd_dag(n_leaves=500, depth=16, seed=0)),
-    ]
-    rows = []
-    for name, dag in instances:
-        inst = BspInstance(dag, P=P, g=float(g), L=float(L))
-        base = bspg_schedule(inst, seed=0)
-        t0 = time.perf_counter()
-        hc_on = hill_climb(base.copy(), seed=0)
-        t1 = time.perf_counter()
-        hc_off = hill_climb(base.copy(), seed=0, use_fronts=False)
-        t2 = time.perf_counter()
-        adv_on = advanced_heuristic(hc_on.copy())
-        t3 = time.perf_counter()
-        adv_off = advanced_heuristic(hc_on.copy(),
-                                     AdvancedOptions(use_fronts=False))
-        t4 = time.perf_counter()
-        assert hc_on.current_cost() == hc_off.current_cost()
-        rows.append({
-            "name": name, "n": dag.n, "P": P,
-            "hill_climb_seconds_front": t1 - t0,
-            "hill_climb_seconds_off": t2 - t1,
-            "hill_climb_speedup": (t2 - t1) / max(t1 - t0, 1e-9),
-            "advanced_seconds_front": t3 - t2,
-            "advanced_seconds_off": t4 - t3,
-            "advanced_speedup": (t4 - t3) / max(t3 - t2, 1e-9),
-            "hill_climb_cost": float(hc_on.current_cost()),
-            "advanced_cost_front": float(adv_on.current_cost()),
-            "advanced_cost_off": float(adv_off.current_cost()),
-        })
-    return rows
-
-
 def multilevel_scale(P=8, g=4, L=20, sizes=None, flat_limit=None, seed=0):
     """Flat vs multilevel V-cycle scheduling at scale (PR 5 tentpole).
 
     End-to-end ``best_replicated_schedule`` on sptrsv/psdd instances: the
-    *pure* V-cycle (``flat_guard_n=0``, so ``ml_seconds``/``vcycle_cost``
-    measure the V-cycle itself, not a hidden flat run) at every size, the
-    flat path up to ``flat_limit`` nodes (beyond it a single flat run
-    takes minutes to hours -- the scaling wall the V-cycle removes; the
-    paper schedules up to 175k-node DAGs in exactly this coarse-grained
-    regime).  ``ml_cost`` is what the default guarded driver returns --
-    ``min(vcycle, flat)`` wherever both ran, the V-cycle alone beyond the
-    guard -- so ``cost_not_worse`` holds by construction and
+    V-cycle (``ml_seconds``/``vcycle_cost``) at every size, the flat path
+    up to ``flat_limit`` nodes (beyond it a single flat run takes minutes
+    to hours -- the scaling wall the V-cycle removes; the paper schedules
+    up to 175k-node DAGs in exactly this coarse-grained regime).
+    ``ml_cost`` is ``min(vcycle, flat)`` wherever both ran, the V-cycle
+    alone beyond -- so ``cost_not_worse`` holds by construction and
     ``vcycle_not_worse`` reports whether the V-cycle won organically.
     Rows land in ``BENCH_schedule.json`` as ``multilevel_scale`` via
     ``run.py``.
@@ -270,9 +222,7 @@ def multilevel_scale(P=8, g=4, L=20, sizes=None, flat_limit=None, seed=0):
                                  seed=seed)
         inst = BspInstance(dag, P=P, g=float(g), L=float(L))
         t0 = time.perf_counter()
-        mlv = best_replicated_schedule(
-            inst, seed=seed, multilevel=True,
-            ml_opts=MultilevelScheduleOptions(flat_guard_n=0))
+        mlv = best_replicated_schedule(inst, seed=seed, multilevel=True)
         t_ml = time.perf_counter() - t0
         assert mlv.validate() == []
         row = {
@@ -305,87 +255,6 @@ def multilevel_scale(P=8, g=4, L=20, sizes=None, flat_limit=None, seed=0):
                                              <= flat.current_cost() + 1e-9),
                        cost_not_worse=bool(guarded
                                            <= flat.current_cost() + 1e-9))
-        rows.append(row)
-    return rows
-
-
-def split_scale(P=8, g=4, L=20, sizes=None, seed=0):
-    """Guard retirement at scale (PR 9 tentpole).
-
-    Per size, up to three end-to-end ``best_replicated_schedule`` variants
-    on the same instance:
-
-    * ``guarded``    -- the pre-PR 9 default (``flat_guard_n=8192``,
-      splits off): the V-cycle plus one full flat hedge run.  Only at
-      n <= 8192, where the flat path is tractable.
-    * ``guard_free`` -- ``flat_guard_n=0``, splits off: what retiring the
-      guard *without* the split front would return (capped at n <= 200k
-      to keep the section's wall-clock sane).
-    * ``split``      -- the new default: guard retired, split front on in
-      every per-level refinement.  Runs at every size, including the
-      n = 10^6 sptrsv row (FULL) -- the scale gate the guard used to
-      make unreachable.
-
-    Asserted per row wherever the guarded variant ran: the new default's
-    cost is <= the old guarded cost (the PR 9 acceptance gate), while
-    ``guard_retired_seconds`` -- guarded minus split wall-clock, i.e.
-    what retiring the hedge saves end to end -- is disclosed.  Variants
-    that did not run at a size are absent from the row, never silently
-    extrapolated.
-
-    Known non-parity instance (disclosed, not benched as a guarded row):
-    psdd_large n=8165 (``large_psdd_dag(n_leaves=2000, depth=16)``) is a
-    V-cycle fixpoint at 3814 where the flat trajectory reaches 3795
-    (+0.5%); forced-split kicks plus full flat polish close it only to
-    3800.  The gap is in the assignment structure, not the superstep
-    structure -- the split front cannot reach it.  The psdd guarded row
-    here runs n=4080, where the guard-free default beats the flat hedge
-    outright (1903 vs 1926).
-    """
-    if sizes is None:
-        sizes = ([("sptrsv", 2000), ("sptrsv", 6000), ("sptrsv", 8192),
-                  ("psdd", 4000), ("sptrsv", 50_000), ("sptrsv", 100_000),
-                  ("sptrsv", 1_000_000)] if FULL else
-                 [("sptrsv", 2000), ("sptrsv", 6000), ("psdd", 4000)])
-    rows = []
-    for kind, n in sizes:
-        if kind == "sptrsv":
-            dag = (large_sptrsv_dag(n, band=48, seed=seed) if n > 8192
-                   else sptrsv_dag(n=n, band=32 if n <= 3000 else 48,
-                                   seed=seed))
-        else:
-            dag = large_psdd_dag(n_leaves=max(250, n // 4), depth=16,
-                                 seed=seed)
-        inst = BspInstance(dag, P=P, g=float(g), L=float(L))
-        row = {"name": dag.name, "n": dag.n, "edges": dag.num_edges,
-               "P": P, "g": g, "L": L}
-        t0 = time.perf_counter()
-        split = best_replicated_schedule(inst, seed=seed, multilevel=True)
-        row["split_seconds"] = time.perf_counter() - t0
-        row["split_cost"] = float(split.current_cost())
-        row["split_supersteps"] = split.S
-        row["split_replicas"] = sum(len(a) - 1 for a in split.assign
-                                    if len(a) > 1)
-        assert split.validate() == []
-        if dag.n <= 200_000:
-            t0 = time.perf_counter()
-            gf = best_replicated_schedule(
-                inst, seed=seed, multilevel=True,
-                ml_opts=MultilevelScheduleOptions(superstep_splits=False))
-            row["guard_free_seconds"] = time.perf_counter() - t0
-            row["guard_free_cost"] = float(gf.current_cost())
-        if dag.n <= 8192:
-            t0 = time.perf_counter()
-            guarded = best_replicated_schedule(
-                inst, seed=seed, multilevel=True,
-                ml_opts=MultilevelScheduleOptions(flat_guard_n=8192,
-                                                  superstep_splits=False))
-            row["guarded_seconds"] = time.perf_counter() - t0
-            row["guarded_cost"] = float(guarded.current_cost())
-            row["guard_retired_seconds"] = (row["guarded_seconds"]
-                                            - row["split_seconds"])
-            assert row["split_cost"] <= row["guarded_cost"] + 1e-9, row
-            row["split_not_worse_than_guarded"] = True
         rows.append(row)
     return rows
 
@@ -467,7 +336,7 @@ def multilevel_smoke(P=8, g=4, L=20):
     push -- coarsen, coarse solve, project, refine, replica-prune -- with
     validity and flat-parity asserts at sizes where both run in seconds.
     """
-    opts = MultilevelScheduleOptions(coarsest_n=400, flat_guard_n=0)
+    opts = MultilevelScheduleOptions(coarsest_n=400)
     rows = []
     for n in (1500, 2500):
         dag = sptrsv_dag(n=n, band=32, seed=0)
@@ -486,35 +355,6 @@ def multilevel_smoke(P=8, g=4, L=20):
     return {"multilevel_smoke": rows}
 
 
-def split_smoke(P=8, g=4, L=20):
-    """Small-n CI smoke (PR 9): on every push, the guard-retired default
-    (splits on) must return a schedule no costlier than the old guarded
-    driver's on a replication-hungry psdd instance -- the family the flat
-    hedge existed for."""
-    rows = []
-    for n_leaves, depth in ((500, 12), (800, 12)):
-        dag = psdd_dag(n_leaves=n_leaves, depth=depth, seed=1)
-        inst = BspInstance(dag, P=P, g=float(g), L=float(L))
-        t0 = time.perf_counter()
-        mlv = best_replicated_schedule(inst, seed=0, multilevel=True)
-        t_new = time.perf_counter() - t0
-        assert mlv.validate() == []
-        t0 = time.perf_counter()
-        guarded = best_replicated_schedule(
-            inst, seed=0, multilevel=True,
-            ml_opts=MultilevelScheduleOptions(flat_guard_n=8192,
-                                              superstep_splits=False))
-        t_old = time.perf_counter() - t0
-        assert mlv.current_cost() <= guarded.current_cost() + 1e-9, \
-            (dag.n, mlv.current_cost(), guarded.current_cost())
-        rows.append({"n": dag.n,
-                     "split_cost": float(mlv.current_cost()),
-                     "guarded_cost": float(guarded.current_cost()),
-                     "split_seconds": t_new, "guarded_seconds": t_old,
-                     "guard_retired_seconds": t_old - t_new})
-    return {"split_smoke": rows}
-
-
 def run_all():
     t0 = time.time()
     results = {
@@ -523,9 +363,7 @@ def run_all():
         "table4": table4_ablation(),
         "table13": table13_size_consistency(),
         "engine": engine_scale(),
-        "frontier": frontier_scale(),
         "multilevel": multilevel_scale(),
-        "split": split_scale(),
         "device": device_scale(),
     }
     results["seconds"] = time.time() - t0
@@ -537,7 +375,5 @@ if __name__ == "__main__":
     import sys
     if "--schedule-multilevel-smoke" in sys.argv:
         print(json.dumps(multilevel_smoke(), indent=1))
-    elif "--schedule-split-smoke" in sys.argv:
-        print(json.dumps(split_smoke(), indent=1))
     else:
         print(json.dumps(run_all(), indent=1))

@@ -5,10 +5,10 @@ made each *single* pricing incremental, this package makes whole *fronts*
 of candidates one vectorized evaluation:
 
   * ``partition_front`` -- ragged batched gain evaluation over the CSR
-    arrays of a ``PartitionState`` (NumPy backend, always available, and a
-    JAX/Pallas backend via ``repro.kernels.gain``), plus the ``GainCache``
-    that makes FM / replication passes output-sensitive (only nodes whose
-    gain changed are repriced);
+    arrays of a ``PartitionState`` (NumPy), plus the ``GainCache`` that
+    makes FM / replication passes output-sensitive (only nodes whose gain
+    changed are repriced), and ``device_pass``, the jax backend's
+    device-resident pass (``repro.kernels.front_pass``);
   * ``schedule_front`` -- batched node-move pricing and superstep-
     replication front enumeration + pure pricing against flat
     per-superstep load arrays of a ``ScheduleState``.

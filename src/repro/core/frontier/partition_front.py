@@ -8,16 +8,12 @@ per-(candidate, edge) cost terms are summed sequentially in edge order
 (``np.bincount``), the same reduction the engine uses, so a front of one
 node and a front of a thousand produce identical floats.
 
-Two interchangeable lambda backends (selected per call or via
-``set_backend``):
-
-  * ``"numpy"`` (default): ``engine._lambda_from_rows`` -- a single
-    argmax over the popcount-ordered subset columns;
-  * ``"jax"``: ``repro.kernels.gain.min_cover_lambdas`` -- the same
-    reduction as a Pallas TPU kernel (jnp fallback off-TPU), dispatched
-    like ``kernels/ops.py``.  Lambdas are small integers, so both
-    backends feed identical values into the (float64, NumPy) cost
-    reduction -- bit-equality holds across backends too.
+Lambdas come from ``engine._lambda_from_rows`` (a single argmax over the
+popcount-ordered subset columns) or, on tables wider than 64 columns, the
+single-pin-change bound (``_bounded_lambdas``); both give the engine's
+integers.  The jax backend does not price fronts here: it attaches the
+device-resident pass (``device_pass``, ``kernels.front_pass``), which
+keeps the engine state on the device for a whole refinement pass.
 
 ``GainCache`` sits on top: it memoizes each node's candidate deltas and
 invalidates through the pin-adjacency on every applied move, so FM-style
@@ -46,14 +42,14 @@ _BACKEND = "numpy"
 # any per-candidate sum
 _CHUNK_ELEMS = 4_000_000
 
-# the jax backend only pays for itself on big fronts: below this row count
-# device dispatch dominates and the numpy reduction runs instead (the two
-# produce bit-identical lambdas, so this is a pure scheduling choice)
-_JAX_MIN_ROWS = 4096
-
 
 def set_backend(backend: str) -> None:
-    """Select the default lambda backend: ``"numpy"`` or ``"jax"``."""
+    """Select the default frontier backend: ``"numpy"`` or ``"jax"``.
+
+    It applies where a caller passes no backend of its own: the partition
+    heuristics' ``frontier=None`` and the schedule window pricers
+    (``device_windows``).
+    """
     if backend not in ("numpy", "jax"):
         raise ValueError(f"unknown frontier backend {backend!r}")
     global _BACKEND
@@ -68,14 +64,13 @@ def device_pass(state: PartitionState, cap: float, backend: str | None = None,
                 **kw):
     """Device-resident whole-pass runner for the jax backend, or None.
 
-    The PR 3 jax path ships one front to the device per priced node; the
-    PR 6 device-resident path (``kernels.front_pass``) keeps the engine
+    The device-resident path (``kernels.front_pass``) keeps the engine
     state on device for an entire refinement pass with one host sync per
-    committed move.  Dispatch mirrors ``_lambdas``: the explicit
-    ``frontier=`` argument wins, else the module default backend; anything
-    but ``"jax"`` -- or an instance the device pass cannot hold
-    bit-identically (too small, non-integer mu, unassigned nodes, no jax)
-    -- returns None and the caller keeps the numpy front path.
+    committed move.  The explicit ``frontier=`` argument wins, else the
+    module default backend; anything but ``"jax"`` -- or an instance the
+    device pass cannot hold bit-identically (too small, non-integer mu,
+    unassigned nodes, no jax) -- returns None and the caller keeps the
+    numpy front path.
     """
     if backend is None:
         backend = _BACKEND
@@ -94,15 +89,8 @@ def _ragged_gather(starts: np.ndarray, lens: np.ndarray) -> np.ndarray:
     return np.repeat(starts, lens) + (np.arange(total, dtype=np.int64) - off)
 
 
-def _lambdas(rows: np.ndarray, state: PartitionState, backend: str) -> np.ndarray:
-    if backend == "jax" and rows.shape[0] >= _JAX_MIN_ROWS:
-        from ...kernels import gain
-        return gain.min_cover_lambdas(rows, state._order, state._order_pc)
-    return _lambda_from_rows(rows, state._order, state._order_pc)
-
-
 def price_mask_front(state: PartitionState, vs: np.ndarray, cands: np.ndarray,
-                     xcand: np.ndarray, backend: str | None = None) -> np.ndarray:
+                     xcand: np.ndarray) -> np.ndarray:
     """Cost deltas for a ragged candidate front, one vectorized pass.
 
     ``vs[i]`` gets candidates ``cands[xcand[i]:xcand[i+1]]``; the result is
@@ -113,7 +101,6 @@ def price_mask_front(state: PartitionState, vs: np.ndarray, cands: np.ndarray,
     """
     if state.backend != "numpy":
         raise ValueError("price_mask_front needs a numpy-backend PartitionState")
-    backend = backend or _BACKEND
     vs = np.asarray(vs, dtype=np.int64)
     cands = np.asarray(cands, dtype=np.int64)
     xcand = np.asarray(xcand, dtype=np.int64)
@@ -147,14 +134,13 @@ def price_mask_front(state: PartitionState, vs: np.ndarray, cands: np.ndarray,
         # pair's terms in one sequential run)
         while hi < R and pair_ids[hi] == pair_ids[hi - 1]:
             hi += 1
-        if nsub <= 64 or (backend == "jax" and hi - lo >= _JAX_MIN_ROWS):
+        if nsub <= 64:
             # small tables (P <= 6): the one-shot scan beats the grouped
-            # bounded scan; jax: the device kernel takes full uncov rows.
-            # Both produce bit-equal lambdas.
+            # bounded scan; both produce bit-equal lambdas
             rows = (state.uncov[edge_rep[lo:hi]]
                     + state._contrib[cand_rows[lo:hi]]
                     - state._contrib[old_rows[lo:hi]])
-            lam = _lambdas(rows, state, backend)
+            lam = _lambda_from_rows(rows, order, order_pc)
         else:
             lam = _bounded_lambdas(state, edge_rep[lo:hi],
                                    cand_rows[lo:hi], old_rows[lo:hi],
@@ -264,7 +250,7 @@ def fm_move_candidates(state: PartitionState, vs: np.ndarray):
 def move_candidates(state: PartitionState, vs: np.ndarray):
     """FM move front: for each single-assignment node, masks ``1 << q`` for
     every q except the current primary, ascending q (the deterministic
-    tie-break order, see ``heuristic._fm_refine``)."""
+    tie-break order, see ``heuristic.fm_refine``)."""
     P = state.P
     vs = np.asarray(vs, dtype=np.int64)
     prim = np.zeros(len(vs), dtype=np.int64)
@@ -325,11 +311,9 @@ class GainCache:
     batched front, so a full FM pass touches clean nodes for free.
     """
 
-    def __init__(self, state: PartitionState, cands_builder,
-                 backend: str | None = None) -> None:
+    def __init__(self, state: PartitionState, cands_builder) -> None:
         self.state = state
         self.cands_builder = cands_builder
-        self.backend = backend
         n = state.hg.n
         self._dirty = np.ones(n, dtype=bool)
         self._cands: list = [None] * n
@@ -337,8 +321,7 @@ class GainCache:
 
     def _refresh(self, vs: np.ndarray) -> None:
         cands, xcand = self.cands_builder(self.state, vs)
-        deltas = price_mask_front(self.state, vs, cands, xcand,
-                                  backend=self.backend)
+        deltas = price_mask_front(self.state, vs, cands, xcand)
         for i, v in enumerate(vs):
             lo, hi = xcand[i], xcand[i + 1]
             self._cands[v] = cands[lo:hi]

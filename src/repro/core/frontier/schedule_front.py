@@ -210,7 +210,7 @@ def price_comp_moves(sched: ScheduleState, v: int, p: int,
 def node_move_targets(sched: ScheduleState, v: int) -> list[bool]:
     """Feasible targets of the hill climber's node move, as P bools.
 
-    Mirrors ``list_sched.try_node_move``'s guards: q must differ from the
+    Mirrors ``reference.try_node_move``'s guards: q must differ from the
     current processor, every parent must be present on q at v's superstep,
     and v must not be consumed on its current processor in that superstep
     (the replacement comm could not arrive in time).  Plain-python with
@@ -255,7 +255,7 @@ def sr_front(sched: ScheduleState, s: int) -> list[tuple[int, int, list[int]]]:
     send from there, minus processors it is already assigned to); the
     candidate list then reads off as the non-zero (p1, p2) combinations,
     in the deterministic lexicographic order both search paths share.
-    ``nodes`` reproduces ``try_superstep_replication``'s eligibility
+    ``nodes`` reproduces ``reference.try_superstep_replication``'s eligibility
     filter exactly (sorted members of ``comp[s][p1]`` with a use on p2).
     """
     P = sched.inst.P

@@ -436,7 +436,7 @@ class PartitionState:
         from ..frontier.partition_front import price_mask_front
         return price_mask_front(
             self, np.array([v], dtype=np.int64), new_masks,
-            np.array([0, len(new_masks)], dtype=np.int64), backend="numpy")
+            np.array([0, len(new_masks)], dtype=np.int64))
 
     def delta_move(self, v: int, p_from: int, p_to: int) -> float:
         m = int(self.masks[v])

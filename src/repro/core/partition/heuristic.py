@@ -19,9 +19,8 @@ priced in batched vectorized fronts and invalidated through the
 pin-adjacency, so refinement passes are *output-sensitive* -- only nodes
 whose gain actually changed are repriced, and they are repriced together
 instead of one engine call per node.  Decisions are identical to the
-per-node rescan (kept as ``frontier="off"`` for benchmarking); the seed
-full-recompute implementation survives in ``reference.py`` as the
-equivalence/benchmark oracle.
+seed full-recompute implementation, which survives in ``reference.py`` as
+the test oracle.
 
 Tie-breaking rule (shared by every move selection below, and pinned by
 ``tests/test_frontier.py``): candidate masks are generated in **ascending
@@ -112,11 +111,11 @@ def fm_refine(hg: Hypergraph, masks: np.ndarray, P: int, eps: float,
     masks or a live ``PartitionState`` (the multilevel V-cycle hands it
     the state built from projected masks at every level).
 
-    Default path: a frontier ``GainCache`` prices the whole node front in
-    one batched call per pass and thereafter only nodes adjacent to an
-    applied move (output-sensitive FM).  ``frontier="off"`` keeps the
-    per-node rescan; both take identical decisions (ties to the lowest
-    processor id, see the module docstring).
+    A frontier ``GainCache`` prices the whole node front in one batched
+    call per pass and thereafter only nodes adjacent to an applied move
+    (output-sensitive FM); ties go to the lowest processor id (see the
+    module docstring).  ``frontier="jax"`` runs whole passes on the
+    device-resident pass instead wherever it can hold the level.
 
     ``nodes`` (optional sorted id array) restricts the sweep to those
     movers -- the process-parallel layer's shard/boundary passes.  With
@@ -127,7 +126,7 @@ def fm_refine(hg: Hypergraph, masks: np.ndarray, P: int, eps: float,
     st = state if state is not None else PartitionState(hg, P, masks=masks)
     if nodes is not None:
         nodes = np.asarray(nodes, dtype=np.int64)
-    if frontier != "off" and nodes is None:
+    if nodes is None:
         # jax backend, large instance: run whole passes device-resident
         # (one host sync per committed move; decisions bit-identical --
         # see kernels.front_pass).  Falls through to the numpy front path
@@ -141,30 +140,9 @@ def fm_refine(hg: Hypergraph, masks: np.ndarray, P: int, eps: float,
                 dev.detach()
             masks[:] = st.masks
             return masks
-    if frontier == "off":
-        for _ in range(passes):
-            improved = False
-            for v in (rng.permutation(hg.n) if nodes is None
-                      else nodes[rng.permutation(len(nodes))]):
-                v = int(v)
-                p = int(st.masks[v]).bit_length() - 1
-                targets = [q for q in range(P)
-                           if q != p and st.fits(v, q, cap)]
-                if not targets:
-                    continue
-                deltas = st.delta_masks(v, np.array([1 << q for q in targets]))
-                best = int(np.argmin(deltas))
-                if deltas[best] < -1e-12:
-                    st.apply(v, 1 << targets[best])
-                    st.commit()
-                    improved = True
-            if not improved:
-                break
-        masks[:] = st.masks
-        return masks
     from ..frontier import (GainCache, fm_move_candidates,
                             lookahead_window, refresh_boundary_window)
-    cache = GainCache(st, fm_move_candidates, backend=frontier)
+    cache = GainCache(st, fm_move_candidates)
     W = lookahead_window(st)
     # on high-degree instances (dense coarse multilevel levels) a window
     # refresh prices mostly nodes that get re-dirtied before their visit;
@@ -174,7 +152,7 @@ def fm_refine(hg: Hypergraph, masks: np.ndarray, P: int, eps: float,
     use_windows = len(st.pins) <= 128 * max(hg.n, 1)
     xinc, inc_edges = st.xinc, st.inc_edges
     elam = st.edge_lambda  # updated in place by apply/undo
-    # boundary filter (exact at visit time, mirrors the per-node rescan):
+    # boundary filter (exact at visit time, mirrors the oracle's rescan):
     # if every incident edge has lambda <= 1, each one is covered by a
     # single processor every pin of it shares -- re-masking v can only
     # raise its lambda, so no candidate is strictly improving and the node
@@ -228,10 +206,8 @@ def partition_heuristic(hg: Hypergraph, P: int, eps: float,
                         frontier: str | None = None) -> HeuristicResult:
     """Non-replicating baseline: greedy initial + FM refinement, best of restarts.
 
-    ``frontier`` selects the gain-pricing path: ``None`` (the frontier
-    layer's default backend), ``"numpy"`` / ``"jax"`` explicitly, or
-    ``"off"`` for the pre-frontier per-node rescan -- all decision-
-    identical.
+    ``frontier`` selects the backend: ``None`` (the frontier layer's
+    default), ``"numpy"`` or ``"jax"`` -- decision-identical.
     """
     if P > _MAX_P:  # beyond the engine's 2^P tables: scalar reference path
         from .reference import partition_heuristic_reference
@@ -270,10 +246,10 @@ def replicate_local_search(
     ``PartitionState`` instead of rebuilding one from ``masks`` (the
     multilevel V-cycle supplies the state built from projected masks; the
     search then refines it in place).  Add-replica candidates are priced
-    through the frontier ``GainCache`` (batched, output-sensitive;
-    ``frontier="off"`` keeps the per-node engine rescan -- identical
-    decisions, ties to the lowest processor id); drops and the multi-pin
-    edge-guided move stay on the engine's scalar delta / apply+undo path.
+    through the frontier ``GainCache`` (batched, output-sensitive; ties to
+    the lowest processor id), or by the device-resident pass under
+    ``frontier="jax"``; drops and the multi-pin edge-guided move stay on
+    the engine's scalar delta / apply+undo path.
 
     ``nodes`` (optional sorted id array) restricts every mover -- the node
     sweep visits only those nodes and the edge-guided move may only
@@ -295,24 +271,23 @@ def replicate_local_search(
     xpins, pins = hg.xpins, hg.pins
     cache = None
     dev = None
-    W = 64
     use_windows = len(st.pins) <= 128 * max(hg.n, 1)  # cf. fm_refine
     allowed = None
     if nodes is not None:
         nodes = np.asarray(nodes, dtype=np.int64)
         allowed = np.zeros(hg.n, dtype=bool)
         allowed[nodes] = True
-    if frontier != "off" and nodes is None:
+    if nodes is None:
         # device-resident node sweep (cf. fm_refine): the edge-guided phase
         # stays on the host engine, whose apply/undo hook keeps the device
         # mirror synced; the add/drop sweep runs on device with one host
         # sync per committed move
         from ..frontier.partition_front import device_pass
         dev = device_pass(st, cap, backend=frontier)
-    if frontier != "off" and dev is None:
+    if dev is None:
         from ..frontier import (GainCache, connected_add_candidates,
                                 lookahead_window, refresh_boundary_window)
-        cache = GainCache(st, connected_add_candidates, backend=frontier)
+        cache = GainCache(st, connected_add_candidates)
         W = lookahead_window(st)
     # memoized boundary status, invalidated through the pin-adjacency on
     # every applied mutation (cf. fm_refine: exact at visit time)
@@ -381,22 +356,12 @@ def replicate_local_search(
                 bnd_fresh[v] = True
             # --- try adding a replica ---
             if bnd[v] and (max_replicas is None or k < max_replicas):
-                if cache is not None:
-                    if use_windows and cache.is_dirty(v):
-                        refresh_boundary_window(cache, perm, i, W)
-                    cands, deltas = cache.get(v)
-                    sel = [j for j in range(len(cands))
-                           if st.fits(v, (int(cands[j]) ^ m).bit_length() - 1,
-                                      cap)]
-                else:
-                    adds = [p for p in range(P)
-                            if not (m >> p) & 1 and st.fits(v, p, cap)]
-                    sel = []
-                    if adds:
-                        cands = np.array([m | (1 << p) for p in adds],
-                                         dtype=np.int64)
-                        deltas = st.delta_masks(v, cands)
-                        sel = list(range(len(adds)))
+                if use_windows and cache.is_dirty(v):
+                    refresh_boundary_window(cache, perm, i, W)
+                cands, deltas = cache.get(v)
+                sel = [j for j in range(len(cands))
+                       if st.fits(v, (int(cands[j]) ^ m).bit_length() - 1,
+                                  cap)]
                 if sel:
                     sub = deltas[sel]
                     best = int(np.argmin(sub))  # ties: lowest processor id
@@ -471,8 +436,13 @@ def partition_with_replication(
     reconciliation accepts improving moves only -- but the refinement
     trajectory may diverge from serial (disclosed in the benches).
     ``stats`` (multilevel only) receives one row per refinement stop.
+    ``frontier`` is ``None``, ``"numpy"`` or ``"jax"``; anything else
+    raises ``ValueError``.
     """
     from .exact import exact_partition
+
+    if frontier not in (None, "numpy", "jax"):
+        raise ValueError(f"unknown frontier backend {frontier!r}")
 
     if hg.n <= exact_node_limit and P <= _MAX_P:
         base = exact_partition(hg, P, eps, mode="none", time_limit=time_limit)
@@ -512,7 +482,3 @@ def partition_with_replication(
             break
     return base, best
 
-
-# Pre-PR 4 private names of the stage entry points, kept as aliases.
-_greedy_initial = greedy_initial
-_fm_refine = fm_refine

@@ -3,13 +3,13 @@
 This is the full-recompute local search the repo shipped with before the
 incremental-gain engine: every candidate move re-runs exact set cover over
 all incident hyperedges.  It is O(deg^2)-ish per evaluation and only viable
-on toy instances, but its simplicity makes it the ground truth for
+on toy instances, but its simplicity makes it the ground truth for the
+equivalence tests: on fixed seeds the engine-backed heuristic must cost
+no more (``tests/test_partition_engine.py``) and take the same decisions
+(``tests/test_frontier.py``).
 
-  * equivalence tests (the engine-backed heuristic must return valid,
-    balanced masks with equal-or-better cost on fixed seeds), and
-  * the old-vs-new throughput benchmark in ``benchmarks/partitioning.py``.
-
-Do not use it in production paths; ``heuristic.py`` is the fast one.
+Production paths use it only beyond the engine's 2^P tables (P > 12);
+``heuristic.py`` is the fast one.
 """
 from __future__ import annotations
 

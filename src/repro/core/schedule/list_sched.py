@@ -17,9 +17,9 @@ All moves are priced through the incremental-delta engine: comm
 re-placement uses the pure ``delta_move_comm``, node moves price their
 whole target front at once through the frontier layer
 (``core.frontier.price_node_moves`` -- bit-equal to per-target
-``delta_node_move``, so the first-feasible-improving-q decision is
-unchanged; ``use_fronts=False`` keeps the per-target loop), and the
-no-replication merge runs inside a ``begin()``/``rollback()`` transaction.
+``delta_node_move``, so the first-feasible-improving-q decision is the
+oracle's), and the no-replication merge runs inside a
+``begin()``/``rollback()`` transaction.
 Tie-breaking is deterministic (sorted iteration, ``(superstep, processor)``
 keys), matching ``reference.py`` decision-for-decision.
 """
@@ -130,7 +130,6 @@ def _long_comm_windows(sched: Schedule, keys, start: int):
 
 
 def rebalance_comms(sched: Schedule, max_passes: int = 4,
-                    use_fronts: bool = True,
                     backend: str | None = None) -> bool:
     """Move each comm within its window to the cheapest superstep.
 
@@ -160,7 +159,7 @@ def rebalance_comms(sched: Schedule, max_passes: int = 4,
             lo, hi = _comm_window(sched, v, dst)
             if hi < lo:
                 continue
-            if use_fronts and hi - lo + 1 >= _COMM_FRONT_MIN_WINDOW:
+            if hi - lo + 1 >= _COMM_FRONT_MIN_WINDOW:
                 deltas = (win.comm_window(
                               v, dst, lo, hi - lo + 1,
                               _long_comm_windows(sched, keys, i + 1))
@@ -196,7 +195,6 @@ def _comp_window(sched: Schedule, v: int, p: int) -> tuple[int, int]:
 
 
 def comp_rebalance_pass(sched: Schedule, max_passes: int = 4,
-                        use_fronts: bool = True,
                         backend: str | None = None) -> bool:
     """Re-time each single-assigned node within its feasible superstep
     window on its own processor (work-max balancing across supersteps).
@@ -236,7 +234,7 @@ def comp_rebalance_pass(sched: Schedule, max_passes: int = 4,
             if hi <= lo and s == lo:
                 continue
             om = dag.omega[v]
-            if use_fronts and hi - lo + 1 >= _COMM_FRONT_MIN_WINDOW:
+            if hi - lo + 1 >= _COMM_FRONT_MIN_WINDOW:
                 ts = np.arange(lo, hi + 1)
                 deltas = (win.price_comp_moves(v, p, ts) if win is not None
                           else price_comp_moves(sched, v, p, ts))
@@ -257,52 +255,20 @@ def comp_rebalance_pass(sched: Schedule, max_passes: int = 4,
     return improved_any
 
 
-def try_node_move(sched: Schedule, v: int, q: int) -> bool:
-    """Move node v (single assignment) to processor q, same superstep."""
-    assert len(sched.assign[v]) == 1
-    (p, s), = sched.assign[v].items()
-    if q == p:
-        return False
-    dag = sched.inst.dag
-    # parents must be present on q at s
-    for u in dag.parents[v]:
-        if not sched.present_at(u, q, s):
-            return False
-    # v must not be used on p in superstep s itself (comm can't arrive in time)
-    uses_p = sched.uses_on(v, p)
-    if uses_p and min(uses_p) <= s:
-        return False
-    if sched.delta_node_move(v, q) < -EPS:
-        sched.apply_node_move(v, q)
-        return True
-    return False
-
-
 def node_move_pass(sched: Schedule, seed: int = 0,
-                   use_fronts: bool = True,
                    backend: str | None = None) -> bool:
     """One pass of node moves: first feasible improving target wins.
 
-    Default path prices every target processor in one frontier front
-    (``price_node_moves``); ``use_fronts=False`` keeps the pre-frontier
-    per-target ``try_node_move`` loop.  ``backend="jax"`` folds the move's
+    Every target processor is priced in one frontier front
+    (``price_node_moves``).  ``backend="jax"`` folds the move's
     per-superstep (P x P) delta matrices on device when many supersteps
-    are touched (``frontier.device_windows``).  All paths take identical
+    are touched (``frontier.device_windows``).  Both paths take identical
     decisions.
     """
+    from ..frontier import device_windows, node_move_targets, price_node_moves
     rng = np.random.default_rng(seed)
     improved = False
     P = sched.inst.P
-    if not use_fronts:
-        for v in rng.permutation(sched.inst.dag.n):
-            if len(sched.assign[v]) != 1:
-                continue
-            for q in range(P):
-                if try_node_move(sched, int(v), q):
-                    improved = True
-                    break
-        return improved
-    from ..frontier import device_windows, node_move_targets, price_node_moves
     win = device_windows(sched, backend)
     for v in rng.permutation(sched.inst.dag.n):
         v = int(v)
@@ -383,13 +349,11 @@ def merge_pass(sched: Schedule) -> bool:
 
 
 def hill_climb(sched: Schedule, rounds: int = 6, seed: int = 0,
-               use_fronts: bool = True,
                backend: str | None = None) -> Schedule:
     for r in range(rounds):
         improved = False
         improved |= rebalance_comms(sched, backend=backend)
-        improved |= node_move_pass(sched, seed=seed + r,
-                                   use_fronts=use_fronts, backend=backend)
+        improved |= node_move_pass(sched, seed=seed + r, backend=backend)
         improved |= merge_pass(sched)
         if not improved:
             break

@@ -44,15 +44,10 @@ Pipeline (one V-cycle)::
 
 Cost safety: refinement only ever applies strictly improving moves, and at
 or below ``coarsest_n`` the driver *is* the flat heuristic (exact-equality
-fallthrough).  The ``flat_guard_n`` hedge -- run the flat path too and keep
-the cheaper schedule -- is retired by default (``flat_guard_n = 0``, PR 9):
-with the superstep-split front in per-level refinement the pure V-cycle
-matches or beats flat on every benched instance (split widens the basin
-the projection lands in; the psdd circuits that used to need the hedge no
-longer do), pinned by ``tests/test_schedule_multilevel.py`` and measured
-by ``benchmarks/scheduling.py::split_scale``.  Setting ``flat_guard_n``
-back to a positive n restores the old cost-not-worse-than-flat hedge at
-the old price of one full flat run.
+fallthrough).  Above it the V-cycle does not run the flat path as a hedge:
+with the superstep-split front in per-level refinement it matches or beats
+flat on the psdd circuits that once needed one (split widens the basin the
+projection lands in), pinned by ``tests/test_schedule_multilevel.py``.
 
 Scale: coarsening's same-level scoring pass shards over node ranges
 through PR 7's ``ParallelContext`` (``workers=`` on
@@ -60,7 +55,7 @@ through PR 7's ``ParallelContext`` (``workers=`` on
 arrays byte-for-byte, so the matching -- and the whole V-cycle -- is
 bit-identical for every worker count.  With the vectorized
 ``Schedule.from_projection`` rebuilds this takes the cycle to n = 10^6
-DAGs end to end (``benchmarks/scheduling.py::split_scale``).
+DAGs end to end.
 """
 from __future__ import annotations
 
@@ -90,12 +85,8 @@ class MultilevelScheduleOptions:
     hc_rounds: int = 3         # rebalance+retime+node-move rounds per stop
     level_rounds: int = 1      # advanced-heuristic rounds per mid level
     final_rounds: int = 4      # advanced-heuristic rounds at the finest
-    flat_guard_n: int = 0      # up to here ALSO run the flat path, keep the
-    #                            cheaper schedule.  0 (default since the
-    #                            split front landed, PR 9) disables the
-    #                            hedge -- the pure V-cycle stands on its own
     superstep_splits: bool = True  # superstep-split front in per-level
-    #                            refinement (the move that retired the guard)
+    #                            refinement
 
 
 # --------------------------------------------------------------- coarsening
@@ -347,7 +338,7 @@ def _refine_level(sched: Schedule, finest: bool,
             break
     rounds = opts.final_rounds if finest else opts.level_rounds
     if rounds > 0:
-        # caller's AdvancedOptions (pass selection, use_fronts) carry
+        # caller's AdvancedOptions (pass selection) carry
         # through to refinement; the round budget and split toggle are
         # per-level knobs of the V-cycle
         with span("schedule.advanced", rounds=rounds):
@@ -376,16 +367,12 @@ def multilevel_schedule(inst: BspInstance,
 
     At or below ``coarsest_n`` (or on immediate coarsening stagnation)
     the driver *is* the flat path -- exact-equality fallthrough, pinned
-    by tests.  When ``flat_guard_n`` is set positive, up to that size the
-    flat path also runs as a hedge and the cheaper schedule wins (see
-    module docstring -- the hedge is off by default since PR 9).
-    ``workers > 1`` shards coarsening's matching-score pass over a
-    shared-memory process pool (bit-identical result; serial, with a
-    ``SerialFallbackWarning``, where shm is unavailable).  ``stats``
+    by tests.  ``workers > 1`` shards coarsening's matching-score pass
+    over a shared-memory process pool (bit-identical result; serial, with
+    a ``SerialFallbackWarning``, where shm is unavailable).  ``stats``
     (optional list) receives one row per refinement stop with
-    projected/refined costs, which is how
-    the refinement-never-increases property is tested, plus a
-    ``flat_guard`` row when the hedge ran.
+    projected/refined costs, which is how the refinement-never-increases
+    property is tested.
     """
     opts = opts or MultilevelScheduleOptions()
     dag = inst.dag
@@ -448,19 +435,4 @@ def multilevel_schedule(inst: BspInstance,
             stats.append({"level": li, "n": levels[li].n, "S": sched.S,
                           "cost_projected": projected,
                           "cost_refined": float(sched.current_cost())})
-    if 0 < dag.n <= opts.flat_guard_n:
-        # hedge while the flat path is tractable: the V-cycle's reach claim
-        # lives beyond this size; below it, basin differences occasionally
-        # favor the flat search (e.g. replication-hungry psdd circuits), so
-        # run it too and keep the cheaper schedule.  Guarantees
-        # cost-not-worse wherever both paths run, at the disclosed price of
-        # one flat run.
-        flat = best_replicated_schedule(inst, baseline=baseline,
-                                        opts=adv_opts, seed=seed)
-        if stats is not None:
-            stats.append({"flat_guard": True, "n": dag.n,
-                          "flat_cost": float(flat.current_cost()),
-                          "vcycle_cost": float(sched.current_cost())})
-        if flat.current_cost() < sched.current_cost():
-            return flat
     return sched

@@ -5,9 +5,10 @@ matrices and re-derives superstep costs through a dirty-set sweep, and every
 compound trial move (superstep merging, superstep replication) prices itself
 by ``copy()`` + mutate + compare + discard.  The engine-backed stack in
 ``bsp.py`` / ``replication.py`` / ``list_sched.py`` must produce *identical
-final costs* on the same instances -- ``tests/test_schedule_engine.py`` and
-``benchmarks/scheduling.py`` hold the two paths together, and the only
-intended difference is wall-clock.
+final costs* on the same instances -- the tests (``test_schedule_engine.py``,
+``test_frontier.py``, ``test_schedule_split.py``) hold the two paths
+together, and the only intended difference is wall-clock.  It is a test
+oracle only: no production path calls it.
 
 To make that equivalence exact, the deliberate deviations from the seed
 are deterministic tie-breaking (sorted iteration over comms/compute sets,

@@ -16,13 +16,14 @@ pruned, empty supersteps compacted), mirroring the paper's §C.2.1 remark.
 
 The pricing mechanics run on the incremental-delta engine (``engine.py``):
 the basic move is priced by a pure ``delta_replicate_for_comm`` (no
-mutation at all), and the compound BR/SM trials mutate inside a
+mutation at all), and the compound BR trial mutates inside a
 ``begin()``/``commit()``/``rollback()`` transaction instead of working on a
-throwaway ``Schedule.copy()``.  The SR pass goes further through the
-frontier layer (``core.frontier.schedule_front``): each superstep's whole
-``(s, p1, p2)`` candidate front is enumerated from one flat pass over the
-compute phase and priced *purely* (failed candidates never touch the undo
-log); only the winning candidate commits through a transaction.  Decisions
+throwaway ``Schedule.copy()``.  The SM, split and SR passes go further
+through the frontier layer (``core.frontier.schedule_front``): each pass's
+whole candidate front (for SR, every ``(s, p1, p2)`` of a superstep from
+one flat pass over its compute phase) is priced *purely* (failed
+candidates never touch the undo log); only the winning candidate commits
+through a transaction.  Decisions
 are tie-broken deterministically (sorted comm/compute iteration,
 ``(superstep, processor)`` source keys, lexicographic SR winner) so the
 search trajectory is identical to the preserved full-recompute oracle in
@@ -228,62 +229,20 @@ def batch_replication_pass(sched: Schedule) -> bool:
 
 # --------------------------------------------------------- superstep merging
 
-def try_merge_with_replication(sched: Schedule, s: int) -> bool:
-    """Attempt to merge superstep s+1 into s (SM), in place under a
-    transaction.  Commits (and compacts) on improvement, rolls back
-    otherwise; returns whether the merge was kept.
-
-    First-improvement comparator path (``use_fronts=False``), post-prune
-    accept; the mutation sequence itself lives in
-    ``frontier.apply_sm_mutations``, shared with the winner-rule path and
-    the oracle.
-    """
-    from ..frontier import apply_sm_mutations
-
-    if s + 1 >= sched.S:
-        return False
-    before = sched.current_cost()
-    sched.begin()
-    if not apply_sm_mutations(sched, s):
-        sched.rollback()
-        return False
-    sched.prune_useless_comms()
-    if sched.current_cost() < before - EPS:
-        sched.commit()
-        sched.compact()
-        return True
-    sched.rollback()
-    return False
-
-
-def superstep_merge_pass(sched: Schedule,
-                         use_fronts: bool = True) -> tuple[Schedule, bool]:
+def superstep_merge_pass(sched: Schedule) -> tuple[Schedule, bool]:
     """SM sweep over adjacent superstep pairs.
 
-    Default path: price every candidate merge *purely*
+    Price every candidate merge *purely*
     (``frontier.price_superstep_merge`` -- failed or losing candidates
     never touch the undo log) and commit **the winner** -- minimal
     pre-prune delta, ties to the smallest s -- through the transaction
     machinery, repeating until no candidate improves.  The oracle
     (``reference.superstep_merge_pass``) applies the same winner rule, so
     trajectories stay identical (bit-identical on integer weights).
-
-    ``use_fronts=False`` keeps the pre-frontier first-improvement
-    transactional sweep with its post-prune accept test (benchmark
-    comparator; may visit a different local optimum).
     """
-    improved = False
-    if not use_fronts:
-        s = 0
-        while s < sched.S - 1:
-            if try_merge_with_replication(sched, s):
-                improved = True
-                # stay at the same index: maybe merge further
-            else:
-                s += 1
-        return sched, improved
     from ..frontier import (commit_superstep_merge, price_superstep_merge,
                             sm_front)
+    improved = False
     while sched.S > 1:
         # one comm sort per round, bucketed by superstep, shared by every
         # candidate pricing (identical iteration to the inline sort)
@@ -321,7 +280,7 @@ def superstep_split_pass(sched: Schedule) -> tuple[Schedule, bool]:
     h-relation into one overloaded comm phase, the split re-derives the
     affected comms canonically across the two resulting phases, trading
     ``L`` against ``g * h`` -- the priced fixed point of merge + split is
-    what retires the multilevel flat-path guard.
+    what lets the multilevel V-cycle stand without a flat-path hedge.
     """
     from ..frontier import (commit_superstep_split, price_superstep_split,
                             split_front)
@@ -347,70 +306,22 @@ def superstep_split_pass(sched: Schedule) -> tuple[Schedule, bool]:
 
 # ------------------------------------------------------ superstep replication
 
-def try_superstep_replication(sched: Schedule, s: int, p1: int, p2: int) -> bool:
-    """SR: replicate (the useful part of) V_{p1,s} onto p2, in place under
-    a transaction.  Returns whether the replication was kept.
-
-    First-improvement comparator path (``use_fronts=False``); the mutation
-    sequence itself lives in ``frontier.apply_sr_mutations``, shared with
-    the winner-rule path and the oracle.
-    """
-    from ..frontier import apply_sr_mutations
-
-    nodes = [v for v in sorted(sched.comp[s][p1])
-             if p2 not in sched.assign[v] and sched.has_use_on(v, p2)]
-    if not nodes:
-        return False
-    before = sched.current_cost()
-    sched.begin()
-    if not apply_sr_mutations(sched, s, p1, p2, nodes):
-        sched.rollback()
-        return False
-    sched.prune_useless_comms()
-    if sched.current_cost() < before - EPS:
-        sched.commit()
-        return True
-    sched.rollback()
-    return False
-
-
-def superstep_replication_pass(sched: Schedule,
-                               use_fronts: bool = True) -> tuple[Schedule, bool]:
+def superstep_replication_pass(sched: Schedule) -> tuple[Schedule, bool]:
     """SR sweep over supersteps.
 
-    Default path: per superstep, enumerate the whole ``(p1, p2)`` candidate
-    front from one flat pass (``frontier.sr_front``), price every candidate
-    purely (no transaction, no rollback; pruning after commit only helps),
-    and commit **the winner** -- minimal priced delta, ties to the
+    Per superstep, enumerate the whole ``(p1, p2)`` candidate front from
+    one flat pass (``frontier.sr_front``), price every candidate purely
+    (no transaction, no rollback; pruning after commit only helps), and
+    commit **the winner** -- minimal priced delta, ties to the
     lexicographically smallest ``(p1, p2)`` -- through the transaction
     machinery, repeating the superstep until no candidate improves.  The
     oracle (``reference.superstep_replication_pass``) applies the same
     winner rule, so trajectories stay identical.
-
-    ``use_fronts=False`` keeps the pre-frontier first-improvement
-    transactional sweep (benchmark comparator; may visit a different local
-    optimum than the winner rule).
     """
-    improved = False
-    P = sched.inst.P
-    s = 0
-    if not use_fronts:
-        while s < sched.S:
-            done = False
-            for p1 in range(P):
-                for p2 in range(P):
-                    if p1 == p2:
-                        continue
-                    if try_superstep_replication(sched, s, p1, p2):
-                        improved = done = True
-                        break
-                if done:
-                    break
-            if not done:
-                s += 1
-        return sched, improved
     from ..frontier import (commit_superstep_replication,
                             price_superstep_replication, sr_front)
+    improved = False
+    s = 0
     while s < sched.S:
         best = None
         for (p1, p2, nodes) in sr_front(sched, s):
@@ -470,11 +381,8 @@ class AdvancedOptions:
     superstep_merging: bool = True
     superstep_replication: bool = True
     max_rounds: int = 8
-    # False = pre-frontier first-improvement SR sweep (benchmark comparator)
-    use_fronts: bool = True
     # winner-commit superstep splits right after the SM block (multilevel
-    # refinement enables this so merge/split reach a priced fixed point);
-    # appended last to keep positional construction stable
+    # refinement enables this so merge/split reach a priced fixed point)
     superstep_splitting: bool = False
 
 
@@ -487,8 +395,7 @@ def advanced_heuristic(sched: Schedule, opts: AdvancedOptions | None = None) -> 
         # would otherwise exploit (ablations show SM is the bigger lever,
         # cf. paper Table 14)
         if opts.superstep_merging:
-            sched, imp = superstep_merge_pass(sched,
-                                              use_fronts=opts.use_fronts)
+            sched, imp = superstep_merge_pass(sched)
             improved |= imp
         # splits directly after merges: the two alternate to a priced
         # fixed point (every commit strictly improves, so this terminates)
@@ -498,8 +405,7 @@ def advanced_heuristic(sched: Schedule, opts: AdvancedOptions | None = None) -> 
         if opts.batch_replication:
             improved |= batch_replication_pass(sched)
         if opts.superstep_replication:
-            sched, imp = superstep_replication_pass(
-                sched, use_fronts=opts.use_fronts)
+            sched, imp = superstep_replication_pass(sched)
             improved |= imp
         # interleave the basic move as cleanup (cheap local improvements)
         before = sched.current_cost()

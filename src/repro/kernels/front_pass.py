@@ -1,8 +1,8 @@
 """Device-resident refinement passes: whole FM / replication sweeps on JAX.
 
-PR 3 gave the frontier layer a jax backend, but it ships one front to the
-device at a time: every priced node pays a host->device round trip, so on
-CPU the jax path merely ties numpy.  This module keeps the engine's state
+The numpy frontier path prices one candidate front at a time on the host.
+Shipping each front to the device would make every priced node pay a
+host->device round trip.  This module instead keeps the engine's state
 resident on the device across an entire refinement pass and fuses the whole
 per-visit pipeline -- row gather, popcount-ordered masked-min lambda
 pricing, integer cost reduction, winner argmin -- into one jitted program
@@ -12,7 +12,7 @@ committed move (plus one terminal read per pass scan), applies the move to
 both the host engine and the device mirror, and re-enters the scan at the
 next position.
 
-Correctness contract (same as PR 3, property-tested in interpret mode):
+Correctness contract (property-tested in interpret mode):
 
   * **Bit-identical decisions.**  The device program is all-integer: when
     ``mu`` is integer-valued (every shipped instance), cost deltas are

@@ -19,7 +19,11 @@ from repro.core.frontier import (GainCache, add_replica_candidates,
                                  price_mask_front, price_node_moves,
                                  price_superstep_replication, sr_front)
 from repro.core.hypergraph import Dag, Hypergraph
-from repro.core.partition import PartitionState, partition_heuristic
+from repro.core.partition import (PartitionState, partition_heuristic,
+                                  partition_with_replication,
+                                  replicate_local_search)
+from repro.core.partition.reference import (partition_heuristic_reference,
+                                            replicate_local_search_reference)
 from repro.core.schedule import BspInstance, bspg_schedule
 from repro.core.schedule.engine import EPS
 
@@ -45,22 +49,13 @@ def random_dag(n, seed, fanin=3, p_edge=0.5, n_src=8, weighted=False):
     return Dag(n=n, edge_list=edges, omega=omega, mu=mu)
 
 
-def _backends():
-    yield "numpy"
-    try:
-        import jax  # noqa: F401
-        yield "jax"
-    except ImportError:
-        pass
-
-
 # ---------------------------------------------------------- partition front
 
 @given(st.integers(0, 10_000))
 @settings(max_examples=15, deadline=None)
 def test_property_front_equals_per_node_delta_masks(seed):
     """A ragged multi-node front must reproduce per-node ``delta_masks``
-    bit-for-bit, on every available backend."""
+    bit-for-bit."""
     rng = np.random.default_rng(seed)
     hg = random_hypergraph(rng)
     P = int(rng.integers(2, 5))
@@ -73,9 +68,8 @@ def test_property_front_equals_per_node_delta_masks(seed):
         want = np.concatenate(
             [state.delta_masks(int(v), cands[xcand[i]:xcand[i + 1]])
              for i, v in enumerate(vs)]) if len(cands) else np.zeros(0)
-        for backend in _backends():
-            got = price_mask_front(state, vs, cands, xcand, backend=backend)
-            assert np.array_equal(got, want), (builder.__name__, backend)
+        got = price_mask_front(state, vs, cands, xcand)
+        assert np.array_equal(got, want), builder.__name__
 
 
 @given(st.integers(0, 10_000))
@@ -143,43 +137,86 @@ def test_tie_break_lowest_processor():
     assert a.cost == b.cost and np.array_equal(a.masks, b.masks)
 
 
-@pytest.mark.parametrize("frontier", ["off", "numpy"])
-def test_fm_paths_identical(frontier):
-    """The output-sensitive cached path and the per-node rescan must take
-    identical decisions (same masks, not just same cost)."""
+@pytest.mark.parametrize("P", [4, 6, 8])
+def test_fm_paths_identical(P):
+    """The output-sensitive cached path must take the full-recompute
+    oracle's decisions (same masks, not just same cost).  P = 8 prices
+    through the single-pin-change bound, P <= 6 through the one-shot
+    scan."""
     rng = np.random.default_rng(5)
     hg = random_hypergraph(rng, n=80, m=120)
-    got = partition_heuristic(hg, 4, 0.1, seed=1, frontier=frontier)
-    want = partition_heuristic(hg, 4, 0.1, seed=1, frontier="off")
-    assert got.cost == want.cost
-    assert np.array_equal(got.masks, want.masks)
+    got = partition_heuristic(hg, P, 0.1, seed=1, frontier="numpy")
+    want_masks, want_cost = partition_heuristic_reference(hg, P, 0.1, seed=1)
+    assert np.array_equal(got.masks, want_masks)
+    assert abs(got.cost - want_cost) < 1e-9
 
 
-@given(st.integers(0, 10_000))
-@settings(max_examples=8, deadline=None)
-def test_property_gain_kernel_matches_numpy_lambda(seed):
-    """kernels.gain lambdas == engine._lambda_from_rows, jnp path and
-    Pallas kernel in interpret mode (small fronts bypass the jax backend
-    inside price_mask_front, so the kernel is pinned directly here)."""
-    jax = pytest.importorskip("jax")  # noqa: F841
+@pytest.mark.parametrize("max_replicas", [2, None])
+def test_replicate_paths_identical(max_replicas):
+    """The replication search's cached add front must take the
+    full-recompute oracle's decisions, replicas included."""
+    rng = np.random.default_rng(5)
+    hg = random_hypergraph(rng, n=80, m=120)
+    base = partition_heuristic(hg, 4, 0.1, seed=1)
+    got = replicate_local_search(hg, base.masks.copy(), 4, 0.1,
+                                 max_replicas=max_replicas, seed=2,
+                                 frontier="numpy")
+    want_masks, want_cost = replicate_local_search_reference(
+        hg, base.masks.copy(), 4, 0.1, max_replicas=max_replicas, seed=2)
+    assert np.array_equal(got.masks, want_masks)
+    assert abs(got.cost - want_cost) < 1e-9
+    assert (got.masks & (got.masks - 1)).any()  # some node is replicated
+
+
+def test_unknown_frontier_raises():
+    rng = np.random.default_rng(5)
+    hg = random_hypergraph(rng, n=40, m=60)
+    with pytest.raises(ValueError, match="frontier"):
+        partition_with_replication(hg, 4, 0.1, exact_node_limit=0,
+                                   frontier="off")
+
+
+@pytest.mark.parametrize("P", [3, 5, 8])
+def test_front_dlam_matches_oracle(P):
+    """The device pass's Pallas kernel (interpret mode) on real candidate
+    rows of a ``PartitionState`` -- the move front of every node, pins
+    left unassigned included -- gives the cost terms of the engine's
+    ``_lambda_from_rows``."""
+    jnp = pytest.importorskip("jax.numpy")
     from repro.core.partition.engine import _lambda_from_rows
-    from repro.kernels import gain, ops
-    rng = np.random.default_rng(seed)
-    hg = random_hypergraph(rng)
-    P = int(rng.integers(2, 6))
-    masks = rng.integers(0, 1 << P, size=hg.n)  # incl. unassigned pins
+    from repro.kernels import gain
+    rng = np.random.default_rng(P)
+    hg = random_hypergraph(rng, n=40, m=80)
+    masks = rng.integers(0, 1 << P, size=hg.n)
+    masks[: hg.n // 2] = 1 << rng.integers(0, P, size=hg.n // 2)
     state = PartitionState(hg, P, masks=masks)
-    rows = state.uncov
-    want = _lambda_from_rows(rows, state._order, state._order_pc)
-    got = gain.min_cover_lambdas(rows, state._order, state._order_pc)
-    assert np.array_equal(want, got)
-    ops.force("pallas")
-    try:
-        got_pl = gain.min_cover_lambdas(rows, state._order, state._order_pc,
-                                        interpret=True)
-    finally:
-        ops.force(None)
-    assert np.array_equal(want, got_pl)
+    vs = np.arange(hg.n)
+    cands, xcand = move_candidates(state, vs)
+    node = np.repeat(vs, np.diff(xcand))
+    pairs = [(e, c, int(state.masks[v])) for v, c in zip(node, cands)
+             for e in state.inc_edges[state.xinc[v]:state.xinc[v + 1]]]
+    er, cr, orow = (np.array(x, dtype=np.int64) for x in zip(*pairs))
+    rows = state.uncov[er] + state._contrib[cr] - state._contrib[orow]
+    lam_old = state.edge_lambda[er].astype(np.int32)
+    lam_new = _lambda_from_rows(rows, state._order, state._order_pc)
+    want = (np.maximum(lam_new.astype(np.int32) - 1, 0)
+            - np.maximum(lam_old - 1, 0))
+    # the device pass's layout: popcount-ordered columns behind subset 0,
+    # padded to 128 columns (never a cover) and a pow2 of rows (dropped)
+    cols = np.concatenate(([0], state._order))
+    pc = np.concatenate(([gain._NO_COVER], state._order_pc))
+    R, M = len(rows), len(cols)
+    Rp, Mp = max(512, 1 << (R - 1).bit_length()), -(-M // 128) * 128
+    rows_p = np.ones((Rp, Mp), dtype=np.int32)
+    rows_p[:R, :M] = rows[:, cols]
+    pc_p = np.full(Mp, gain._NO_COVER, dtype=np.int32)
+    pc_p[:M] = pc
+    lam_p = np.zeros(Rp, dtype=np.int32)
+    lam_p[:R] = lam_old
+    got = np.asarray(gain.front_dlam(jnp.asarray(rows_p), jnp.asarray(pc_p),
+                                     jnp.asarray(lam_p), interpret=True))
+    assert np.array_equal(got[:R], want)
+    assert (want != 0).any()
 
 
 # ----------------------------------------------------------- schedule front
@@ -207,7 +244,7 @@ def test_property_node_move_front_equals_delta(seed):
 @given(st.integers(0, 10_000))
 @settings(max_examples=10, deadline=None)
 def test_property_node_move_targets_mirror_guards(seed):
-    """Feasibility vector == try_node_move's guard conditions."""
+    """Feasibility vector == reference.try_node_move's guard conditions."""
     rng = np.random.default_rng(seed)
     dag = random_dag(int(rng.integers(20, 60)), seed)
     inst = BspInstance(dag, P=int(rng.integers(2, 6)),
@@ -336,17 +373,18 @@ def test_sm_winner_pass_never_increases_cost():
 
 
 def test_node_move_pass_paths_identical():
-    """hill_climb with and without fronts must produce identical schedules."""
+    """hill_climb's node-move fronts must produce the full-recompute
+    oracle's schedules."""
     from repro.core.schedule import hill_climb
+    from repro.core.schedule import reference as ref
     for seed in (0, 1, 2):
         dag = random_dag(120, seed)
         inst = BspInstance(dag, P=4, g=4.0, L=20.0)
-        on = hill_climb(bspg_schedule(inst, seed=seed), seed=seed)
-        off = hill_climb(bspg_schedule(inst, seed=seed), seed=seed,
-                         use_fronts=False)
-        assert on.current_cost() == off.current_cost()
-        assert on.comms == off.comms
-        assert [dict(a) for a in on.assign] == [dict(a) for a in off.assign]
+        eng = hill_climb(bspg_schedule(inst, seed=seed), seed=seed)
+        orc = ref.hill_climb(ref.bspg_schedule(inst, seed=seed), seed=seed)
+        assert eng.current_cost() == orc.current_cost()
+        assert eng.comms == orc.comms
+        assert [dict(a) for a in eng.assign] == [dict(a) for a in orc.assign]
 
 
 def test_sr_winner_improves_and_stays_valid():

@@ -262,7 +262,7 @@ def test_refinement_never_increases_cost_per_level():
     stats = []
     sched = multilevel_schedule(
         inst, seed=0, stats=stats,
-        opts=MultilevelScheduleOptions(coarsest_n=400, flat_guard_n=0))
+        opts=MultilevelScheduleOptions(coarsest_n=400))
     rows = [r for r in stats if "level" in r]
     assert len(rows) >= 2, "no coarsening happened"
     for row in rows:
@@ -274,47 +274,25 @@ def test_refinement_never_increases_cost_per_level():
 @pytest.mark.parametrize("n,band", [(2000, 32), (3000, 32)])
 def test_multilevel_not_worse_than_flat(n, band):
     """Final-cost parity (<=) against the flat path, on the pure V-cycle
-    (guard disabled) -- instances where the projection+refinement beats
-    flat outright."""
+    -- instances where the projection+refinement beats flat outright."""
     dag = sptrsv_dag(n=n, band=band, seed=0)
     inst = BspInstance(dag, P=8, g=4.0, L=20.0)
     flat = best_replicated_schedule(inst, seed=0)
-    mlv = best_replicated_schedule(
-        inst, seed=0, multilevel=True,
-        ml_opts=MultilevelScheduleOptions(flat_guard_n=0))
+    mlv = best_replicated_schedule(inst, seed=0, multilevel=True)
     assert mlv.validate() == []
     assert mlv.current_cost() <= flat.current_cost() + 1e-9
 
 
-def test_flat_guard_enforces_not_worse():
-    """With the guard opted back in (``flat_guard_n`` positive -- it is
-    retired by default since the split front landed), the driver returns
-    the cheaper of the V-cycle and the flat path -- never worse than flat
-    by construction, even on basin-unfriendly instances."""
-    dag = psdd_dag(n_leaves=500, depth=12, seed=1)
-    inst = BspInstance(dag, P=8, g=4.0, L=20.0)
-    flat = best_replicated_schedule(inst, seed=0)
-    stats = []
-    mlv = best_replicated_schedule(
-        inst, seed=0, multilevel=True, stats=stats,
-        ml_opts=MultilevelScheduleOptions(flat_guard_n=8192))
-    assert mlv.current_cost() <= flat.current_cost() + 1e-9
-    guard_rows = [r for r in stats if r.get("flat_guard")]
-    assert len(guard_rows) == 1
-    assert guard_rows[0]["flat_cost"] == flat.current_cost()
-
-
 def test_guard_off_not_worse_on_psdd():
-    """PR 9 acceptance: the pure V-cycle (guard retired, splits on) is not
-    worse than flat on the psdd family that used to need the hedge."""
+    """The pure V-cycle (no flat hedge, splits on) is not worse than flat
+    on the psdd family that used to need the hedge."""
     dag = psdd_dag(n_leaves=500, depth=12, seed=1)
     inst = BspInstance(dag, P=8, g=4.0, L=20.0)
     flat = best_replicated_schedule(inst, seed=0)
     stats = []
     mlv = best_replicated_schedule(inst, seed=0, multilevel=True,
                                    stats=stats)
-    assert not any(r.get("flat_guard") for r in stats), \
-        "guard must be off by default"
+    assert all("level" in r for r in stats)
     assert mlv.validate() == []
     assert mlv.current_cost() <= flat.current_cost() + 1e-9
 
@@ -343,7 +321,7 @@ def test_multilevel_immediate_stagnation_falls_through():
     dag = Dag(n=n, edge_list=edges)
     inst = BspInstance(dag, P=4, g=4.0, L=20.0)
     opts = MultilevelScheduleOptions(coarsest_n=64, max_fanout=8,
-                                     cluster_cap_frac=1e-9, flat_guard_n=0)
+                                     cluster_cap_frac=1e-9)
     flat = best_replicated_schedule(inst, seed=0)
     mlv = best_replicated_schedule(inst, seed=0, multilevel=True,
                                    ml_opts=opts)
