@@ -25,8 +25,8 @@ from .partition_front import (GainCache, add_replica_candidates,
                               lookahead_window, move_candidates,
                               price_mask_front, refresh_boundary_window,
                               set_backend)
-from .schedule_front import (apply_sm_mutations, apply_sr_mutations,
-                             commit_superstep_merge,
+from .schedule_front import (MergePriceCache, apply_sm_mutations,
+                             apply_sr_mutations, commit_superstep_merge,
                              commit_superstep_replication,
                              commit_superstep_split, device_windows,
                              node_move_targets, price_comm_moves,
@@ -40,7 +40,7 @@ __all__ = [
     "GainCache", "add_replica_candidates", "connected_add_candidates",
     "connected_targets", "device_pass", "fm_move_candidates", "get_backend",
     "lookahead_window", "move_candidates", "price_mask_front",
-    "refresh_boundary_window", "set_backend",
+    "refresh_boundary_window", "set_backend", "MergePriceCache",
     "apply_sm_mutations", "apply_sr_mutations", "commit_superstep_merge",
     "commit_superstep_replication", "commit_superstep_split",
     "device_windows", "node_move_targets",

@@ -31,7 +31,9 @@ Three fronts from the scheduling stack's hot loops:
     lower the cost further) and only the **winner** (min priced delta,
     ties to the smallest s) commits through a transaction; the
     ``reference.py`` oracle applies the same winner rule in lockstep, so
-    trajectories stay bit-identical on integer weights.
+    trajectories stay bit-identical on integer weights.  Within one pass
+    ``MergePriceCache`` keeps each price with the read set of its replay,
+    so a commit reprices only the candidates whose reads it wrote.
 
 All are pure; committing stays with the engine's transaction machinery.
 """
@@ -522,7 +524,14 @@ class _MergeSim:
     Only the members the SM sequence uses are implemented: ``comms`` /
     ``assign`` (merged dict views), ``comp`` (base -- the sequence never
     revisits a phase it mutates), ``uses_on`` / ``compute_sstep`` /
-    ``present_at``, and the four mutation primitives.
+    ``present_at``, and the four mutation primitives.  ``_assign`` (the
+    values whose assignment the replay read), ``_src`` (the source sets it
+    read) and the cells' supersteps make its read set
+    (``MergePriceCache``).  Its comm reads need no log of their own: it
+    tests a comm key ``(v, p)`` only where it has read v's assignment
+    (``present_at``, ``_ensure_present_for_merge``), moves and removes
+    only comms of the buckets at s and s + 1, and reaches any other comm
+    through a source set (``uses_on``).
     """
 
     def __init__(self, base: ScheduleState) -> None:
@@ -563,7 +572,12 @@ class _MergeSim:
         return c[1] if c is not None else INF
 
     def present_at(self, v: int, p: int, s: int) -> bool:
-        return self.compute_sstep(v, p) <= s or self.recv_sstep(v, p) < s
+        # compute_sstep(v, p) <= s or recv_sstep(v, p) < s, inlined: the
+        # recursion of ``_ensure_present_for_merge`` calls it most
+        if self[v].get(p, INF) <= s:
+            return True
+        c = self.comms.get((v, p))
+        return c is not None and c[1] < s
 
     def uses_on(self, v: int, p: int) -> list[int]:
         out = []
@@ -636,15 +650,49 @@ def price_superstep_merge(sched: ScheduleState, s: int,
     """
     if s + 1 >= sched.S:
         return None
+    return _priced_merge(sched, s, comms_at)[0]
+
+
+def _priced_merge(sched: ScheduleState, s: int, comms_at):
+    """``price_superstep_merge`` of a valid pair with its read set:
+    ``(rows, values, (value, source) keys)``, rows being s, s + 1 (the
+    comm buckets and the work row the replay reads) and every superstep a
+    cell touched (the rows ``_delta_cells`` reads)."""
     sim = _MergeSim(sched)
-    if not apply_sm_mutations(sim, s, comms_at):
-        return None
-    return sched._delta_cells(sim.cells)
+    price = (sched._delta_cells(sim.cells)
+             if apply_sm_mutations(sim, s, comms_at) else None)
+    rows = {c[1] for c in sim.cells}
+    rows.update((s, s + 1))
+    # key sets, not the sim's dicts: a cache entry then keeps no copied
+    # assignment or source set alive for the garbage collector to walk
+    return price, (rows, set(sim._assign), set(sim._src))
 
 
-def commit_superstep_merge(sched: ScheduleState, s: int) -> None:
+def _merge_writes(records: list):
+    """What a merge transaction wrote, from its undo records, in the terms
+    of ``_priced_merge``'s read sets -- a value counts when its assignment
+    or any of its comms changed -- or None when it grew ``S``."""
+    rows: set[int] = set()
+    values: set[int] = set()
+    srcs: set[tuple[int, int]] = set()
+    for rec in records:
+        tag = rec[0]
+        if tag == "S":
+            return None
+        args, saves = rec[1], rec[2]
+        values.add(args[0])
+        if tag in ("-comm", "+comm"):       # args (v, src, dst, ...)
+            srcs.add((args[0], args[1]))
+        rows.update(sv[1] for sv in saves)
+    return rows, values, srcs
+
+
+def commit_superstep_merge(sched: ScheduleState, s: int):
     """Replay a priced SM winner through the transaction machinery, then
-    prune (the commit is never worse than its price) and compact."""
+    prune (the commit is never worse than its price) and compact.
+
+    Returns what the transaction wrote (``_merge_writes``, in the
+    numbering before the compaction) and ``compact``'s old -> new map."""
     sched.begin()
     try:
         if not apply_sm_mutations(sched, s):
@@ -653,8 +701,74 @@ def commit_superstep_merge(sched: ScheduleState, s: int) -> None:
     except BaseException:
         sched.rollback()
         raise
+    writes = _merge_writes(sched.frame_records())
     sched.commit()
-    sched.compact()
+    return writes, sched.compact()
+
+
+class MergePriceCache:
+    """Exact SM prices across the commits of one winner-commit pass.
+
+    Each candidate s keeps its price with the read set of the replay that
+    computed it (``_priced_merge``).  A commit's undo records say what it
+    wrote in the same terms (``_merge_writes``; the prune runs inside the
+    transaction, so its removals count).  An entry whose reads the commit
+    did not write keeps its price: the replay would read the same values,
+    take the same branches and fold the same cells against the same rows,
+    summed in the same ascending order.  The compaction then renumbers the
+    survivors.  Its map is monotone and every superstep test of the replay
+    is an order comparison, so each comes out the same; an entry that
+    read a removed superstep goes.  ``priced`` and ``reused`` count
+    candidates, once per round each.
+    """
+
+    def __init__(self) -> None:
+        self.entries: dict[int, tuple] = {}
+        self.priced = 0
+        self.reused = 0
+
+    def prices(self, sched: ScheduleState) -> list[float | None]:
+        """The price of every candidate of ``sm_front(sched)``, in order,
+        replaying only those with no entry."""
+        front = sm_front(sched)
+        todo = [s for s in front if s not in self.entries]
+        self.priced += len(todo)
+        self.reused += len(front) - len(todo)
+        if todo:
+            # the comm buckets those replays read, each sorted as the
+            # filtered full sort would list it (comm keys are unique)
+            need = set(todo)
+            need.update(s + 1 for s in todo)
+            by_t: dict[int, list] = {}
+            for kv in sched.comms.items():
+                if kv[1][1] in need:
+                    by_t.setdefault(kv[1][1], []).append(kv)
+            for bucket in by_t.values():
+                bucket.sort()
+            for s in todo:
+                self.entries[s] = _priced_merge(
+                    sched, s, (by_t.get(s, []), by_t.get(s + 1, [])))
+        return [self.entries[s][0] for s in front]
+
+    def commit(self, sched: ScheduleState, s: int) -> None:
+        """Commit the merge at s; keep, renumbered, the entries it did not
+        write."""
+        writes, remap = commit_superstep_merge(sched, s)
+        if writes is None:
+            self.entries = {}
+            return
+        w_rows, w_values, w_srcs = writes
+        kept = {}
+        for c, (price, (rows, values, srcs)) in self.entries.items():
+            if not (rows.isdisjoint(w_rows) and values.isdisjoint(w_values)
+                    and srcs.isdisjoint(w_srcs)):
+                continue
+            if remap is not None:
+                if not all(t in remap for t in rows):
+                    continue
+                c, rows = remap[c], {remap[t] for t in rows}
+            kept[c] = (price, (rows, values, srcs))
+        self.entries = kept
 
 
 def commit_superstep_replication(sched: ScheduleState, s: int, p1: int,

@@ -428,7 +428,7 @@ class ScheduleState:
     def add_comm(self, v: int, src: int, dst: int, s: int) -> None:
         self._grow(s)
         assert (v, dst) not in self.comms
-        saves = self._log(("-comm", v, dst))
+        saves = self._log(("-comm", v, src, dst))
         self.comms[(v, dst)] = (src, s)
         self.src_index[(v, src)].add(dst)
         self._prune_dirty.add(v)
@@ -497,8 +497,8 @@ class ScheduleState:
                     self.assign[v][p] = s
                     self._mark_comp_dirty(v)
                 elif tag == "-comm":
-                    v, dst = args
-                    src, _ = self.comms.pop((v, dst))
+                    v, src, dst = args
+                    self.comms.pop((v, dst))
                     self.src_index[(v, src)].discard(dst)
                     self._prune_dirty.add(v)
                 elif tag == "+comm":
@@ -515,6 +515,15 @@ class ScheduleState:
                 self._total = total_before
         finally:
             self._replaying = False
+
+    def frame_records(self) -> list:
+        """The undo records of the innermost open frame, oldest first:
+        ``("S", old_S, None)`` for a growth, else ``(tag, args, saves,
+        total_before)`` with tag ``-comp`` (args ``v, p``), ``+comp``
+        (``v, p, s``), ``-comm`` (``v, src, dst``) or ``+comm``
+        (``v, src, dst, s``) and ``saves`` the ``(kind, s, p, ...)`` rows
+        the mutation overwrote.  Read-only: ``commit`` drops them."""
+        return self._undo[self._frames[-1]:]
 
     @property
     def depth(self) -> int:
@@ -770,19 +779,21 @@ class ScheduleState:
             self.remove_comm(*key)
         return len(drop)
 
-    def compact(self) -> None:
+    def compact(self) -> dict[int, int] | None:
         """Remove empty supersteps (no compute and no comm anywhere).
 
         Renumbers through ``comp`` membership -- O(nodes in shifted
         supersteps + comms) -- instead of rebuilding every assign dict.
-        Must not run inside an open transaction."""
+        Returns the old -> new superstep map it applied (removed supersteps
+        absent), or None when it removed nothing.  Must not run inside an
+        open transaction."""
         assert not self._frames, "compact inside an open transaction"
         P = self.inst.P
         keep = [s for s in range(self.S)
                 if any(self.work[s]) or any(self.sent[s]) or any(self.recv[s])
                 or any(self.comp[s][p] for p in range(P))]
         if len(keep) == self.S:
-            return
+            return None
         remap = {old: new for new, old in enumerate(keep)}
         for old_s in keep:
             new_s = remap[old_s]
@@ -803,6 +814,7 @@ class ScheduleState:
         self._total = sum(self._scost)
         self.comms = {k: (src, remap[s])
                       for k, (src, s) in self.comms.items()}
+        return remap
 
     # ------------------------------------------------------------ projection
     @classmethod

@@ -33,7 +33,9 @@ instead of O(n + S*P + comms).
 from __future__ import annotations
 
 import dataclasses
+import sys
 
+from ...spans import span
 from .bsp import EPS, INF, Schedule
 
 
@@ -229,6 +231,15 @@ def batch_replication_pass(sched: Schedule) -> bool:
 
 # --------------------------------------------------------- superstep merging
 
+def _schedule_totals() -> dict | None:
+    """``front_pass.SCHEDULE_TOTALS`` once jax is loaded, else None: a
+    numpy-only process stays jax-free, and holds no reader of the table."""
+    if "jax" not in sys.modules:
+        return None
+    from ...kernels.front_pass import SCHEDULE_TOTALS
+    return SCHEDULE_TOTALS
+
+
 def superstep_merge_pass(sched: Schedule) -> tuple[Schedule, bool]:
     """SM sweep over adjacent superstep pairs.
 
@@ -239,27 +250,29 @@ def superstep_merge_pass(sched: Schedule) -> tuple[Schedule, bool]:
     machinery, repeating until no candidate improves.  The oracle
     (``reference.superstep_merge_pass``) applies the same winner rule, so
     trajectories stay identical (bit-identical on integer weights).
+    Prices carry across the pass's commits (``frontier.MergePriceCache``):
+    a round replays only the candidates whose read set the last commit
+    wrote.  The pass is the ``schedule.sm`` span; its candidates count
+    into ``SCHEDULE_TOTALS["sm_priced"]`` / ``["sm_reused"]``.
     """
-    from ..frontier import (commit_superstep_merge, price_superstep_merge,
-                            sm_front)
+    from ..frontier import MergePriceCache
+    cache = MergePriceCache()
     improved = False
-    while sched.S > 1:
-        # one comm sort per round, bucketed by superstep, shared by every
-        # candidate pricing (identical iteration to the inline sort)
-        by_t: dict[int, list] = {}
-        for kv in sorted(sched.comms.items()):
-            by_t.setdefault(kv[1][1], []).append(kv)
-        best = None
-        for s in sm_front(sched):
-            priced = price_superstep_merge(
-                sched, s, comms_at=(by_t.get(s, []), by_t.get(s + 1, [])))
-            if priced is not None and priced < -EPS:
-                if best is None or priced < best[0]:
-                    best = (priced, s)
-        if best is None:
-            break
-        commit_superstep_merge(sched, best[1])
-        improved = True
+    with span("schedule.sm"):
+        while sched.S > 1:
+            best = None
+            for s, priced in enumerate(cache.prices(sched)):
+                if priced is not None and priced < -EPS:
+                    if best is None or priced < best[0]:
+                        best = (priced, s)
+            if best is None:
+                break
+            cache.commit(sched, best[1])
+            improved = True
+    totals = _schedule_totals()
+    if totals is not None:
+        totals["sm_priced"] += cache.priced
+        totals["sm_reused"] += cache.reused
     return sched, improved
 
 

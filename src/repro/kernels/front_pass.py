@@ -113,9 +113,12 @@ _PROGRAM_CACHE_SIZE = 64
 PARTITION_TOTALS: dict[tuple[int, bool, bool], dict[str, int]] = {}
 # Every ``DeviceScheduleWindows`` built, every host sync it made, every
 # byte it uploaded, every comm window it priced and every priced-ahead
-# window a mutation dropped unused.
+# window a mutation dropped unused.  Two host keys, counted by
+# ``replication.superstep_merge_pass`` on every backend once jax is
+# loaded: SM candidates replayed (``sm_priced``) and taken from the
+# pass's price cache (``sm_reused``), once per candidate per round.
 SCHEDULE_TOTALS = {"attaches": 0, "syncs": 0, "h2d_bytes": 0, "windows": 0,
-                   "discarded": 0}
+                   "discarded": 0, "sm_priced": 0, "sm_reused": 0}
 
 
 def _integer_valued(a: np.ndarray) -> bool:

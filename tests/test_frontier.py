@@ -340,26 +340,6 @@ def test_property_sm_pricing_equals_trial(seed):
             assert actual is not None and abs(actual - priced) < 1e-9
 
 
-def test_sm_winner_pass_engine_matches_oracle():
-    """The SM winner rule must walk engine and oracle through identical
-    trajectories (same costs, shapes and replica counts)."""
-    from repro.core.schedule import reference as ref
-    from repro.core.schedule.replication import superstep_merge_pass
-    for seed in (0, 1, 2, 5):
-        dag = random_dag(90 + 10 * seed, seed)
-        inst = BspInstance(dag, P=4, g=4.0, L=20.0)
-        eng = bspg_schedule(inst, seed=seed)
-        orc = ref.bspg_schedule(inst, seed=seed)
-        assert eng.current_cost() == orc.current_cost()
-        eng, imp_e = superstep_merge_pass(eng)
-        orc, imp_o = ref.superstep_merge_pass(orc)
-        assert imp_e == imp_o
-        assert eng.current_cost() == orc.current_cost()
-        assert eng.S == orc.S
-        assert eng.comms == orc.comms
-        eng.check()
-
-
 def test_sm_winner_pass_never_increases_cost():
     from repro.core.schedule.replication import superstep_merge_pass
     from repro.datagen import sptrsv_dag
@@ -370,6 +350,217 @@ def test_sm_winner_pass_never_increases_cost():
     sched, _ = superstep_merge_pass(sched)
     assert sched.current_cost() <= before + EPS
     sched.check()
+
+
+def _bits(prices):
+    return [None if x is None else float(x).hex() for x in prices]
+
+
+@pytest.fixture
+def checked_sm(monkeypatch):
+    """The SM pass with its price cache watched: every round asserts that
+    each price it hands the winner rule -- replayed or reused -- equals a
+    fresh ``price_superstep_merge`` of the current state bit for bit, and
+    records the round's front size; each cache built is kept."""
+    from repro.core.frontier import MergePriceCache, price_superstep_merge
+    seen = {"fronts": [], "caches": []}
+    prices, init = MergePriceCache.prices, MergePriceCache.__init__
+
+    def checked(self, sched):
+        got = prices(self, sched)
+        fresh = [price_superstep_merge(sched, s) for s in range(sched.S - 1)]
+        assert _bits(got) == _bits(fresh)
+        seen["fronts"].append(len(got))
+        return got
+
+    def kept(self):
+        init(self)
+        seen["caches"].append(self)
+
+    monkeypatch.setattr(MergePriceCache, "prices", checked)
+    monkeypatch.setattr(MergePriceCache, "__init__", kept)
+    return seen
+
+
+def _sm_lockstep_instance(which):
+    from repro.datagen import sptrsv_dag
+    if which == "sptrsv":
+        return BspInstance(sptrsv_dag(n=800, band=32, seed=0), P=8, g=1.0,
+                           L=5.0), 0
+    seed = int(which.split("-")[1])
+    dag = random_dag(90 + 10 * seed, seed, weighted=which.startswith("w"))
+    return BspInstance(dag, P=4, g=4.0, L=20.0), seed
+
+
+@pytest.mark.parametrize("which", ["random-0", "random-1", "random-2",
+                                   "random-5", "weighted-1", "weighted-4",
+                                   "sptrsv"])
+def test_sm_winner_pass_engine_matches_oracle(checked_sm, which):
+    """The SM winner rule must walk engine and oracle through identical
+    trajectories: same S, comms, assignments and cost, with every price
+    the cached pass reuses checked bit for bit against a fresh replay.
+    The sptrsv instance commits 20+ merges in one pass, so most of its
+    prices come from the cache."""
+    from repro.core.schedule import reference as ref
+    from repro.core.schedule.replication import superstep_merge_pass
+    inst, seed = _sm_lockstep_instance(which)
+    exact = not which.startswith("weighted")  # float rows sum differently
+    eng = bspg_schedule(inst, seed=seed)
+    orc = ref.bspg_schedule(inst, seed=seed)
+    assert eng.current_cost() == (orc.current_cost() if exact else
+                                  pytest.approx(orc.current_cost()))
+    eng, imp_e = superstep_merge_pass(eng)
+    orc, imp_o = ref.superstep_merge_pass(orc)
+    commits = len(checked_sm["fronts"]) - 1
+    if which == "sptrsv":
+        assert commits >= 20
+        assert checked_sm["caches"][0].reused > 0
+    assert imp_e == imp_o == (commits > 0)
+    assert eng.S == orc.S
+    assert eng.comms == orc.comms
+    assert [dict(a) for a in eng.assign] == [dict(a) for a in orc.assign]
+    if exact:
+        assert eng.current_cost() == orc.current_cost()
+        eng.check()
+    else:   # float rows: check()'s exact top-2 test is the engine's own
+        # known float drift (test_property_delta_matches_recompute)
+        assert eng.current_cost() == pytest.approx(orc.current_cost())
+
+
+def test_sm_counters_cover_every_candidate_of_every_round(checked_sm):
+    """``sm_priced + sm_reused`` is the sum of S - 1 over the pass's
+    rounds, in the cache and in ``SCHEDULE_TOTALS``; a pass that commits
+    twice or more reuses prices, and one that commits nothing reuses
+    none."""
+    from repro.core.schedule.replication import superstep_merge_pass
+    from repro.kernels import front_pass
+    inst, seed = _sm_lockstep_instance("sptrsv")
+    sched = bspg_schedule(inst, seed=seed)
+    for passes in range(2):
+        t0 = dict(front_pass.SCHEDULE_TOTALS)
+        checked_sm["fronts"].clear()
+        sched, improved = superstep_merge_pass(sched)
+        cache = checked_sm["caches"][-1]
+        fronts = checked_sm["fronts"]
+        assert cache.priced + cache.reused == sum(fronts)
+        assert front_pass.SCHEDULE_TOTALS["sm_priced"] - t0["sm_priced"] \
+            == cache.priced
+        assert front_pass.SCHEDULE_TOTALS["sm_reused"] - t0["sm_reused"] \
+            == cache.reused
+        commits = len(fronts) - 1
+        assert improved == (commits > 0)
+        if passes == 0:
+            assert commits >= 2 and cache.reused > 0
+        else:   # the first pass reached the fixed point
+            assert commits == 0
+            assert cache.reused == 0 and cache.priced == sched.S - 1
+
+
+def test_sm_commit_renumbers_kept_prices():
+    """``compact`` returns the map it applied (None when it removed
+    nothing); a candidate above the merged pair keeps its cached price
+    under its new index, and every kept price is a fresh replay's."""
+    from repro.core.frontier import (MergePriceCache, commit_superstep_merge,
+                                     price_superstep_merge)
+    inst, seed = _sm_lockstep_instance("sptrsv")
+    sched = bspg_schedule(inst, seed=seed)
+    assert sched.compact() is None
+    cache = MergePriceCache()
+    moved_up = 0
+    while True:
+        prices = cache.prices(sched)
+        best = None
+        for s, priced in enumerate(prices):
+            if priced is not None and priced < -EPS:
+                if best is None or priced < best[0]:
+                    best = (priced, s)
+        if best is None:
+            break
+        s_star, S0 = best[1], sched.S
+        before = dict(cache.entries)
+        cache.commit(sched, s_star)
+        assert sched.S == S0 - 1
+        for c, (price, _reads) in cache.entries.items():
+            assert _bits([price]) == _bits([price_superstep_merge(sched, c)])
+            if c > s_star:      # above the merged pair: one index down
+                assert before[c + 1][0] is price
+                moved_up += 1
+    assert moved_up > 0
+    # the map itself, on a merge the pass would not pick
+    trial = sched.copy()
+    s = next(s for s in range(trial.S - 1)
+             if price_superstep_merge(trial, s) is not None)
+    S0 = trial.S
+    writes, remap = commit_superstep_merge(trial, s)
+    assert writes is not None
+    assert remap == {t: (t if t <= s else t - 1)
+                     for t in range(S0) if t != s + 1}
+    assert trial.compact() is None
+
+
+@pytest.mark.parametrize("writes, remap, kept", [
+    # nothing read was written, nothing removed: every entry stays
+    (({9}, {99}, {(99, 0)}), None, {0: 0, 2: 2, 4: 4}),
+    # a written row, value or source set drops just its readers
+    (({3}, {99}, set()), None, {0: 0, 4: 4}),
+    (({9}, {10}, set()), None, {2: 2, 4: 4}),
+    (({9}, {99}, {(12, 1)}), None, {0: 0, 2: 2}),
+    # a removed superstep renumbers the rest and drops its readers:
+    # 3 goes, so 2 (read 1..3) goes and 4 moves to 3
+    (({9}, {99}, set()), {0: 0, 1: 1, 2: 2, 4: 3, 5: 4}, {0: 0, 3: 4}),
+    # a growth of S drops everything
+    (None, None, {}),
+], ids=["untouched", "row", "value", "source-set", "renumbered", "grown"])
+def test_sm_commit_keeps_only_unwritten_prices(monkeypatch, writes, remap,
+                                               kept):
+    """``MergePriceCache.commit``'s carry rule on hand-made read and write
+    sets: an entry goes when the commit wrote a row, value or source set
+    it read, or removed a row it read; the rest keep their price under
+    the renumbered index (``kept`` maps new index -> old)."""
+    from repro.core.frontier import MergePriceCache
+    import repro.core.frontier.schedule_front as sf
+    monkeypatch.setattr(sf, "commit_superstep_merge",
+                        lambda sched, s: (writes, remap))
+    cache = MergePriceCache()
+    cache.entries = {0: (-1.0, ({0, 1}, {10}, set())),
+                     2: (-2.0, ({1, 2, 3}, {11}, set())),
+                     4: (None, ({4, 5}, {13}, {(12, 1)}))}
+    old = dict(cache.entries)
+    cache.commit(None, 0)
+    assert {c: e[0] for c, e in cache.entries.items()} == \
+        {c: old[o][0] for c, o in kept.items()}
+    for c, o in kept.items():
+        want = old[o][1][0]
+        if remap is not None:
+            want = {remap[t] for t in want}
+        assert cache.entries[c][1][0] == want
+
+
+def test_sm_read_and_write_sets():
+    """Every replay reads its own pair's rows; a merge transaction's undo
+    records name the rows, values and source sets it wrote."""
+    from repro.core.frontier.schedule_front import (_merge_writes,
+                                                    _priced_merge)
+    inst, seed = _sm_lockstep_instance("random-3")
+    sched = bspg_schedule(inst, seed=seed)
+    for s in range(sched.S - 1):
+        rows, values, srcs = _priced_merge(sched, s, None)[1]
+        assert {s, s + 1} <= rows <= {s - 1, s, s + 1}
+    # merging an empty superstep moves no cell, and still reads its pair
+    from repro.core.schedule import Schedule
+    bare = Schedule(BspInstance(Dag(n=2, edge_list=[]), P=2), 3)
+    bare.add_comp(0, 0, 0)
+    bare.add_comp(1, 0, 2)
+    assert _priced_merge(bare, 0, None) == (0.0, ({0, 1}, set(), set()))
+    (v, dst), (src, t) = sorted(sched.comms.items())[0]
+    u, (p, su) = next((u, next(iter(a.items())))
+                      for u, a in enumerate(sched.assign) if u != v)
+    sched.begin()
+    sched.move_comm(v, dst, t + 1)
+    sched.remove_comp(u, p)
+    writes = _merge_writes(sched.frame_records())
+    sched.rollback()
+    assert writes == ({t, t + 1, su}, {v, u}, {(v, src)})
 
 
 def test_node_move_pass_paths_identical():
